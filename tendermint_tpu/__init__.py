@@ -7,7 +7,7 @@ L2 fork of Tendermint Core v0.34.x) for TPU hardware:
   idiomatic Python (asyncio) with C++ where the reference leans on native code;
 - device plane: the signature-verification hot path (vote ingestion, commit
   verification, blocksync replay, light-client bisection, BLS aggregation) as
-  batched JAX/Pallas kernels sharded over a `jax.sharding.Mesh`.
+  batched JAX/XLA programs sharded over a `jax.sharding.Mesh`.
 
 Layout (mirrors SURVEY.md §1-2 of this repo):
     crypto/    host reference crypto (ed25519, merkle, hashes) + verifier API

@@ -56,18 +56,30 @@ def cmd_start(args) -> int:
     node = Node(cfg)
 
     async def run():
-        await node.start()
-        try:
-            await asyncio.Event().wait()  # run until interrupted
-        except asyncio.CancelledError:
-            pass
-        finally:
-            await node.stop()
+        import signal
 
-    try:
-        asyncio.run(run())
-    except KeyboardInterrupt:
-        print("\nshutting down")
+        # handlers first: a signal that arrives while the node is still
+        # starting stops it cleanly once it has started
+        stop = asyncio.Event()
+        loop = asyncio.get_running_loop()
+        for sig in (signal.SIGINT, signal.SIGTERM):
+            loop.add_signal_handler(sig, stop.set)
+        await node.start()
+        stopped = loop.create_task(stop.wait())
+        try:
+            # run until a signal asks for a clean stop, or a background
+            # part of startup (the verifier warm) fails
+            await asyncio.wait(
+                {stopped, node.failed},
+                return_when=asyncio.FIRST_COMPLETED,
+            )
+        finally:
+            stopped.cancel()
+            await node.stop()
+        if node.failed.done():
+            raise node.failed.exception()
+
+    asyncio.run(run())
     return 0
 
 
@@ -652,6 +664,11 @@ def main(argv=None) -> int:
     sp.set_defaults(fn=cmd_version)
 
     args = p.parse_args(argv)
+    # one compile-cache policy for every subcommand that compiles
+    # (start, verify-service, light, probe-tpu): set before the first
+    from .libs.jax_cache import configure_compile_cache
+
+    configure_compile_cache()
     return args.fn(args)
 
 

@@ -139,42 +139,6 @@ class Node(Service):
         bls_native.native_lib()
         secp_native.native_lib()
         aead._native_lib()
-        # persistent XLA compile cache under the node home: table-build
-        # and verify programs compile once per machine, not once per
-        # process restart. jax is already imported by this module's
-        # import chain, so env vars would be silently ignored — use
-        # jax.config directly (bench.py/conftest.py can use env vars
-        # because they run before any jax import).
-        try:
-            import jax as _jax
-
-            # machine-level shared dir (content-addressed, multi-process
-            # safe): the multiprocess testnets and every node on a host
-            # amortize the same table-build/verify compiles. An explicit
-            # JAX_COMPILATION_CACHE_DIR in the environment wins.
-            from ..crypto._native_build import _host_tag
-
-            # per-host-ISA subdir: XLA:CPU AOT entries embed host
-            # instructions; a cross-host entry on a shared dir is a
-            # SIGILL/segfault, not a cache miss (libs/jax_cache.py)
-            cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR") or (
-                os.path.join(
-                    os.path.expanduser("~"),
-                    ".cache",
-                    "tendermint_tpu",
-                    "jax_cache",
-                    _host_tag(),
-                )
-            )
-            _jax.config.update("jax_compilation_cache_dir", cache_dir)
-            _jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 1
-            )
-            _jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", -1
-            )
-        except Exception:
-            pass  # cache is an optimization; never block node startup
         # export the fused device-SHA-512 knob before the first
         # default_verifier() constructs the process-wide verifier
         if config.base.device_challenge_min > 0:
@@ -202,6 +166,15 @@ class Node(Service):
                     f"jax.distributed.initialize failed: {e}; "
                     "continuing single-process"
                 )
+        # one process for each chip: with [scheduler] remote_socket set
+        # the verify service owns the device, so THIS process's local
+        # fallback verifier (and its warm thread) is a CPU verifier —
+        # pinned before the first JAX call opens the default backend
+        from ..libs.device import device_info, pin_cpu
+
+        if config.scheduler.enable and config.scheduler.remote_socket:
+            pin_cpu()
+        self.logger.info("node device", **device_info())
         # [tpu] mesh axes -> env, so the process-wide default_verifier()
         # (constructed lazily by whichever reactor first verifies) builds
         # the sharded verifier per config (parallel/mesh.py).
@@ -709,7 +682,15 @@ class Node(Service):
 
     # --- lifecycle (node.go:1041-1112) ---------------------------------------
 
+    def _fail(self, exc: BaseException) -> None:
+        if not self.failed.done():
+            self.failed.set_exception(exc)
+
     async def on_start(self) -> None:
+        # resolves (with the exception) when a background part of
+        # startup fails after on_start returned; `start` waits on it
+        # beside the stop signal
+        self.failed = asyncio.get_running_loop().create_future()
         # (re)arm table warms for this process lifetime (the default
         # verifier — and its shutdown flag — is shared process-wide)
         ev = getattr(self.consensus.verifier, "shutdown_event", None)
@@ -793,11 +774,24 @@ class Node(Service):
 
             self._warm_abort = self.consensus.verifier.shutdown_event
 
+            loop = asyncio.get_running_loop()
+
             def _warm_startup(
                 verifier=self.consensus.verifier,
                 abort=self._warm_abort,
             ):
+                from ..libs.jax_cache import compile_log
+
+                clog = compile_log()
                 verifier.warm(pubs, key_types=ktypes, abort=abort)
+                # what the warm cost: few seconds with cache_hits > 0 is
+                # a restart on a machine that kept its compiles
+                # (libs/jax_cache.py)
+                self.logger.info(
+                    "validator-table warm complete",
+                    keys=len(pubs),
+                    **clog.totals(),
+                )
                 # ahead-of-time bucket-ladder prewarm (the §10 fix for
                 # per-shape program loads landing mid-height): compile/
                 # load every verify program the ladder dispatches, then
@@ -806,51 +800,56 @@ class Node(Service):
                 # same artifact standalone)
                 if not self.config.scheduler.prewarm or abort.is_set():
                     return
-                try:
-                    entries = verifier.prewarm_buckets(abort=abort)
-                    from ..crypto.shape_registry import (
-                        default_shape_registry,
-                    )
-                    import json as _json
-                    import time as _time
+                entries = verifier.prewarm_buckets(abort=abort)
+                from ..crypto.shape_registry import (
+                    default_shape_registry,
+                )
+                import json as _json
+                import time as _time
 
-                    manifest = {
-                        "created_unix": int(_time.time()),
-                        "ladder": list(default_shape_registry().ladder),
-                        # the mesh topology the ladder was loaded for:
-                        # tools/prewarm.py --verify fails loudly when a
-                        # restarted node's live mesh disagrees (a wrong
-                        # topology would recompile on the hot path)
-                        "device_count": getattr(
-                            verifier, "mesh_devices", 1
-                        ),
-                        "mesh_min_rows": getattr(
-                            verifier, "_mesh_min_rows", 0
-                        ),
-                        "mesh_backend": os.environ.get(
-                            "TM_TPU_MESH_BACKEND", ""
-                        ),
-                        "entries": entries,
-                    }
-                    path = self.config.path(
-                        self.config.scheduler.prewarm_manifest
+                manifest = {
+                    "created_unix": int(_time.time()),
+                    "ladder": list(default_shape_registry().ladder),
+                    # the mesh topology the ladder was loaded for:
+                    # tools/prewarm.py --verify fails loudly when a
+                    # restarted node's live mesh disagrees (a wrong
+                    # topology would recompile on the hot path)
+                    "device_count": getattr(verifier, "mesh_devices", 1),
+                    "mesh_min_rows": getattr(
+                        verifier, "_mesh_min_rows", 0
+                    ),
+                    "mesh_backend": os.environ.get(
+                        "TM_TPU_MESH_BACKEND", ""
+                    ),
+                    "entries": entries,
+                }
+                path = self.config.path(
+                    self.config.scheduler.prewarm_manifest
+                )
+                os.makedirs(os.path.dirname(path), exist_ok=True)
+                with open(path, "w") as f:
+                    _json.dump(manifest, f, indent=1)
+                self.logger.info(
+                    "verify-program prewarm complete",
+                    programs=len(entries),
+                    seconds=round(sum(e["seconds"] for e in entries), 1),
+                    manifest=path,
+                )
+
+            def _warm_or_fail():
+                # a device that cannot build the validator's table or
+                # load the ladder is a failed start, not a log line:
+                # the node stops and `start` exits non-zero
+                try:
+                    _warm_startup()
+                except Exception as e:
+                    self.logger.error(
+                        "verifier warm failed; stopping node", err=repr(e)
                     )
-                    os.makedirs(os.path.dirname(path), exist_ok=True)
-                    with open(path, "w") as f:
-                        _json.dump(manifest, f, indent=1)
-                    self.logger.info(
-                        "verify-program prewarm complete",
-                        programs=len(entries),
-                        seconds=round(
-                            sum(e["seconds"] for e in entries), 1
-                        ),
-                        manifest=path,
-                    )
-                except Exception as e:  # prewarm is an optimization
-                    self.logger.error("bucket prewarm failed", err=repr(e))
+                    loop.call_soon_threadsafe(self._fail, e)
 
             self._warm_thread = _threading.Thread(
-                target=_warm_startup,
+                target=_warm_or_fail,
                 name="verifier-warm",
             )
             self._warm_thread.start()

@@ -1,11 +1,10 @@
 """On-demand profiling hooks — device trace + sampled event-loop
 profile, armed over RPC (`profile_start`/`profile_stop`).
 
-The first live TPU tunnel session (ROADMAP item 1) must be minable
-without a redeploy: when real-silicon anomalies show up mid-capture,
-the operator starts a bounded profile against the RUNNING node, pulls
-the artifacts from `data/profiles/`, and keeps serving. Two captures
-per session:
+A live node on the chip must be minable without a redeploy: when an
+anomaly shows up mid-run, the operator starts a bounded profile
+against the RUNNING node, pulls the artifacts from `data/profiles/`,
+and keeps serving. Two captures per session:
 
 - **device trace**: `jax.profiler.start_trace(dir)` when the jax
   profiler is importable and startable — guarded, CPU-backend tolerant

@@ -1,4 +1,4 @@
-"""Device-plane kernels (JAX/XLA, Pallas where it pays).
+"""Device-plane kernels (JAX/XLA; no Pallas kernel).
 
 The reference's compute-heavy primitives (SURVEY.md §2.2) re-designed for TPU:
 batched ed25519 verification (field/curve arithmetic over 2^255-19, SHA-512,

@@ -23,24 +23,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.4.35 exposes it at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# the "don't verify replication" kwarg was renamed check_rep -> check_vma
-import inspect
-
-try:
-    _CHECK_KW = (
-        "check_vma"
-        if "check_vma" in inspect.signature(_shard_map).parameters
-        else "check_rep"
-    )
-except (TypeError, ValueError):  # pragma: no cover
-    _CHECK_KW = "check_rep"
 
 _CACHE: dict = {}
 
@@ -88,12 +72,12 @@ def aggregate_sharded(points, mesh, add_fn, identity, trailing_shape):
             return x
 
         fn = jax.jit(
-            _shard_map(
+            shard_map(
                 local,
                 mesh=mesh,
                 in_specs=spec,
                 out_specs=spec,
-                **{_CHECK_KW: False},
+                check_vma=False,
             )
         )
         _CACHE[key] = fn

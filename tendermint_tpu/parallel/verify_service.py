@@ -26,9 +26,12 @@ cross-subsystem coalescing design one level, to cross-PROCESS:
   of the in-proc scheduler, so `set_default_scheduler(remote)` captures
   every subsystem's verify path unchanged. Connection retry with capped
   exponential backoff; when the socket dies MID-FLIGHT every pending
-  submission degrades to the local in-proc verifier on this process —
-  the PR 1 backend-guard philosophy: never hang, never silently drop a
-  verdict. Each degrade lands a structured `verify_service.degrade`
+  submission degrades to the local in-proc verifier on this process:
+  never hang, never silently drop a verdict. That verifier is a CPU
+  verifier — the chip belongs to the service, so whoever builds this
+  client pins the process to the CPU platform first
+  (libs/device.pin_cpu; node assembly does). Each degrade lands a
+  structured `verify_service.degrade`
   tracer event + `tm_verify_remote_degrades_total`; submit→verdict
   round trips feed cumulative `ipc_stats()` that the health plane's
   `ipc_round_trip` detector (obs/health.py) watches for drift.
@@ -87,6 +90,8 @@ import numpy as np
 
 from ..crypto.batch_verifier import SigItem, default_verifier
 from ..crypto.shape_registry import default_shape_registry
+from ..libs.device import device_info
+from ..libs.jax_cache import compile_log, configure_compile_cache
 from ..libs.log import Logger, nop_logger
 from ..libs.metrics import (
     Registry,
@@ -416,6 +421,10 @@ class VerifyServiceServer:
         # so the table and every STATS/dump response stay bounded
         self.client_stats: dict[str, dict] = {}
         self.max_client_stats = 1024
+        # rounds answered with an ERROR frame: clients absorb those by
+        # verifying locally, so this count is where a failing device
+        # shows
+        self.error_frames = 0
 
     async def start(self) -> None:
         if not self.scheduler.running:
@@ -464,11 +473,21 @@ class VerifyServiceServer:
     # --- stats/dump surface ------------------------------------------------
 
     def dump(self, entries: int = 128) -> dict:
-        """The dump_dispatch_ledger shape + the tenant table."""
+        """The dump_dispatch_ledger shape + the tenant table. The
+        `service` block says which device this process resolved (and
+        its peak memory where the backend reports it) and what it
+        compiled — "the node committed blocks" proves nothing about
+        the chip, this block does."""
         ledger = self.scheduler.ledger
         return {
             "enabled": True,
-            "service": {"socket": self.path, "pid": os.getpid()},
+            "service": {
+                "socket": self.path,
+                "pid": os.getpid(),
+                **device_info(),
+                "compile": compile_log().snapshot(),
+                "error_frames": self.error_frames,
+            },
             "summary": ledger.summary(),
             "entries": ledger.entries(limit=entries) if entries > 0 else [],
             "shape_registry": default_shape_registry().snapshot(),
@@ -625,9 +644,7 @@ class VerifyServiceServer:
         try:
             verdicts = await self.scheduler.submit(items, klass, ctx=ctx)
         except Exception as e:
-            await self._send_guarded(
-                send, encode_error(req_id, f"verify failed: {e!r}")
-            )
+            await self._send_error(send, req_id, f"verify failed: {e!r}")
             return
         self._service_span(ctx, t_recv, len(items), klass)
         await self._send_guarded(send, encode_verdicts(req_id, verdicts))
@@ -637,8 +654,8 @@ class VerifyServiceServer:
     ):
         fn = self.engines.get(engine)
         if fn is None:
-            await self._send_guarded(
-                send, encode_error(req_id, f"unknown fn engine {engine!r}")
+            await self._send_error(
+                send, req_id, f"unknown fn engine {engine!r}"
             )
             return
         t_recv = time.perf_counter()
@@ -647,13 +664,16 @@ class VerifyServiceServer:
                 items, fn, klass, engine=engine, ctx=ctx
             )
         except Exception as e:
-            await self._send_guarded(
-                send,
-                encode_error(req_id, f"fn engine {engine} failed: {e!r}"),
+            await self._send_error(
+                send, req_id, f"fn engine {engine} failed: {e!r}"
             )
             return
         self._service_span(ctx, t_recv, len(items), klass)
         await self._send_guarded(send, encode_fn_results(req_id, results))
+
+    async def _send_error(self, send, req_id: int, message: str) -> None:
+        self.error_frames += 1
+        await self._send_guarded(send, encode_error(req_id, message))
 
     async def _send_guarded(self, send, payload: bytes) -> None:
         # the client vanishing mid-response is its problem, not ours —
@@ -1175,19 +1195,23 @@ def run_service(
     ready_fd: Optional[int] = None,
     trace: bool = False,
 ) -> int:
-    """Blocking service runtime for the CLI entrypoint: build the
-    scheduler (which builds the process verifier/mesh on first
-    dispatch), optionally AOT-prewarm the bucket ladder, serve until
-    SIGINT/SIGTERM. `ready_fd` (harness use) gets one JSON line
-    ({"ready": true, "stats_port": N}) written when the socket is
-    accepting — spawners wait on it instead of polling. `trace` (or
-    TM_TPU_TRACE=1) arms the service flight ring served at
+    """Blocking service runtime for the CLI entrypoint: open the device
+    (this process owns it — a chip that cannot be opened fails the
+    start, not the first round), build the scheduler, optionally
+    AOT-prewarm the bucket ladder (a failed prewarm fails the start
+    too), serve until SIGINT/SIGTERM. `ready_fd` (harness use) gets
+    one JSON line ({"ready": true, "stats_port": N}) written when the
+    socket is accepting — spawners wait on it instead of polling.
+    `trace` (or TM_TPU_TRACE=1) arms the service flight ring served at
     GET /dump_traces on the stats port."""
     import signal
 
     from ..obs import Tracer, set_default_tracer
 
     logger = logger or nop_logger()
+    configure_compile_cache()
+    compile_log()  # listen from the first compile on
+    logger.info("verify service device", **device_info())
     tracer = set_default_tracer(
         Tracer(enabled=trace or os.environ.get("TM_TPU_TRACE") == "1")
     )
@@ -1198,31 +1222,28 @@ def run_service(
 
     async def run() -> None:
         await server.start()
-        if prewarm:
-            try:
+        try:
+            if prewarm:
                 entries = server.scheduler.verifier.prewarm_buckets()
                 logger.info(
                     "verify-service prewarm complete",
                     programs=len(entries),
                 )
-            except Exception as e:
-                logger.error("verify-service prewarm failed", err=repr(e))
-        if ready_fd is not None:
-            os.write(
-                ready_fd,
-                json.dumps(
-                    {"ready": True, "stats_port": server.stats_port}
-                ).encode(),
-            )
-            os.close(ready_fd)
-        stop = asyncio.Event()
-        loop = asyncio.get_running_loop()
-        for sig in (signal.SIGINT, signal.SIGTERM):
-            try:
-                loop.add_signal_handler(sig, stop.set)
-            except (NotImplementedError, RuntimeError):
-                pass
-        try:
+            if ready_fd is not None:
+                os.write(
+                    ready_fd,
+                    json.dumps(
+                        {"ready": True, "stats_port": server.stats_port}
+                    ).encode(),
+                )
+                os.close(ready_fd)
+            stop = asyncio.Event()
+            loop = asyncio.get_running_loop()
+            for sig in (signal.SIGINT, signal.SIGTERM):
+                try:
+                    loop.add_signal_handler(sig, stop.set)
+                except (NotImplementedError, RuntimeError):
+                    pass
             await stop.wait()
         finally:
             await server.stop()

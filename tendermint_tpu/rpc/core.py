@@ -183,7 +183,7 @@ class RPCCore:
             raise RPCError(
                 -32602, "invalid entries: not an integer"
             ) from None
-        return {
+        out = {
             "enabled": sched is not None,
             "summary": ledger.summary(),
             # entries <= 0 means "summary only" (ledger.entries treats
@@ -192,6 +192,13 @@ class RPCCore:
             "entries": ledger.entries(limit=n) if n > 0 else [],
             "shape_registry": default_shape_registry().snapshot(),
         }
+        # a verify-service client books its rounds on the SERVICE's
+        # ledger; what this side can say is how the IPC went (attaches,
+        # degrades to local verify)
+        ipc_stats = getattr(sched, "ipc_stats", None)
+        if ipc_stats is not None:
+            out["ipc"] = ipc_stats()
+        return out
 
     def profile_start(self, label="", device=True, **_kw) -> dict:
         """Arm an on-demand profiling session: a jax device trace

@@ -235,7 +235,8 @@ def test_multichip_capture_forced_host_4dev(tmp_path):
     """tools/multichip_capture.py end-to-end in a child process forced
     to 4 host devices: the artifact's series covers 1/2/4 devices from
     the scheduler dispatch path, sharded rounds recorded, meta stamps
-    the cpu backend (a fallback row can never pass as a device row)."""
+    the cpu platform (the explicit JAX_PLATFORMS=cpu is what lets the
+    capture run without a chip at all)."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     flags = env.get("XLA_FLAGS", "")
@@ -266,7 +267,8 @@ def test_multichip_capture_forced_host_4dev(tmp_path):
     assert r.returncode == 0, r.stdout + r.stderr
     art = json.loads(r.stdout.strip().splitlines()[-1])
     assert art["ok"], art
-    assert art["meta"]["backend"] == "cpu"
+    assert art["meta"]["platform"] == "cpu"
+    assert art["meta"]["device_kind"]
     assert art["meta"]["device_count"] == 4
     devs = [s["devices"] for s in art["series"]]
     assert devs == [1, 2, 4]

@@ -214,6 +214,14 @@ async def _run_node(
 
 
 def run_worker(args) -> int:
+    # a worker is a CLIENT of the service, which owns the chip: its
+    # degrade path (RemoteVerifyScheduler's local fallback verifier)
+    # must resolve to the CPU platform, like a node's beside a service
+    from tendermint_tpu.libs.device import pin_cpu
+    from tendermint_tpu.libs.jax_cache import configure_compile_cache
+
+    pin_cpu()
+    configure_compile_cache()
     ed_items, bls_items = committee_fixture(args.validators)
     out = {"nodes": [], "error": None}
 
