@@ -1,0 +1,96 @@
+"""One tiny cell through run.py on the CPU: the result line's keys, a
+run with no chip, and a run with the timed path broken underneath."""
+
+import argparse
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+from conftest import BENCH_DIR, ROOT
+
+REHEARSAL = os.path.join(BENCH_DIR, "tests", "BENCHMARK.rehearsal.json")
+RUN = [
+    sys.executable, os.path.join(BENCH_DIR, "run.py"),
+    "--seed", "2147483999", "--seconds", "1",
+    "--benchmark-file", REHEARSAL,
+]
+
+
+def run(workload, trace, env):
+    return subprocess.run(
+        RUN + ["--workload", workload, "--trace", str(trace)],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=600,
+    )
+
+
+def cpu_env():
+    return dict(os.environ, JAX_PLATFORMS="cpu")
+
+
+@pytest.mark.parametrize(
+    "workload,trace,metric",
+    [
+        ("rehearsal.catchup", 0, "catchup_commits_per_s"),
+        ("rehearsal.live", 1, "queue_wait_ms.live"),
+    ],
+)
+def test_result_line_has_the_contracts_keys(workload, trace, metric):
+    done = run(workload, trace, cpu_env())
+    assert done.returncode == 0, done.stderr[-2000:]
+    line = json.loads(done.stdout.strip().splitlines()[-1])
+    assert list(line)[:5] == [
+        "correct", "attempted", "failed", "metrics", "device"
+    ]
+    assert list(line)[-1] == "checks"
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] > 0
+    assert line["device"]["platform"] == "cpu"  # never under a chip's name
+    assert set(line["device"]) == {
+        "platform", "kind", "count", "memory_peak_bytes"
+    }
+    assert metric in line["metrics"]
+    assert ("setup_s" in line["metrics"]) == (trace == 0)
+    for m in line["metrics"].values():
+        assert set(m) == {"value", "unit"} and m["value"] > 0
+    assert all(c["value"] <= c["limit"] for c in line["checks"].values())
+    stderr = done.stderr.strip().splitlines()
+    assert stderr[-1] == "correct True"
+    assert stderr[-2].startswith("check ")
+    # a share of a roofline or of the device is left out, never 0, where
+    # there was no device trace to read
+    assert not any("roofline" in k or "idle" in k for k in line["metrics"])
+
+
+def test_no_chip_no_result():
+    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
+    done = run("rehearsal.catchup", 0, env)
+    assert done.returncode != 0
+    assert done.stdout.strip() == ""
+
+
+def test_an_answer_altered_where_it_is_produced_is_not_correct(capsys):
+    sys.path.insert(0, BENCH_DIR)
+    import run as bench_run
+    from harness import procs
+    from harness.cell import Cell
+
+    def broken(work, sock, wfd, trace):
+        real = procs.service_command(work, sock, wfd, False)
+        return [
+            sys.executable,
+            os.path.join(BENCH_DIR, "tests", "broken_service.py"), ROOT,
+        ] + real[4:]
+
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    args = argparse.Namespace(
+        workload="rehearsal.catchup", seed=77, seconds=1.0, trace=0,
+        control_guarantee="", rate=0.0, sweep="", benchmark_file=REHEARSAL,
+    )
+    args.cell = Cell(args.workload, REHEARSAL)
+    report = bench_run.run_cell(args, service_command=broken)
+    numbers = report["numbers"]
+    assert numbers["rows_wrong"] >= len(report["requests"])
+    assert numbers["requests_failed"] == 0
