@@ -41,6 +41,7 @@ Responsibilities:
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from dataclasses import dataclass
@@ -97,6 +98,20 @@ TABLE_ROWS_MIN = 128
 # unseen keys are built this many at a time: big-tier tables are 128 KiB
 # each, so building thousands of keys at once would transiently hold GiBs
 TABLE_BUILD_CHUNK = 512
+
+
+@contextlib.contextmanager
+def _traced(name: str, **fields):
+    """With the tracer on: a span `name` on its ring and, for whenever
+    a profiler session is open, a `jax.profiler.TraceAnnotation` of the
+    same name in the profiler's own trace, on its clock. Yields whether
+    the tracer is on. Off: nothing but the attribute read."""
+    tracer = default_tracer()
+    if not tracer.enabled:
+        yield False
+        return
+    with tracer.span(name, **fields), jax.profiler.TraceAnnotation(name):
+        yield True
 
 
 def _bucket(n: int, multiple_of: int = 1) -> int:
@@ -233,7 +248,7 @@ class _TableCache:
 
     def __init__(
         self, lock, build_fn, entry_shape, capacity, nshards, registry=None,
-        tier="build",
+        tier="small",
     ):
         self._lock = lock
         self._build_fn = build_fn
@@ -241,10 +256,11 @@ class _TableCache:
         self._capacity = capacity
         self._nshards = nshards
         self._registry = registry or default_shape_registry()
-        self._tier = tier
+        self.tier = tier
         self._idx: dict[bytes, int] = {}
         self.tables: jnp.ndarray | None = None
         self.valid: jnp.ndarray | None = None
+        self.built = 0  # keys a table was built for, ever
 
     def _grow(self, needed_rows: int) -> None:
         rows = TABLE_ROWS_MIN
@@ -296,22 +312,33 @@ class _TableCache:
                 # builds always shard over the full mesh (batch_verifier
                 # compiles the build fns with sharded inputs)
                 self._registry.record_dispatch(
-                    self._tier, b, devices=self._nshards
+                    "build_" + self.tier, b, devices=self._nshards
                 )
                 arr = np.zeros((b, 32), dtype=np.uint8)
                 for i, pk in enumerate(chunk):
                     arr[i] = np.frombuffer(pk, dtype=np.uint8)
-                tables, valid = self._build_fn(jnp.asarray(arr))
-                rows = []
-                for pk in chunk:
-                    row = len(self._idx)
-                    self._idx[pk] = row
-                    rows.append(row)
-                rows_j = jnp.asarray(np.asarray(rows, dtype=np.int32))
-                self.tables = self.tables.at[rows_j].set(
-                    tables[: len(chunk)]
-                )
-                self.valid = self.valid.at[rows_j].set(valid[: len(chunk)])
+                with _traced(
+                    "crypto.table_build",
+                    keys=len(chunk), bucket=b, tier=self.tier,
+                ) as traced:
+                    tables, valid = self._build_fn(jnp.asarray(arr))
+                    rows = []
+                    for pk in chunk:
+                        row = len(self._idx)
+                        self._idx[pk] = row
+                        rows.append(row)
+                    rows_j = jnp.asarray(np.asarray(rows, dtype=np.int32))
+                    self.tables = self.tables.at[rows_j].set(
+                        tables[: len(chunk)]
+                    )
+                    self.valid = self.valid.at[rows_j].set(
+                        valid[: len(chunk)]
+                    )
+                    self.built += len(chunk)
+                    if traced:
+                        # the span is the build, not its enqueue (a
+                        # build is rare: a restart, a new validator)
+                        self.tables.block_until_ready()
             return True
 
     def snapshot(self, row_pubkeys: list[tuple[int, bytes]], b: int):
@@ -451,7 +478,7 @@ class BatchVerifier:
             table_cache_capacity,
             self._nshards,
             registry=self._registry,
-            tier="build_small",
+            tier="small",
         )
         self._big = _TableCache(
             threading.Lock(),
@@ -460,7 +487,7 @@ class BatchVerifier:
             table_cache_capacity,
             self._nshards,
             registry=self._registry,
-            tier="build_big",
+            tier="big",
         )
 
     # --- mesh topology -----------------------------------------------------
@@ -666,21 +693,37 @@ class BatchVerifier:
         first = key not in self._seen_shapes
         self._seen_shapes.add(key)
         self._registry.record_dispatch(tier, b, rows, devices=devices)
-        tracer = default_tracer()
-        if not tracer.enabled:
-            return np.asarray(fn(*args))
-        t0 = time.perf_counter()
-        out = np.asarray(fn(*args))  # blocks until device-ready
-        tracer.add_span(
+        with _traced(
             "crypto.jit_compile" if first else "crypto.device_execute",
-            t0,
-            time.perf_counter() - t0,
-            batch=n,
-            bucket=b,
-            tier=tier,
-            devices=devices,
-        )
-        return out
+            batch=n, bucket=b, tier=tier, devices=devices,
+        ):
+            return np.asarray(fn(*args))  # blocks until device-ready
+
+    def _table_lookup(self, cache, items, rows, b: int, n: int):
+        """One round's way to its tables: (tables, valid, idx[b]) for
+        the well-formed `rows` of `items` from `cache`, or None where
+        the cache cannot hold the batch. Two attempts: a concurrent
+        verify() can trigger the cache-reset path between ensure() and
+        snapshot(), evicting our rows; on a second miss the caller
+        falls through to the generic path rather than mis-rejecting (or
+        crashing on) valid signatures. Traced as `crypto.table_lookup`,
+        around the `crypto.table_build` of every chunk it had to
+        build."""
+        built = cache.built
+        snap = None
+        with default_tracer().span(
+            "crypto.table_lookup", n=n, tier=cache.tier
+        ) as span:
+            row_pubkeys = [(i, items[i].pubkey) for i in rows]
+            pubkeys = [pk for _, pk in row_pubkeys]
+            for _ in range(2):
+                if not cache.ensure(pubkeys):
+                    break  # cache cannot hold this batch
+                snap = cache.snapshot(row_pubkeys, b)
+                if snap is not None:
+                    break
+            span.set(built=cache.built - built)
+        return snap
 
     def verify(self, items: list[SigItem]) -> np.ndarray:
         """Returns a bool accept bitmap aligned with `items`.
@@ -832,18 +875,10 @@ class BatchVerifier:
         family = self._progs.get(devs) or self._progs[1]
 
         def _run_device() -> np.ndarray:
-            cache = self._big if big else self._small
-            row_pubkeys = [(i, items[i].pubkey) for i in well_formed]
-            # Two attempts: a concurrent verify() can trigger the
-            # cache-reset path between ensure() and snapshot(), evicting
-            # our rows; on a second miss fall through to the generic path
-            # rather than mis-rejecting (or crashing on) valid signatures.
-            for _ in range(2):
-                if not cache.ensure([pk for _, pk in row_pubkeys]):
-                    break  # cache cannot hold this batch: generic path
-                snap = cache.snapshot(row_pubkeys, b)
-                if snap is None:
-                    continue
+            snap = self._table_lookup(
+                self._big if big else self._small, items, well_formed, b, n
+            )
+            if snap is not None:
                 tables, tvalid, idx = snap
                 if device_hash:
                     out = self._dispatch(

@@ -6,9 +6,12 @@ anomaly shows up mid-run, the operator starts a bounded profile
 against the RUNNING node, pulls the artifacts from `data/profiles/`,
 and keeps serving. Two captures per session:
 
-- **device trace**: `jax.profiler.start_trace(dir)` when the jax
-  profiler is importable and startable — guarded, CPU-backend tolerant
-  (the CPU backend records a host-side XPlane trace; a missing/broken
+- **device trace**: a jax profiler session (the session object
+  itself where this JAX has it, else `jax.profiler.start_trace(dir)`)
+  when the jax profiler is importable and startable; the trace lands
+  as `<dir>/plugins/profile/*/*.xplane.pb` — guarded, CPU-backend
+  tolerant (the CPU backend records a host-side XPlane trace; a
+  missing/broken
   profiler degrades to a structured `{"enabled": false, "error": ...}`
   in the session record, never an exception out of the RPC);
 - **sampled event-loop profile**: a daemon thread samples the event
@@ -99,6 +102,7 @@ class ProfileCapture:
         self._session: Optional[dict] = None
         self._sampler: Optional[_StackSampler] = None
         self._device_tracing = False
+        self._raw_session = None
         self._next_id = 1
 
     @property
@@ -107,10 +111,20 @@ class ProfileCapture:
 
     # --- session lifecycle -----------------------------------------------
 
-    def start(self, label: str = "", device: bool = True) -> dict:
+    def start(
+        self,
+        label: str = "",
+        device: bool = True,
+        thread_id: Optional[int] = None,
+        python_tracer: bool = True,
+    ) -> dict:
         """Arm a session. `device=False` skips the jax trace (loop
-        profile only). Raises ProfilerUnavailable when a session is
-        already running."""
+        profile only). `thread_id` is the thread whose stack is sampled
+        (the event loop's, for a caller that starts the session from
+        another thread; default: the calling thread). `python_tracer`
+        False keeps the jax trace to device and XLA host events, where
+        this JAX has profiler options. Raises ProfilerUnavailable when
+        a session is already running."""
         with self._lock:
             if self._session is not None:
                 raise ProfilerUnavailable(
@@ -123,9 +137,11 @@ class ProfileCapture:
             os.makedirs(session_dir, exist_ok=True)
             device_state = {"enabled": False}
             if device:
-                device_state = self._start_device_trace(session_dir)
+                device_state = self._start_device_trace(
+                    session_dir, python_tracer
+                )
             sampler = _StackSampler(
-                threading.get_ident(), self.sample_interval_s
+                thread_id or threading.get_ident(), self.sample_interval_s
             )
             sampler.start()
             self._sampler = sampler
@@ -157,7 +173,8 @@ class ProfileCapture:
         )
         if self._device_tracing:
             session["device_trace"] = dict(
-                session["device_trace"], **self._stop_device_trace()
+                session["device_trace"],
+                **self._stop_device_trace(session["dir"]),
             )
         if sampler is not None:
             sampler.stop()
@@ -168,11 +185,36 @@ class ProfileCapture:
 
     # --- device trace (guarded jax) ---------------------------------------
 
-    def _start_device_trace(self, session_dir: str) -> dict:
+    def _start_device_trace(
+        self, session_dir: str, python_tracer: bool = True
+    ) -> dict:
+        """Open the jax profiler session. Where this JAX has the
+        session object itself, hold it: its `stop()` hands back the
+        trace as it is, while `jax.profiler.stop_trace` also exports
+        it, which on a TPU took 44 s for a 5-second span of bulk rounds
+        and had not ended after 117 s for 3 seconds of small-tier
+        rounds."""
         try:
             import jax
 
-            jax.profiler.start_trace(session_dir)
+            options = getattr(jax.profiler, "ProfileOptions", None)
+            if options is not None:
+                options = options()
+                if not python_tracer:
+                    options.python_tracer_level = 0
+            try:
+                from jax._src.lib import _profiler
+            except ImportError:
+                _profiler = None
+            if options is None:
+                jax.profiler.start_trace(session_dir)
+            elif _profiler is None:
+                jax.profiler.start_trace(
+                    session_dir, profiler_options=options
+                )
+            else:
+                jax.devices()  # the backend is up before a session starts
+                self._raw_session = _profiler.ProfilerSession(options)
         except Exception as e:  # missing jax, no backend, double-trace
             if self.logger is not None:
                 self.logger.error(
@@ -182,19 +224,31 @@ class ProfileCapture:
         self._device_tracing = True
         return {"enabled": True, "dir": session_dir}
 
-    def _stop_device_trace(self) -> dict:
+    def _stop_device_trace(self, session_dir: str) -> dict:
         self._device_tracing = False
+        raw, self._raw_session = self._raw_session, None
         try:
-            import jax
+            if raw is None:
+                import jax
 
-            jax.profiler.stop_trace()
+                jax.profiler.stop_trace()
+                return {}
+            # where `jax.profiler.stop_trace` would have put it
+            out = os.path.join(
+                session_dir, "plugins", "profile",
+                os.path.basename(session_dir),
+            )
+            os.makedirs(out, exist_ok=True)
+            path = os.path.join(out, "host.xplane.pb")
+            with open(path, "wb") as f:
+                f.write(raw.stop())
+            return {"xplane": path, "bytes": os.path.getsize(path)}
         except Exception as e:
             if self.logger is not None:
                 self.logger.error(
                     "device trace stop failed", err=repr(e)
                 )
             return {"stop_error": repr(e)[:400]}
-        return {}
 
     # --- loop profile -----------------------------------------------------
 

@@ -105,6 +105,10 @@ class _Span:
         self._tok = _stack.set(_stack.get() + (self.name,))
         return self
 
+    def set(self, **fields) -> None:
+        """Fields known only once the block has run."""
+        self.fields.update(fields)
+
     def __exit__(self, *exc):
         if self._tok is not None:
             _stack.reset(self._tok)
@@ -127,6 +131,9 @@ class _NopSpan:
 
     def __enter__(self):
         return self
+
+    def set(self, **fields) -> None:
+        pass
 
     def __exit__(self, *exc):
         return False

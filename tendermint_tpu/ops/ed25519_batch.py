@@ -22,6 +22,11 @@ host-computed input mask (`s_ok`).
 
 Verification equation (cofactorless, matching Go x/crypto semantics):
     [s]B == R + [k]A   ⇔   encode([s]B + [k](-A)) == R_bytes
+
+The stages of the cached programs carry `jax.named_scope` names that a
+trace viewer shows and a refactor keeps: `base_mult` ([s]B), `key_mult`
+([k](-A) from the big cache), `double_mult` (the small tier's fused
+pair) and `compress` (the final add and the encoding).
 """
 
 from __future__ import annotations
@@ -71,8 +76,10 @@ def verify_prehashed_table(
     s_ok: jnp.ndarray,  # [B] bool
 ) -> jnp.ndarray:
     """Returns [B] bool accept bitmap (cached-pubkey hot path)."""
-    q = curve.double_scalar_mult_base_table(s_bytes, k_bytes, tables)
-    encoded = curve.compress(q)
+    with jax.named_scope("double_mult"):
+        q = curve.double_scalar_mult_base_table(s_bytes, k_bytes, tables)
+    with jax.named_scope("compress"):
+        encoded = curve.compress(q)
     r_match = jnp.all(encoded == r_bytes, axis=-1)
     return table_valid & s_ok & r_match
 
@@ -103,11 +110,12 @@ def verify_prehashed_bigcache(
     s_ok: jnp.ndarray,  # [B] bool
 ) -> jnp.ndarray:
     """The BatchVerifier steady-state path: doubling-free, cache-resident."""
-    q = curve.add(
-        curve.scalar_mult_base(s_bytes),
-        curve.scalar_mult_var_bigcache(k_bytes, tables_cache, idx),
-    )
-    encoded = curve.compress(q)
+    with jax.named_scope("base_mult"):
+        base = curve.scalar_mult_base(s_bytes)
+    with jax.named_scope("key_mult"):
+        key = curve.scalar_mult_var_bigcache(k_bytes, tables_cache, idx)
+    with jax.named_scope("compress"):
+        encoded = curve.compress(curve.add(base, key))
     r_match = jnp.all(encoded == r_bytes, axis=-1)
     return table_valid & s_ok & r_match
 
@@ -124,11 +132,12 @@ def verify_prehashed_bigcache_mxu(
     """verify_prehashed_bigcache with the table lookups as one-hot MXU
     matmuls (curve.scalar_mult_var_bigcache_mxu) — the real-silicon
     variant; select via TM_TPU_MXU_GATHER=1 (see the kernel docstring)."""
-    q = curve.add(
-        curve.scalar_mult_base(s_bytes),
-        curve.scalar_mult_var_bigcache_mxu(k_bytes, tables_cache, idx),
-    )
-    encoded = curve.compress(q)
+    with jax.named_scope("base_mult"):
+        base = curve.scalar_mult_base(s_bytes)
+    with jax.named_scope("key_mult"):
+        key = curve.scalar_mult_var_bigcache_mxu(k_bytes, tables_cache, idx)
+    with jax.named_scope("compress"):
+        encoded = curve.compress(curve.add(base, key))
     r_match = jnp.all(encoded == r_bytes, axis=-1)
     return table_valid & s_ok & r_match
 

@@ -56,13 +56,17 @@ Wire format (all integers big-endian):
     SUBMIT_FN(3)    body := str8 klass | str8 engine | u32 n | n * item
                     | [ctx]
     item            := u8 nparts | nparts * bytes32
-    ctx             := u64 height | u32 round | str8 origin
+    ctx             := u64 height | u32 round | str8 origin | [stamps]
                     (optional trailer: clients stamp the consensus
                     height in progress + their identity so the service
                     records queue/dispatch/device sub-spans under the
                     submitter's span context; a decoder that stops at
                     the last item ignores it, so old servers accept new
                     clients and vice versa)
+    stamps          := u64 t_submit_ns | u64 t_encoded_ns
+                    (optional within the trailer: the client's clock at
+                    the entry of its submit and once the items were
+                    encoded; see SHARED_CLOCK)
     FN_RESULTS(4)   body := u32 n | n * (u8 tag | [u32 len | bytes])
                     tag: 0=False 1=True 2=None 3=bytes
     PING(5)/PONG(6) body := opaque (echoed verbatim)
@@ -83,6 +87,7 @@ import os
 import struct
 import threading
 import time
+import urllib.parse
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Optional
 
@@ -101,6 +106,7 @@ from ..libs.metrics import (
 )
 from ..obs import default_tracer
 from ..obs.ledger import default_ledger
+from ..obs.profiler import ProfileCapture, ProfilerUnavailable
 from .scheduler import VerifyScheduler, _ClassedVerifier
 
 MSG_SUBMIT = 1
@@ -125,6 +131,7 @@ _U16 = struct.Struct(">H")
 _U32 = struct.Struct(">I")
 _U64 = struct.Struct(">Q")
 _HDR = struct.Struct(">BQ")  # type, request_id
+_STAMPS = struct.Struct(">QQ")  # t_submit_ns, t_encoded_ns
 
 
 # Frame decode violations (cap, truncation, unknown tag) share the
@@ -196,14 +203,43 @@ class _Cursor:
         return self.take(self.u32())
 
 
+# The stamps' clock. Client and service share a host by construction (a
+# unix socket), and on Linux `time.perf_counter` is the system-wide
+# CLOCK_MONOTONIC: one reading means the same instant in both processes,
+# and it is the clock obs/tracer.py already records on. Where
+# perf_counter is another clock the client stamps nothing and the
+# service reads no stamp.
+SHARED_CLOCK = "CLOCK_MONOTONIC" in time.get_clock_info(
+    "perf_counter"
+).implementation
+
+# a stamp older than this (or from the future) is another clock's
+STAMP_MAX_AGE_S = 60.0
+
+# GET /profile_start?seconds=N on the stats port: a session closes by
+# itself after N seconds
+PROFILE_DEFAULT_S = 5.0
+PROFILE_MAX_S = 30.0
+
+# records of the standalone service's ring: a request leaves about 17
+# (its way in and out, queue, prep, lookup, device), so this holds a
+# 30-second window of 56 requests a second more than twice
+SERVICE_RING_SIZE = 65536
+
+
 def _put_trace_ctx(out: list, ctx) -> None:
-    """Optional trace-context trailer: (height, round, origin)."""
+    """Optional trace-context trailer: (height, round, origin) and,
+    where the client read one, its `t_submit_ns`. The second stamp,
+    `t_encoded_ns`, is read here: the trailer stands at the END of the
+    frame so that it is written once the items are encoded."""
     if ctx is None:
         return
-    height, round_, origin = ctx
+    height, round_, origin, *stamp = ctx
     out.append(_U64.pack(max(0, int(height))))
     out.append(_U32.pack(max(0, int(round_))))
     _put_str8(out, str(origin))
+    if stamp and stamp[0] is not None:
+        out.append(_STAMPS.pack(stamp[0], time.perf_counter_ns()))
 
 
 def decode_trace_ctx(cur: _Cursor, req_id: int):
@@ -216,6 +252,16 @@ def decode_trace_ctx(cur: _Cursor, req_id: int):
     round_ = cur.u32()
     origin = cur.str8()
     return (height, round_, origin, req_id)
+
+
+def decode_trace_stamps(cur: _Cursor):
+    """(t_submit_s, t_encoded_s) after the trailer's three fields, in
+    seconds of the shared clock, or None: a frame with no trailer or
+    with the three-field trailer of an older client carries none."""
+    if not SHARED_CLOCK or len(cur.buf) - cur.off < _STAMPS.size:
+        return None
+    t_submit, t_encoded = _STAMPS.unpack(cur.take(_STAMPS.size))
+    return t_submit * 1e-9, t_encoded * 1e-9
 
 
 def encode_submit(
@@ -365,6 +411,21 @@ from .engines import (  # noqa: E402,F401
 # --- the server -------------------------------------------------------------
 
 
+class _WayIn:
+    """What `_handle_conn` knows of a submission's way in, taken once
+    the trailer is decoded: the frame's size, when the whole frame was
+    held, when its decode ended, and the client's stamps if it sent
+    any."""
+
+    __slots__ = ("nbytes", "t_frame", "t_decoded", "stamps")
+
+    def __init__(self, cur: _Cursor, t_frame: float):
+        self.nbytes = len(cur.buf)
+        self.t_frame = t_frame
+        self.stamps = decode_trace_stamps(cur)
+        self.t_decoded = time.perf_counter()
+
+
 class VerifyServiceServer:
     """Owns the scheduler/device plane and serves the UDS protocol.
 
@@ -425,8 +486,22 @@ class VerifyServiceServer:
         # verifying locally, so this count is where a failing device
         # shows
         self.error_frames = 0
+        # GET /profile_start | /profile_stop on the stats port: only the
+        # process that holds the chip can trace it. One thread starts
+        # and stops every session, never the event loop (exporting a
+        # trace takes seconds to tens of seconds)
+        self.profiler = ProfileCapture(
+            path + ".profiles", logger=self.logger
+        )
+        self._profile_pool = ThreadPoolExecutor(
+            1, thread_name_prefix="verify-profile"
+        )
+        self._profile_timer: Optional[asyncio.Task] = None
+        self._last_profile: Optional[dict] = None
+        self._loop_thread = 0
 
     async def start(self) -> None:
+        self._loop_thread = threading.get_ident()
         if not self.scheduler.running:
             await self.scheduler.start()
         # a stale socket file from a crashed predecessor refuses bind
@@ -464,6 +539,9 @@ class VerifyServiceServer:
                 await t
             except (asyncio.CancelledError, Exception):
                 pass
+        if self.profiler.active:
+            await self._profile_stop()
+        self._profile_pool.shutdown(wait=False)
         await self.scheduler.stop()
         try:
             os.unlink(self.path)
@@ -515,6 +593,66 @@ class VerifyServiceServer:
             "peer_clock": {},
             "records": [r.to_json() for r in tracer.records()],
         }
+
+    # --- device profiler (GET /profile_start, /profile_stop) -------------
+
+    async def _profile_start(self, query: dict) -> tuple[int, dict]:
+        """Open a profiler session in this process (device and XLA host
+        events, the Python tracer off; the event loop's stack sampled
+        beside it) that stops by itself after `seconds` (default
+        PROFILE_DEFAULT_S, at most PROFILE_MAX_S). One at a time."""
+        try:
+            seconds = float(query.get("seconds", PROFILE_DEFAULT_S))
+        except ValueError:
+            return 400, {"error": "seconds is not a number"}
+        seconds = min(PROFILE_MAX_S, max(0.0, seconds))
+        loop = asyncio.get_running_loop()
+        try:
+            started = await loop.run_in_executor(
+                self._profile_pool,
+                lambda: self.profiler.start(
+                    label=query.get("label", ""),
+                    thread_id=self._loop_thread,
+                    python_tracer=False,
+                ),
+            )
+        except ProfilerUnavailable as e:
+            return 409, {"error": str(e)}
+        self._trace().event(
+            "profiler.start", session=started["id"], seconds=seconds
+        )
+        self._profile_timer = loop.create_task(self._stop_after(seconds))
+        return 200, {"started": True, "seconds": seconds, **started}
+
+    async def _stop_after(self, seconds: float) -> None:
+        await asyncio.sleep(seconds)
+        self._profile_timer = None  # this task must not cancel itself
+        await self._profile_stop()
+
+    async def _profile_stop(self) -> tuple[int, dict]:
+        """Close the session and write its trace; `stop_s` is what that
+        took. With none open: 409, beside the last session's record."""
+        timer, self._profile_timer = self._profile_timer, None
+        if timer is not None:
+            timer.cancel()
+        t0 = time.perf_counter()
+        try:
+            session = await asyncio.get_running_loop().run_in_executor(
+                self._profile_pool, self.profiler.stop
+            )
+        except ProfilerUnavailable as e:
+            return 409, {"error": str(e), "last": self._last_profile}
+        session["stop_s"] = round(time.perf_counter() - t0, 3)
+        self._last_profile = session
+        self._trace().event(
+            "profiler.stop", session=session["id"], dir=session["dir"],
+            duration_s=session["duration_s"], stop_s=session["stop_s"],
+        )
+        self.logger.info(
+            "profile session written", dir=session["dir"],
+            duration_s=session["duration_s"], stop_s=session["stop_s"],
+        )
+        return 200, session
 
     # --- UDS protocol ------------------------------------------------------
 
@@ -568,11 +706,13 @@ class VerifyServiceServer:
                 frame = await read_frame(reader)
                 if frame is None:
                     break
+                t_frame = time.perf_counter()
                 cur = _Cursor(frame)
                 typ, req_id = _HDR.unpack(cur.take(_HDR.size))
                 if typ == MSG_SUBMIT:
                     items, klass = decode_submit(cur)
                     ctx = decode_trace_ctx(cur, req_id)
+                    way_in = _WayIn(cur, t_frame)
                     stats["submissions"] += 1
                     stats["rows"] += len(items)
                     # create_task here, synchronously in read order:
@@ -580,16 +720,20 @@ class VerifyServiceServer:
                     # enqueues before its first await point, so one
                     # client's submissions keep FIFO within their class
                     spawn(
-                        self._do_submit(send, req_id, items, klass, ctx)
+                        self._do_submit(
+                            send, req_id, items, klass, ctx, way_in
+                        )
                     )
                 elif typ == MSG_SUBMIT_FN:
                     engine, items, klass = decode_submit_fn(cur)
                     ctx = decode_trace_ctx(cur, req_id)
+                    way_in = _WayIn(cur, t_frame)
                     stats["fn_submissions"] += 1
                     stats["fn_items"] += len(items)
                     spawn(
                         self._do_submit_fn(
-                            send, req_id, engine, items, klass, ctx
+                            send, req_id, engine, items, klass, ctx,
+                            way_in,
                         )
                     )
                 elif typ == MSG_PING:
@@ -626,31 +770,82 @@ class VerifyServiceServer:
                 self.client_stats.pop(client, None)
             writer.close()
 
-    def _service_span(self, ctx, t_recv: float, n: int, klass: str) -> None:
-        """End-to-end service-side span for one traced submission
-        (decode -> verdicts encoded); the queue/device slices inside it
-        are recorded by the scheduler under the same ctx."""
+    def _span(self, name, ctx, t0: float, t1: float, **fields) -> None:
+        """One span of a traced submission on the service's ring, under
+        the submitter's context. A frame with no trailer has no `req`
+        to join its spans by and records none."""
         if ctx is None:
             return
         height, round_, origin, req = ctx
         self._trace().add_span(
-            "verify.service", t_recv, time.perf_counter() - t_recv,
-            height=height, round=round_, origin=origin, req=req,
-            n=n, klass=klass,
+            name, t0, t1 - t0,
+            height=height, round=round_, origin=origin, req=req, **fields,
         )
 
-    async def _do_submit(self, send, req_id, items, klass, ctx=None):
+    def _way_in_spans(self, ctx, way_in, t_recv: float, **fields) -> None:
+        """The submission's way from the client's `submit` to here:
+        `verify.ingress` (the client's t_submit -> t_recv) around
+        `verify.client_encode`, `verify.wire_in` (encoded -> the whole
+        frame held) and `verify.frame_decode`. Stamps that are not of
+        this host's clock (in its future, or STAMP_MAX_AGE_S old) are
+        dropped with the spans that need them; the decode is the
+        service's own and stays."""
+        if not self._trace().enabled:
+            return
+        stamps = way_in.stamps
+        if stamps is not None and not (
+            t_recv - STAMP_MAX_AGE_S <= stamps[0] <= stamps[1]
+            <= way_in.t_frame
+        ):
+            stamps = None
+        if stamps is not None:
+            t_submit, t_encoded = stamps
+            self._span("verify.ingress", ctx, t_submit, t_recv, **fields)
+            fields["parent"] = "verify.ingress"
+            self._span(
+                "verify.client_encode", ctx, t_submit, t_encoded, **fields
+            )
+            self._span(
+                "verify.wire_in", ctx, t_encoded, way_in.t_frame, **fields
+            )
+        self._span(
+            "verify.frame_decode", ctx, way_in.t_frame, way_in.t_decoded,
+            **fields,
+        )
+
+    async def _answer(
+        self, send, encode, req_id, result, ctx, t_recv: float, **fields
+    ) -> None:
+        """`verify.service` (t_recv -> the answer is ready; what the
+        benchmark's `ipc_overhead` subtracts, so its ends stay where
+        they are), then `verify.reply`: the reply frame encoded,
+        written and drained."""
+        t_ready = time.perf_counter()
+        self._span("verify.service", ctx, t_recv, t_ready, **fields)
+        payload = encode(req_id, result)
+        await self._send_guarded(send, payload)
+        fields["bytes"] = len(payload)
+        self._span(
+            "verify.reply", ctx, t_ready, time.perf_counter(), **fields
+        )
+
+    async def _do_submit(
+        self, send, req_id, items, klass, ctx, way_in
+    ):
         t_recv = time.perf_counter()
+        fields = {"n": len(items), "klass": klass, "bytes": way_in.nbytes}
+        self._way_in_spans(ctx, way_in, t_recv, **fields)
         try:
             verdicts = await self.scheduler.submit(items, klass, ctx=ctx)
         except Exception as e:
             await self._send_error(send, req_id, f"verify failed: {e!r}")
             return
-        self._service_span(ctx, t_recv, len(items), klass)
-        await self._send_guarded(send, encode_verdicts(req_id, verdicts))
+        await self._answer(
+            send, encode_verdicts, req_id, verdicts, ctx, t_recv, **fields
+        )
 
     async def _do_submit_fn(
-        self, send, req_id, engine, items, klass, ctx=None
+        self, send, req_id, engine, items, klass, ctx, way_in
     ):
         fn = self.engines.get(engine)
         if fn is None:
@@ -659,6 +854,8 @@ class VerifyServiceServer:
             )
             return
         t_recv = time.perf_counter()
+        fields = {"n": len(items), "klass": klass, "bytes": way_in.nbytes}
+        self._way_in_spans(ctx, way_in, t_recv, **fields)
         try:
             results = await self.scheduler.submit_fn(
                 items, fn, klass, engine=engine, ctx=ctx
@@ -668,8 +865,9 @@ class VerifyServiceServer:
                 send, req_id, f"fn engine {engine} failed: {e!r}"
             )
             return
-        self._service_span(ctx, t_recv, len(items), klass)
-        await self._send_guarded(send, encode_fn_results(req_id, results))
+        await self._answer(
+            send, encode_fn_results, req_id, results, ctx, t_recv, **fields
+        )
 
     async def _send_error(self, send, req_id: int, message: str) -> None:
         self.error_frames += 1
@@ -698,7 +896,7 @@ class VerifyServiceServer:
                 )
             except (ValueError, UnicodeDecodeError):
                 return
-            path = target.split("?", 1)[0]
+            path, _, query = target.partition("?")
             if method != "GET":
                 body, status, ctype = b"method not allowed\n", 405, "text/plain"
             elif path == "/metrics":
@@ -710,10 +908,18 @@ class VerifyServiceServer:
             elif path == "/dump_traces":
                 body = json.dumps(self.trace_dump()).encode()
                 status, ctype = 200, "application/json"
+            elif path in ("/profile_start", "/profile_stop"):
+                if path == "/profile_start":
+                    status, doc = await self._profile_start(
+                        dict(urllib.parse.parse_qsl(query))
+                    )
+                else:
+                    status, doc = await self._profile_stop()
+                body, ctype = json.dumps(doc).encode(), "application/json"
             else:
                 body, status, ctype = b"not found\n", 404, "text/plain"
-            reason = {200: "OK", 404: "Not Found",
-                      405: "Method Not Allowed"}[status]
+            reason = {200: "OK", 400: "Bad Request", 404: "Not Found",
+                      405: "Method Not Allowed", 409: "Conflict"}[status]
             writer.write(
                 f"HTTP/1.1 {status} {reason}\r\n"
                 f"Content-Type: {ctype}\r\n"
@@ -1061,18 +1267,25 @@ class RemoteVerifyScheduler:
     async def _send_req(self, kind, items, klass, fallback, engine=""):
         from ..obs.tracer import height_hint
 
+        t_submit_ns = time.perf_counter_ns()
         self._next_id += 1
         req_id = self._next_id
         # trace context: the consensus height in progress (published by
         # the state machine on every step transition) + this client's
-        # identity. Always stamped — ~15 bytes on the wire — so the
-        # service side can attribute even when the client's own ring is
-        # off; recording on either side stays gated on its tracer.
+        # identity, and two readings of the clock this process shares
+        # with the service (SHARED_CLOCK): this entry and, taken as the
+        # trailer is appended, the end of the encode. Always stamped —
+        # ~31 bytes on the wire — so the service side can attribute
+        # even when the client's own ring is off; recording on either
+        # side stays gated on its tracer.
         height, round_ = height_hint()
-        wire_ctx = (height, round_, self.origin)
+        wire_ctx = (
+            height, round_, self.origin,
+            t_submit_ns if SHARED_CLOCK else None,
+        )
         req = _RemoteReq(
             kind, items, klass, self._loop.create_future(), fallback,
-            time.perf_counter(), ctx=(height, round_, self.origin, req_id),
+            t_submit_ns * 1e-9, ctx=(height, round_, self.origin, req_id),
         )
         self._pending[req_id] = req
         try:
@@ -1202,8 +1415,9 @@ def run_service(
     too), serve until SIGINT/SIGTERM. `ready_fd` (harness use) gets
     one JSON line ({"ready": true, "stats_port": N}) written when the
     socket is accepting — spawners wait on it instead of polling.
-    `trace` (or TM_TPU_TRACE=1) arms the service flight ring served at
-    GET /dump_traces on the stats port."""
+    `trace` (or TM_TPU_TRACE=1) arms the service flight ring
+    (SERVICE_RING_SIZE records) served at GET /dump_traces on the stats
+    port."""
     import signal
 
     from ..obs import Tracer, set_default_tracer
@@ -1213,7 +1427,10 @@ def run_service(
     compile_log()  # listen from the first compile on
     logger.info("verify service device", **device_info())
     tracer = set_default_tracer(
-        Tracer(enabled=trace or os.environ.get("TM_TPU_TRACE") == "1")
+        Tracer(
+            enabled=trace or os.environ.get("TM_TPU_TRACE") == "1",
+            ring_size=SERVICE_RING_SIZE,
+        )
     )
     server = VerifyServiceServer(
         path, max_batch=max_batch, logger=logger, stats_port=stats_port,
