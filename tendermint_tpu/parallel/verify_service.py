@@ -49,9 +49,26 @@ Wire format (all integers big-endian):
 
     frame    := u32 length | payload            (length = len(payload))
     payload  := u8 type | u64 request_id | body
-    SUBMIT(1)       body := str8 klass | u32 n | n * sigitem | [ctx]
+    SUBMIT(10)      body := str8 klass | u32 n | key_types | column pubkeys
+                       | column msgs | column sigs | [ctx]
+    key_types       := u8 k | k * str8 name | [n * u8 code]
+                    (the distinct names, sorted; the codes, one a row,
+                    an index into the names, only where k > 1; k = 0
+                    only where n = 0; an empty name reads as ed25519)
+    column          := u8 0 | u32 blob_len | u32 width | blob
+                     | u8 1 | u32 blob_len | n * u32 length | blob
+                    (the rows' bytes end to end. Form 0 where every row
+                    is `width` bytes long, width > 0 unless n = 0, and
+                    blob_len = n * width; form 1 otherwise, blob_len =
+                    the sum of the lengths. The encoder reads the form
+                    off the rows it is given; a decoder checks every
+                    size against the frame before it builds a row)
+    SUBMIT_LEGACY(1) body := str8 klass | u32 n | n * sigitem | [ctx]
     sigitem         := str8 key_type | bytes16 pubkey | bytes32 msg
                        | bytes16 sig
+                    (decode-only: the per-item frame of clients older
+                    than SUBMIT(10). The service decodes and serves it
+                    as before; no client of this tree sends it)
     VERDICTS(2)     body := u32 n | ceil(n/8) bitmap (little-bit-order)
     SUBMIT_FN(3)    body := str8 klass | str8 engine | u32 n | n * item
                     | [ctx]
@@ -77,11 +94,19 @@ Wire format (all integers big-endian):
 `str8` = u8 length + bytes; `bytes16`/`bytes32` = u16/u32 length +
 bytes. Frames are capped at MAX_FRAME; an oversized or undecodable
 frame errors the connection (the client degrades and reconnects).
+
+Compatibility, both ways. An older client's SUBMIT_LEGACY(1) frames are
+served by this service like any other submission (the dump counts them
+under `submit_frames.v1`). A client of this tree sends SUBMIT(10) and
+nothing else; a service older than that frame answers it with its
+`unknown frame type 10` ERROR frame, and the client verifies that
+submission locally, as on any ERROR frame.
 """
 
 from __future__ import annotations
 
 import asyncio
+import itertools
 import json
 import os
 import struct
@@ -109,7 +134,7 @@ from ..obs.ledger import default_ledger
 from ..obs.profiler import ProfileCapture, ProfilerUnavailable
 from .scheduler import VerifyScheduler, _ClassedVerifier
 
-MSG_SUBMIT = 1
+MSG_SUBMIT_LEGACY = 1  # decode-only: the per-item frame of older clients
 MSG_VERDICTS = 2
 MSG_SUBMIT_FN = 3
 MSG_FN_RESULTS = 4
@@ -118,6 +143,7 @@ MSG_PONG = 6
 MSG_STATS = 7
 MSG_STATS_RESULT = 8
 MSG_ERROR = 9
+MSG_SUBMIT = 10  # columnar: the one submit frame a client sends
 
 # one frame bounds one submission; 64 MiB holds ~380k vote-sized items,
 # far past max_batch — anything bigger is a protocol violation, not load
@@ -132,6 +158,11 @@ _U32 = struct.Struct(">I")
 _U64 = struct.Struct(">Q")
 _HDR = struct.Struct(">BQ")  # type, request_id
 _STAMPS = struct.Struct(">QQ")  # t_submit_ns, t_encoded_ns
+_COL = struct.Struct(">BI")  # a column's form, blob_len
+
+# the two forms of a byte column: one width for every row, or n lengths
+COL_WIDTH = 0
+COL_LENGTHS = 1
 
 
 # Frame decode violations (cap, truncation, unknown tag) share the
@@ -149,13 +180,6 @@ def _put_str8(out: list, s: str) -> None:
     if len(b) > 255:
         raise WireError(f"str8 too long: {len(b)}")
     out.append(_U8.pack(len(b)))
-    out.append(b)
-
-
-def _put_bytes16(out: list, b: bytes) -> None:
-    if len(b) > 0xFFFF:
-        raise WireError(f"bytes16 too long: {len(b)}")
-    out.append(_U16.pack(len(b)))
     out.append(b)
 
 
@@ -264,22 +288,129 @@ def decode_trace_stamps(cur: _Cursor):
     return t_submit * 1e-9, t_encoded * 1e-9
 
 
+def _put_key_types(out: list, key_types: list) -> None:
+    names = sorted(set(key_types))
+    if len(names) > 255:
+        raise WireError(f"{len(names)} key types in one submission")
+    out.append(_U8.pack(len(names)))
+    for name in names:
+        _put_str8(out, name)
+    if len(names) > 1:
+        code = {name: i for i, name in enumerate(names)}
+        out.append(bytes(map(code.__getitem__, key_types)))
+
+
+def _put_column(out: list, rows: list) -> None:
+    """One byte column: a single width where every row has that length,
+    n lengths otherwise, then the rows themselves: the frame's one
+    `b"".join` lays them end to end, so no column is copied twice."""
+    n = len(rows)
+    lens = np.fromiter(map(len, rows), dtype=np.uint32, count=n)
+    blob_len = int(lens.sum(dtype=np.uint64))
+    if blob_len > MAX_FRAME:
+        raise WireError(f"column of {blob_len} bytes exceeds the frame cap")
+    width = int(lens[0]) if n else 0
+    if not n or (width and (lens == width).all()):
+        out.append(_COL.pack(COL_WIDTH, blob_len))
+        out.append(_U32.pack(width))
+    else:
+        out.append(_COL.pack(COL_LENGTHS, blob_len))
+        out.append(lens.astype(">u4").tobytes())
+    out.extend(rows)
+
+
 def encode_submit(
     req_id: int, items: list[SigItem], klass: str, ctx=None
 ) -> bytes:
+    """The columnar submit frame: a constant number of steps a column
+    (one pass over the items' attribute, its lengths through numpy, one
+    `extend`), none an item."""
     out = [_HDR.pack(MSG_SUBMIT, req_id)]
     _put_str8(out, klass)
     out.append(_U32.pack(len(items)))
-    for it in items:
-        _put_str8(out, it.key_type)
-        _put_bytes16(out, bytes(it.pubkey))
-        _put_bytes32(out, bytes(it.msg))
-        _put_bytes16(out, bytes(it.sig))
+    _put_key_types(out, [it.key_type for it in items])
+    _put_column(out, [it.pubkey for it in items])
+    _put_column(out, [it.msg for it in items])
+    _put_column(out, [it.sig for it in items])
     _put_trace_ctx(out, ctx)
     return b"".join(out)
 
 
-def decode_submit(cur: _Cursor) -> tuple[list[SigItem], str]:
+def _take_key_types(cur: _Cursor, n: int):
+    """An iterable of n key-type names."""
+    k = cur.u8()
+    names = [cur.str8() or "ed25519" for _ in range(k)]
+    if k == 0:
+        if n:
+            raise WireError(f"no key type for {n} rows")
+        return ()
+    if k == 1:
+        return itertools.repeat(names[0], n)
+    codes = cur.take(n)
+    if n and max(codes) >= k:
+        raise WireError(f"key-type code past the {k} names")
+    return map(names.__getitem__, codes)
+
+
+def _take_column(cur: _Cursor, n: int):
+    """Step over one byte column, checking what it says of its size
+    against the frame: (offset of the blob, width, lengths), of the
+    last two the one its form carries. Builds nothing of n's size that
+    the frame does not hold."""
+    form, blob_len = _COL.unpack(cur.take(_COL.size))
+    width = lens = None
+    if form == COL_WIDTH:
+        width = cur.u32()
+        if n * width != blob_len or (n and not width):
+            raise WireError(
+                f"column of {n} rows by {width} bytes in a blob of {blob_len}"
+            )
+    elif form == COL_LENGTHS:
+        lens = np.frombuffer(cur.take(4 * n), dtype=">u4")
+        total = int(lens.sum(dtype=np.uint64))
+        if total != blob_len:
+            raise WireError(
+                f"column lengths sum to {total} in a blob of {blob_len}"
+            )
+    else:
+        raise WireError(f"unknown column form {form}")
+    start = cur.off
+    if start + blob_len > len(cur.buf):
+        raise WireError("truncated frame")
+    cur.off = start + blob_len
+    return start, width, lens
+
+
+def _column_rows(buf: bytes, n: int, start: int, width, lens) -> list:
+    """The n rows of a column that `_take_column` has checked, each a
+    `bytes` of its own."""
+    if n == 0:
+        return []
+    if width is not None:
+        # one C-level pass: an array of n width-byte voids, as bytes
+        return np.frombuffer(
+            buf, dtype=np.dtype((np.void, width)), count=n, offset=start
+        ).tolist()
+    ends = (np.cumsum(lens, dtype=np.int64) + start).tolist()
+    return [buf[a:b] for a, b in zip([start] + ends, ends)]
+
+
+def decode_submit(cur: _Cursor) -> tuple[list[SigItem], str, bool]:
+    """(items, klass, uniform): the list that was encoded, row for row;
+    `uniform` says that every byte column came in its single-width
+    form. Every size the frame states is checked against the frame
+    before a row is built."""
+    klass = cur.str8()
+    n = cur.u32()
+    key_types = _take_key_types(cur, n)
+    cols = [_take_column(cur, n) for _ in range(3)]
+    pubkeys, msgs, sigs = (_column_rows(cur.buf, n, *col) for col in cols)
+    items = list(map(SigItem, pubkeys, msgs, sigs, key_types))
+    return items, klass, all(width is not None for _, width, _ in cols)
+
+
+def decode_submit_legacy(cur: _Cursor) -> tuple[list[SigItem], str]:
+    """The per-item SUBMIT_LEGACY(1) frame of older clients."""
     klass = cur.str8()
     n = cur.u32()
     items = []
@@ -414,16 +545,19 @@ from .engines import (  # noqa: E402,F401
 class _WayIn:
     """What `_handle_conn` knows of a submission's way in, taken once
     the trailer is decoded: the frame's size, when the whole frame was
-    held, when its decode ended, and the client's stamps if it sent
-    any."""
+    held, when its decode ended, the client's stamps if it sent any,
+    and what `verify.frame_decode` says of the frame it decoded: for a
+    signature submission `frame` (`cols` or `v1`) and `uniform` (every
+    byte column in its single-width form)."""
 
-    __slots__ = ("nbytes", "t_frame", "t_decoded", "stamps")
+    __slots__ = ("nbytes", "t_frame", "t_decoded", "stamps", "decoded")
 
-    def __init__(self, cur: _Cursor, t_frame: float):
+    def __init__(self, cur: _Cursor, t_frame: float, **decoded):
         self.nbytes = len(cur.buf)
         self.t_frame = t_frame
         self.stamps = decode_trace_stamps(cur)
         self.t_decoded = time.perf_counter()
+        self.decoded = decoded
 
 
 class VerifyServiceServer:
@@ -486,6 +620,9 @@ class VerifyServiceServer:
         # verifying locally, so this count is where a failing device
         # shows
         self.error_frames = 0
+        # signature submissions by the frame that carried them: `v1`
+        # counts the clients that still send SUBMIT_LEGACY
+        self.submit_frames = {"cols": 0, "v1": 0}
         # GET /profile_start | /profile_stop on the stats port: only the
         # process that holds the chip can trace it. One thread starts
         # and stops every session, never the event loop (exporting a
@@ -565,6 +702,7 @@ class VerifyServiceServer:
                 **device_info(),
                 "compile": compile_log().snapshot(),
                 "error_frames": self.error_frames,
+                "submit_frames": dict(self.submit_frames),
             },
             "summary": ledger.summary(),
             "entries": ledger.entries(limit=entries) if entries > 0 else [],
@@ -709,10 +847,18 @@ class VerifyServiceServer:
                 t_frame = time.perf_counter()
                 cur = _Cursor(frame)
                 typ, req_id = _HDR.unpack(cur.take(_HDR.size))
-                if typ == MSG_SUBMIT:
-                    items, klass = decode_submit(cur)
+                if typ in (MSG_SUBMIT, MSG_SUBMIT_LEGACY):
+                    if typ == MSG_SUBMIT:
+                        kind = "cols"
+                        items, klass, uniform = decode_submit(cur)
+                    else:
+                        kind, uniform = "v1", False
+                        items, klass = decode_submit_legacy(cur)
                     ctx = decode_trace_ctx(cur, req_id)
-                    way_in = _WayIn(cur, t_frame)
+                    way_in = _WayIn(
+                        cur, t_frame, frame=kind, uniform=uniform
+                    )
+                    self.submit_frames[kind] += 1
                     stats["submissions"] += 1
                     stats["rows"] += len(items)
                     # create_task here, synchronously in read order:
@@ -810,7 +956,7 @@ class VerifyServiceServer:
             )
         self._span(
             "verify.frame_decode", ctx, way_in.t_frame, way_in.t_decoded,
-            **fields,
+            **fields, **way_in.decoded,
         )
 
     async def _answer(

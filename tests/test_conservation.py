@@ -215,14 +215,14 @@ def test_wire_trace_ctx_codec_and_legacy_frames():
     frame = encode_submit(7, items, "consensus", ctx=(42, 1, "nodeA"))
     cur = _Cursor(frame)
     _typ, req_id = _HDR.unpack(cur.take(_HDR.size))
-    out_items, klass = decode_submit(cur)
+    out_items, klass, _uniform = decode_submit(cur)
     ctx = decode_trace_ctx(cur, req_id)
     assert klass == "consensus" and len(out_items) == 1
     assert ctx == (42, 1, "nodeA", 7)
     # legacy frame (no trailer): ctx is None, decode unchanged
     cur = _Cursor(encode_submit(8, items, "blocksync"))
     _HDR.unpack(cur.take(_HDR.size))
-    _, klass = decode_submit(cur)
+    _, klass, _uniform = decode_submit(cur)
     assert klass == "blocksync"
     assert decode_trace_ctx(cur, 8) is None
     # fn lane carries the same trailer

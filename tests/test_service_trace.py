@@ -30,14 +30,14 @@ from tendermint_tpu.parallel.verify_service import (
     _STAMPS,
     decode_submit,
     decode_submit_fn,
+    decode_submit_legacy,
     decode_trace_ctx,
     decode_trace_stamps,
-    decode_verdicts,
     encode_submit,
     encode_submit_fn,
-    read_frame,
-    write_frame,
 )
+
+from .wire_legacy import CODEC_CASES, encode_submit_legacy, submit_raw
 
 pytestmark = pytest.mark.verify_service
 
@@ -99,20 +99,6 @@ async def submit_through_client(path: str, items, origin="nodeA"):
         await client.stop()
 
 
-async def submit_raw(path: str, payload: bytes) -> np.ndarray:
-    """One hand-made frame over the socket; the verdicts of the reply."""
-    reader, writer = await asyncio.open_unix_connection(path)
-    try:
-        write_frame(writer, payload)
-        await writer.drain()
-        cur = _Cursor(await read_frame(reader))
-        typ, _ = _HDR.unpack(cur.take(_HDR.size))
-        assert typ == vs.MSG_VERDICTS
-        return decode_verdicts(cur)
-    finally:
-        writer.close()
-
-
 def way_of(ring) -> dict:
     """{name: record} of the one submission's `verify.*` spans, in time."""
     time.sleep(0.05)  # verify.reply lands after the client has its answer
@@ -123,9 +109,24 @@ def way_of(ring) -> dict:
 # --- (a) the trailer --------------------------------------------------------
 
 
+def _encode_fn(req_id, items, klass, ctx=None):
+    return encode_submit_fn(
+        req_id, "bls_agg", [(b"a" * 32, b"b" * 32)], klass, ctx=ctx
+    )
+
+
+FRAMES = {
+    "cols": (encode_submit, decode_submit),
+    "v1": (encode_submit_legacy, decode_submit_legacy),
+    "fn": (_encode_fn, decode_submit_fn),
+}
+
+
+@pytest.mark.parametrize("kind", FRAMES)
 @pytest.mark.parametrize("trailer", ["stamps", "old", "none"])
-def test_trailer_round_trips_and_older_frames_decode(trailer):
+def test_trailer_round_trips_and_older_frames_decode(trailer, kind):
     assert vs.SHARED_CLOCK, time.get_clock_info("perf_counter")
+    encode, decode = FRAMES[kind]
     items = sig_items(3)
     before = time.perf_counter()
     ctx = {
@@ -133,37 +134,27 @@ def test_trailer_round_trips_and_older_frames_decode(trailer):
         "old": (42, 1, "nodeA"),
         "none": None,
     }[trailer]
-    frames = [
-        (encode_submit(7, items, "consensus", ctx=ctx), decode_submit),
-        (
-            encode_submit_fn(
-                7, "bls_agg", [(b"a" * 32, b"b" * 32)], "consensus", ctx=ctx
-            ),
-            decode_submit_fn,
-        ),
-    ]
-    for frame, decode in frames:
-        cur = _Cursor(frame)
-        _, req_id = _HDR.unpack(cur.take(_HDR.size))
-        decoded = decode(cur)
-        if decode is decode_submit:
-            assert decoded == (items, "consensus")
-        got_ctx = decode_trace_ctx(cur, req_id)
-        stamps = decode_trace_stamps(cur)
-        assert cur.off == len(frame)
-        if trailer == "none":
-            assert got_ctx is None and stamps is None
-            continue
-        assert got_ctx == (42, 1, "nodeA", 7)
-        if trailer == "old":
-            assert stamps is None
-            continue
-        t_submit, t_encoded = stamps
-        assert before <= t_submit <= t_encoded <= time.perf_counter()
+    frame = encode(7, items, "consensus", ctx=ctx)
+    cur = _Cursor(frame)
+    _, req_id = _HDR.unpack(cur.take(_HDR.size))
+    decoded = decode(cur)
+    if kind != "fn":
+        assert decoded[:2] == (items, "consensus")
+    got_ctx = decode_trace_ctx(cur, req_id)
+    stamps = decode_trace_stamps(cur)
+    assert cur.off == len(frame)
+    if trailer == "none":
+        assert got_ctx is None and stamps is None
+        return
+    assert got_ctx == (42, 1, "nodeA", 7)
+    if trailer == "old":
+        assert stamps is None
+        return
+    t_submit, t_encoded = stamps
+    assert before <= t_submit <= t_encoded <= time.perf_counter()
     # 16 bytes a submission, and only those
-    if trailer == "stamps":
-        old = encode_submit(7, items, "consensus", ctx=ctx[:3])
-        assert len(frames[0][0]) - len(old) == _STAMPS.size == 16
+    old = encode(7, items, "consensus", ctx=ctx[:3])
+    assert len(frame) - len(old) == _STAMPS.size == 16
 
 
 # --- (b) one submission, one chain of spans ---------------------------------
@@ -200,6 +191,10 @@ def test_one_submission_leaves_its_way_in_and_out_on_the_service_ring(svc):
     assert ingress.t0 == way["verify.client_encode"].t0
     assert abs(end(ingress) - way["verify.service"].t0) < 1e-6
     assert "parent" not in way["verify.service"].fields
+    # which frame the decode read is on its span, and on no other
+    decode = way.pop("verify.frame_decode").fields
+    assert (decode["frame"], decode["uniform"]) == ("cols", True)
+    assert not any("frame" in r.fields for r in way.values())
     # the frame in is the items, the frame out a bitmap
     assert way["verify.wire_in"].fields["bytes"] > 12 * 128
     assert way["verify.reply"].fields["bytes"] == _HDR.size + 4 + 2
@@ -208,8 +203,10 @@ def test_one_submission_leaves_its_way_in_and_out_on_the_service_ring(svc):
 # --- (c) frames of other clients: served the same, never failed -------------
 
 
-def stamped(t_submit_s: float, t_encoded_s: float) -> bytes:
-    return encode_submit(
+def stamped(
+    t_submit_s: float, t_encoded_s: float, encode=encode_submit
+) -> bytes:
+    return encode(
         9, sig_items(12), "consensus", ctx=(5, 0, "w1")
     ) + _STAMPS.pack(int(t_submit_s * 1e9), int(t_encoded_s * 1e9))
 
@@ -232,9 +229,27 @@ def stamped(t_submit_s: float, t_encoded_s: float) -> bytes:
         (lambda now: stamped(now - 0.001, now - 0.002), WAY[3:]),
         # and one whose stamps are sound, sent the same way
         (lambda now: stamped(now - 0.002, now - 0.001), WAY),
+        # the per-item frame of an older client, with each trailer it
+        # may carry
+        (
+            lambda now: encode_submit_legacy(9, sig_items(12), "consensus"),
+            (),
+        ),
+        (
+            lambda now: encode_submit_legacy(
+                9, sig_items(12), "consensus", ctx=(5, 0, "w1")
+            ),
+            WAY[3:],
+        ),
+        (
+            lambda now: stamped(
+                now - 0.002, now - 0.001, encode_submit_legacy
+            ),
+            WAY,
+        ),
     ],
     ids=["no-trailer", "old-trailer", "future", "60s-old", "backwards",
-         "sound"],
+         "sound", "v1-no-trailer", "v1-old-trailer", "v1-sound"],
 )
 def test_other_frames_are_served_alike_and_bad_stamps_drop_client_spans(
     svc, frame, names
@@ -249,6 +264,38 @@ def test_other_frames_are_served_alike_and_bad_stamps_drop_client_spans(
     if "verify.frame_decode" in way and names != WAY:
         assert "parent" not in way["verify.frame_decode"].fields
     assert thread.server.error_frames == 0
+
+
+@pytest.mark.parametrize(
+    "encode, case, want",
+    [
+        (encode_submit, "uniform-ed25519", ("cols", True)),
+        (encode_submit, "one-item", ("cols", True)),
+        (encode_submit, "uniform-keys-varying-msgs", ("cols", False)),
+        (encode_submit, "mixed-key-types", ("cols", False)),
+        (encode_submit, "empty-fields", ("cols", False)),
+        (encode_submit_legacy, "uniform-ed25519", ("v1", False)),
+        (encode_submit_legacy, "mixed-key-types", ("v1", False)),
+    ],
+    ids=lambda v: v if isinstance(v, str) else getattr(v, "__name__", None),
+)
+def test_frame_decode_span_says_which_frame_it_read(svc, encode, case, want):
+    """`verify.frame_decode` carries `frame` (`cols` or `v1`) and
+    `uniform`; the dump counts the submit frames of each kind."""
+    thread, ring = svc
+    items, _ = CODEC_CASES[case]
+    verdicts = asyncio.run(
+        submit_raw(
+            thread.server.path,
+            encode(9, items, "consensus", ctx=(5, 0, "w1")),
+        )
+    )
+    assert verdicts.tolist() == [it.sig[:1] == b"1" for it in items]
+    fields = way_of(ring)["verify.frame_decode"].fields
+    assert (fields["frame"], fields["uniform"]) == want
+    assert fields["n"] == len(items)
+    counts = {"cols": 0, "v1": 0, want[0]: 1}
+    assert thread.server.dump()["service"]["submit_frames"] == counts
 
 
 # --- (d) tracer off ---------------------------------------------------------

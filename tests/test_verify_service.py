@@ -22,6 +22,7 @@ import json
 import os
 import threading
 import time
+import tracemalloc
 
 import numpy as np
 import pytest
@@ -33,18 +34,26 @@ from tendermint_tpu.parallel.scheduler import (
     set_default_scheduler,
 )
 from tendermint_tpu.parallel.verify_service import (
+    COL_LENGTHS,
+    COL_WIDTH,
     MSG_ERROR,
     MSG_STATS,
     MSG_STATS_RESULT,
     MSG_SUBMIT,
+    MSG_SUBMIT_LEGACY,
     RemoteVerifyScheduler,
     ServiceThread,
     WireError,
+    _COL,
     _Cursor,
     _HDR,
+    _U32,
     decode_fn_results,
     decode_submit,
     decode_submit_fn,
+    decode_submit_legacy,
+    decode_trace_ctx,
+    decode_trace_stamps,
     decode_verdicts,
     encode_error,
     encode_fn_results,
@@ -54,6 +63,8 @@ from tendermint_tpu.parallel.verify_service import (
     read_frame,
     write_frame,
 )
+
+from .wire_legacy import CODEC_CASES, encode_submit_legacy, submit_raw
 
 pytestmark = pytest.mark.verify_service
 
@@ -129,9 +140,9 @@ def test_wire_codec_roundtrips():
     ]
     cur = _Cursor(encode_submit(7, items, "blocksync"))
     typ, req = _HDR.unpack(cur.take(_HDR.size))
-    assert (typ, req) == (MSG_SUBMIT, 7)
-    got, klass = decode_submit(cur)
-    assert klass == "blocksync"
+    assert (typ, req) == (MSG_SUBMIT, 7) == (10, 7)
+    got, klass, uniform = decode_submit(cur)
+    assert klass == "blocksync" and not uniform
     assert [
         (i.pubkey, i.msg, i.sig, i.key_type) for i in got
     ] == [(i.pubkey, i.msg, i.sig, i.key_type) for i in items]
@@ -163,16 +174,320 @@ def test_wire_codec_roundtrips():
 
 
 def test_wire_codec_rejects_malformed():
-    # truncated frame
-    cur = _Cursor(encode_submit(1, sig_items(2), "consensus")[:-3])
-    cur.take(_HDR.size)
-    with pytest.raises(WireError):
-        decode_submit(cur)
+    # truncated frame, of either kind
+    for encode, decode in (
+        (encode_submit, decode_submit),
+        (encode_submit_legacy, decode_submit_legacy),
+    ):
+        cur = _Cursor(encode(1, sig_items(2), "consensus")[:-3])
+        cur.take(_HDR.size)
+        with pytest.raises(WireError):
+            decode(cur)
     # unknown fn-result tag
     cur = _Cursor(_HDR.pack(4, 1) + b"\x00\x00\x00\x01\x09")
     cur.take(_HDR.size)
     with pytest.raises(WireError):
         decode_fn_results(cur)
+
+
+# --- the columnar submit frame ----------------------------------------------
+
+TRAILERS = {
+    "no-trailer": None,
+    "old-trailer": (42, 1, "nodeA"),
+    "stamped": (42, 1, "nodeA", 1_234_567_890_123),
+}
+
+
+def body_of(frame: bytes, typ: int) -> _Cursor:
+    cur = _Cursor(frame)
+    assert _HDR.unpack(cur.take(_HDR.size)) == (typ, 7)
+    return cur
+
+
+@pytest.mark.parametrize("trailer", TRAILERS)
+@pytest.mark.parametrize("case", CODEC_CASES)
+def test_submit_codec_round_trips(case, trailer):
+    """decode(encode(items)) == items, row for row, with and without
+    the trailer behind the columns, for every list the per-item frame
+    could carry; and the per-item frame of the same list decodes to the
+    same."""
+    items, uniform = CODEC_CASES[case]
+    ctx = TRAILERS[trailer]
+    frame = encode_submit(7, items, "blocksync", ctx=ctx)
+    cur = body_of(frame, MSG_SUBMIT)
+    assert decode_submit(cur) == (items, "blocksync", uniform)
+    legacy = body_of(
+        encode_submit_legacy(7, items, "blocksync", ctx=ctx),
+        MSG_SUBMIT_LEGACY,
+    )
+    assert decode_submit_legacy(legacy) == (items, "blocksync")
+    for c in (cur, legacy):
+        got_ctx = decode_trace_ctx(c, 7)
+        stamps = decode_trace_stamps(c)
+        assert c.off == len(c.buf)
+        assert got_ctx == (None if ctx is None else (42, 1, "nodeA", 7))
+        if trailer == "stamped":
+            assert stamps[0] == pytest.approx(1234.567890123)
+            assert stamps[1] >= stamps[0]
+        else:
+            assert stamps is None
+    for it in decode_submit(body_of(frame, MSG_SUBMIT))[0]:
+        assert type(it.pubkey) is type(it.msg) is type(it.sig) is bytes
+
+
+def test_submit_codec_takes_any_buffer_and_defaults_the_key_type():
+    """Rows held as bytearray or memoryview are encoded as their bytes,
+    and an empty key type still reads as ed25519."""
+    items = [
+        SigItem(bytearray(b"p" * 32), memoryview(b"m" * 9), b"1" * 64, ""),
+        SigItem(b"q" * 32, bytearray(b"n" * 9), memoryview(b"0" * 64)),
+    ]
+    want = [
+        SigItem(b"p" * 32, b"m" * 9, b"1" * 64, "ed25519"),
+        SigItem(b"q" * 32, b"n" * 9, b"0" * 64, "ed25519"),
+    ]
+    for encode, decode, typ in (
+        (encode_submit, decode_submit, MSG_SUBMIT),
+        (encode_submit_legacy, decode_submit_legacy, MSG_SUBMIT_LEGACY),
+    ):
+        got = decode(body_of(encode(7, items, "light"), typ))
+        assert got[:2] == (want, "light")
+
+
+def test_columnar_frame_is_smaller_and_says_uniform_once():
+    """64 uniform rows: three 9-byte column headers and one key-type
+    name, against 16 bytes a row."""
+    items = sig_items(64)
+    frame = encode_submit(7, items, "consensus")
+    legacy = encode_submit_legacy(7, items, "consensus")
+    rows = 64 * (32 + 33 + 64)
+    head = _HDR.size + 1 + len("consensus") + 4
+    assert len(frame) == head + (1 + 1 + len("ed25519")) + 3 * 9 + rows
+    assert len(legacy) == head + 64 * (1 + len("ed25519") + 2 + 4 + 2) + rows
+
+
+def column_ends(frame: bytes, n: int) -> list[tuple[str, int]]:
+    """(name, offset) of every boundary of a columnar frame with no
+    trailer, walked by the grammar of the module's docstring."""
+    off = _HDR.size
+    off += 1 + frame[off]
+    marks = [("klass", off)]
+    off += 4
+    marks.append(("n", off))
+    k = frame[off]
+    off += 1
+    for _ in range(k):
+        off += 1 + frame[off]
+    marks.append(("key-type-names", off))
+    if k > 1:
+        off += n
+        marks.append(("key-type-codes", off))
+    for col in ("pubkeys", "msgs", "sigs"):
+        form, blob_len = _COL.unpack_from(frame, off)
+        off += _COL.size
+        marks.append((col + "-head", off))
+        off += 4 if form == COL_WIDTH else 4 * n
+        marks.append((col + "-sizes", off))
+        marks.append((col + "-mid-blob", off + blob_len // 2))
+        off += blob_len
+        marks.append((col + "-blob", off))
+    assert off == len(frame)
+    return marks[:-1]  # the last boundary is the whole frame
+
+
+CUT_FRAME = encode_submit(7, CODEC_CASES["mixed-key-types"][0], "evidence")
+CUT_UNIFORM = encode_submit(7, sig_items(6), "evidence")
+
+
+@pytest.mark.parametrize(
+    "frame, name, off",
+    [("mixed", name, off) for name, off in column_ends(CUT_FRAME, 20)]
+    + [("uniform", name, off) for name, off in column_ends(CUT_UNIFORM, 6)],
+    ids=lambda v: str(v),
+)
+def test_columnar_frame_cut_at_a_boundary_is_a_wire_error(frame, name, off):
+    whole = CUT_FRAME if frame == "mixed" else CUT_UNIFORM
+    assert 0 < off < len(whole)
+    with pytest.raises(WireError):
+        decode_submit(body_of(whole[:off], MSG_SUBMIT))
+
+
+def test_every_prefix_of_a_columnar_frame_is_a_wire_error():
+    for off in range(_HDR.size, len(CUT_FRAME)):
+        with pytest.raises(WireError):
+            decode_submit(body_of(CUT_FRAME[:off], MSG_SUBMIT))
+
+
+def hostile(n: int, key_types: bytes, *columns: bytes) -> bytes:
+    """A columnar frame said by hand, padded to 64 bytes."""
+    frame = b"".join(
+        (_HDR.pack(MSG_SUBMIT, 7), b"\x01c", _U32.pack(n), key_types)
+        + columns
+    )
+    return frame.ljust(64, b"\x00")
+
+
+def width_col(blob_len: int, width: int, blob: bytes = b"") -> bytes:
+    return _COL.pack(COL_WIDTH, blob_len) + _U32.pack(width) + blob
+
+
+def lengths_col(blob_len: int, lens: list, blob: bytes = b"") -> bytes:
+    return (
+        _COL.pack(COL_LENGTHS, blob_len)
+        + b"".join(map(_U32.pack, lens))
+        + blob
+    )
+
+
+ONE_TYPE = b"\x01\x07ed25519"
+BIG = 2**32 - 1
+
+HOSTILE = {
+    # n rows of no bytes at all: the one way n could outgrow the frame
+    "huge-n-zero-width": hostile(
+        BIG, ONE_TYPE, width_col(0, 0), width_col(0, 0), width_col(0, 0)
+    ),
+    "huge-n-width-form": hostile(BIG, ONE_TYPE, width_col(BIG, 1)),
+    "huge-n-lengths-form": hostile(BIG, ONE_TYPE, lengths_col(8, [4, 4])),
+    "huge-n-key-type-codes": hostile(BIG, b"\x02\x01a\x01b" + b"\x00" * 40),
+    "huge-n-no-key-type": hostile(BIG, b"\x00"),
+    "width-times-n-is-not-the-blob": hostile(
+        2, ONE_TYPE, width_col(5, 2, b"aaaaa")
+    ),
+    "width-blob-past-the-frame": hostile(2, ONE_TYPE, width_col(400, 200)),
+    "lengths-sum-under-the-blob": hostile(
+        2, ONE_TYPE, lengths_col(5, [2, 2], b"aaaaa")
+    ),
+    "lengths-sum-over-the-blob": hostile(
+        2, ONE_TYPE, lengths_col(3, [2, 2], b"aaa")
+    ),
+    "lengths-sum-wraps-u32": hostile(
+        2, ONE_TYPE, lengths_col(1, [BIG, 2], b"a")
+    ),
+    "unknown-column-form": hostile(
+        1, ONE_TYPE, _COL.pack(2, 1) + _U32.pack(1) + b"a"
+    ),
+    "key-type-code-out-of-range": hostile(
+        2, b"\x02\x01a\x01b\x00\x02", width_col(2, 1, b"pp"),
+        width_col(2, 1, b"mm"), width_col(2, 1, b"ss"),
+    ),
+    "bad-utf8-key-type": hostile(
+        1, b"\x01\x02\xff\xfe", width_col(1, 1, b"p"),
+        width_col(1, 1, b"m"), width_col(1, 1, b"s"),
+    ),
+}
+
+
+@pytest.mark.parametrize("name", HOSTILE)
+def test_hostile_sizes_are_wire_errors_before_anything_is_built(name):
+    """`n` and every length are outside input: a size the frame does
+    not bear out is a WireError, and nothing of n's size is allocated
+    on the way to it."""
+    frame = HOSTILE[name]
+    assert len(frame) == 64
+    cur = body_of(frame, MSG_SUBMIT)
+    tracemalloc.start()
+    try:
+        with pytest.raises(WireError):
+            decode_submit(cur)
+        _, peak = tracemalloc.get_traced_memory()
+    finally:
+        tracemalloc.stop()
+    assert peak < 64 * 1024, peak
+
+
+@pytest.mark.parametrize(
+    "case", [c for c, (items, _) in CODEC_CASES.items() if items]
+)
+def test_legacy_frame_is_served_like_the_columnar_one(tmp_path, case):
+    """An older client's per-item frame is decoded and served: the same
+    verdicts as the columnar frame of the same items, and the dump
+    counts one frame of each kind."""
+    items, _ = CODEC_CASES[case]
+    svc = service(tmp_path)
+    try:
+        ctx = (3, 0, "old-node")
+
+        async def run():
+            path = svc.server.path
+            v1 = await submit_raw(
+                path, encode_submit_legacy(7, items, "consensus", ctx=ctx)
+            )
+            cols = await submit_raw(
+                path, encode_submit(7, items, "consensus", ctx=ctx)
+            )
+            return v1, cols
+
+        v1, cols = asyncio.run(run())
+        want = [it.sig[:1] == b"1" for it in items]
+        assert v1.tolist() == cols.tolist() == want
+        dump = svc.server.dump()
+        assert dump["service"]["submit_frames"] == {"cols": 1, "v1": 1}
+        assert dump["service"]["error_frames"] == 0
+        assert [c["rows"] for c in dump["per_client"].values()] == [
+            len(items), len(items),
+        ]
+    finally:
+        svc.stop()
+
+
+def test_client_sends_the_columnar_frame_and_no_other(tmp_path):
+    svc = service(tmp_path)
+    try:
+
+        async def run():
+            remote = await connect(svc.server.path)
+            out = [
+                await remote.submit(items, "consensus")
+                for items, _ in CODEC_CASES.values()
+            ]
+            await remote.stop()
+            return out
+
+        for got, (items, _) in zip(asyncio.run(run()), CODEC_CASES.values()):
+            assert got.tolist() == [it.sig[:1] == b"1" for it in items]
+        sent = sum(1 for items, _ in CODEC_CASES.values() if items)
+        assert svc.server.submit_frames == {"cols": sent, "v1": 0}
+    finally:
+        svc.stop()
+
+
+def test_client_that_meets_an_older_service_verifies_locally(tmp_path):
+    """A service from before the columnar frame answers it with its
+    `unknown frame type` ERROR frame; the client degrades that
+    submission to its local verifier, as on any ERROR frame."""
+    path = os.path.join(str(tmp_path), "old.sock")
+
+    async def old_service(reader, writer):
+        while (frame := await read_frame(reader)) is not None:
+            typ, req_id = _HDR.unpack(frame[: _HDR.size])
+            assert typ == MSG_SUBMIT
+            write_frame(
+                writer, encode_error(req_id, f"unknown frame type {typ}")
+            )
+            await writer.drain()
+        writer.close()
+
+    async def run():
+        server = await asyncio.start_unix_server(old_service, path=path)
+        remote = await connect(path, verifier=SigTagVerifier())
+        try:
+            got = await asyncio.wait_for(
+                remote.submit(
+                    sig_items(6, good=lambda i: i != 2), "consensus"
+                ),
+                15,
+            )
+            return got, remote.ipc_stats()
+        finally:
+            await remote.stop()
+            server.close()
+            await server.wait_closed()
+
+    got, stats = asyncio.run(run())
+    assert got.tolist() == [i != 2 for i in range(6)]
+    assert stats["degrades"] == 1
 
 
 def test_read_frame_caps_oversized(tmp_path):
@@ -629,7 +944,11 @@ def test_tenant_table_bounded(tmp_path):
                 )
                 writer.close()
             await asyncio.sleep(0.2)
-            idle_entries = len(svc.server.client_stats)
+            # (the fifth connection may arrive before the first four are
+            # seen closed and open the `_closed` aggregate, empty)
+            idle_entries = len(
+                [k for k in svc.server.client_stats if k != "_closed"]
+            )
             # 8 sequential submitting clients: table stays bounded
             for _ in range(8):
                 remote = await connect(svc.server.path)
