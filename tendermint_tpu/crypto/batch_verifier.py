@@ -42,6 +42,7 @@ Responsibilities:
 from __future__ import annotations
 
 import contextlib
+import functools
 import threading
 import time
 from dataclasses import dataclass
@@ -68,16 +69,33 @@ from .shape_registry import (
 # the historical name importable.
 BUCKETS = DEFAULT_BUCKET_LADDER
 
-# max rows of the device-resident table caches. Small tier: radix-16 window
-# tables, 2 KiB/key. Big tier: fixed-window tables, 128 KiB/key as canonical
-# uint8 limbs (4096 keys = 512 MiB of data worst case; both stores allocate
-# lazily and grow in power-of-two row counts, so the cap only bounds the
-# worst case). Read on a TPU v5 lite (PERF.md section 5): the big store
-# takes exactly its data there (XLA lays the row dimension out minor-most,
-# so nothing pads), and each loaded big-tier verify program holds device
-# memory of its own, independent of the key count (about 174 MB with the
-# build program at the 16384 bucket).
+# keys of a device-resident table store where the backend keeps no
+# memory statistics (XLA:CPU: the tests, a node beside a verify service);
+# on a device that reports `bytes_limit` the capacity is derived from it
+# (_device_table_capacity). Small tier: radix-16 window tables, 2 KiB/key.
+# Big tier: fixed-window tables, 128 KiB/key as canonical uint8 limbs.
+# Both stores allocate lazily and grow in power-of-two row counts, so a
+# capacity only bounds the worst case. Read on a TPU v5 lite (PERF.md
+# sections 4 to 6, PR 27): `bytes_limit` 16,909,336,064, so 16,384 keys;
+# the big store takes exactly its data (XLA lays the row dimension out
+# minor-most, so nothing pads): 134,218,752 bytes at 1,024 rows,
+# 2,147,500,032 at the 16,384 a 10,000-key committee allocates; a
+# 512-key build chunk takes 0.13 s once the build program is loaded
+# (19-22 s from the compile cache); with the three big-tier programs a
+# 10,000-row request can reach loaded, 2,451,370,496 bytes are in use
+# (304 MB beside the store); a `big@16384` execution holds 153 MB of
+# temporaries whatever the key count (the compiler's count, not a reading).
 TABLE_CACHE_CAPACITY = 4096
+
+# one big-tier table: 64 windows x 16 entries x 4 coordinates x 32 limbs
+BIG_TABLE_BYTES = 64 * 16 * 4 * 32
+
+# the share of a device's memory the big store may grow to. A quarter
+# leaves three for the loaded programs (0.3 GB read), a round's operands
+# and temporaries, the copy a growth or an install beside a round in
+# flight makes (at most one more store), and whatever else the process
+# keeps there.
+TABLE_STORE_SHARE = 0.25
 
 # batches >= this bucket size use the big (doubling-free) tier; smaller
 # batches are latency-sensitive (live votes) and must not stall on the big
@@ -239,119 +257,279 @@ def _jit_program_family(big_impl, mesh: Mesh | None, sharded: bool) -> dict:
     }
 
 
-class _TableCache:
-    """One device-resident table store (pubkey -> row), lazily grown.
+def _device_table_capacity(device) -> int:
+    """Keys a table store may hold on `device`: TABLE_STORE_SHARE of the
+    memory the backend says it may use, counted in big-tier tables and
+    rounded down to the power-of-two rows `_grow` allocates (a v5e's 16
+    GB gives 16,384 or 32,768 by what `bytes_limit` reads). Where the
+    backend keeps no memory statistics (XLA:CPU) TABLE_CACHE_CAPACITY
+    stands."""
+    limit = int((device.memory_stats() or {}).get("bytes_limit", 0))
+    keys = int(limit * TABLE_STORE_SHARE) // BIG_TABLE_BYTES
+    if keys < 1:
+        return TABLE_CACHE_CAPACITY
+    return max(TABLE_ROWS_MIN, 1 << (keys.bit_length() - 1))
 
-    Thread-safety: all methods take the shared verifier lock — the vote
+
+# A build chunk's way into the store. On the TPU the store's rows are its
+# minor-most dimension, and XLA's scatter (`.at[rows].set`) transposes
+# the whole store there and back: two more stores of temporaries and a
+# third for the result, for every chunk (compiled for a v5e at 16,384
+# rows: 4.4 GB of temporaries beside 2 GiB in and 2 GiB out). A
+# dynamic-update-slice of a donated store is written in place with no
+# temporary at all, so the two installs below are made of those.
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _install_block(tables, valid, start, new_tables, new_valid):
+    """The whole chunk (its padding too) at rows start, start+1, ...:
+    fresh rows, which nothing reads before a key is given them."""
+    return (
+        jax.lax.dynamic_update_slice_in_dim(tables, new_tables, start, 0),
+        jax.lax.dynamic_update_slice_in_dim(valid, new_valid, start, 0),
+    )
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _install_rows(tables, valid, rows, n, new_tables, new_valid):
+    """The chunk's first `n` entries, entry j into row rows[j]: rows
+    taken over from evicted keys lie anywhere."""
+
+    def one(j, stores):
+        return tuple(
+            jax.lax.dynamic_update_slice_in_dim(
+                store, jax.lax.dynamic_slice_in_dim(new, j, 1), rows[j], 0
+            )
+            for store, new in zip(stores, (new_tables, new_valid))
+        )
+
+    return jax.lax.fori_loop(0, n, one, (tables, valid))
+
+
+@functools.partial(jax.jit, static_argnums=2)
+def _grown(tables, valid, rows: int):
+    """The store at `rows` rows: one new allocation beside the old one
+    (zeros then `.at[:cur].set` held three)."""
+    pad = [(0, rows - tables.shape[0])] + [(0, 0)] * (tables.ndim - 1)
+    return jnp.pad(tables, pad), jnp.pad(valid, pad[:1])
+
+
+class _TableCache:
+    """One device-resident table store (pubkey -> row), lazily grown up
+    to `capacity` rows. Keys that no longer fit take the rows of the
+    least recently used ones; the store never clears itself, so a hot
+    committee stays resident while validator sets rotate past it. A
+    round with more distinct keys than `capacity` gets no tables
+    (`lookup` returns None, counted in `fallback_rounds`) and is
+    answered by the generic program.
+
+    A snapshot in flight keeps its arrays: `tables` and `valid` are
+    replaced, never written, once `lookup` or `arrays` has handed them
+    out (`_lent`), so the round that holds an old pair still reads the
+    rows its `idx` names, whatever was installed or evicted since. Only
+    arrays nothing else holds are donated to the next install.
+
+    Thread-safety: all methods take the store's lock — the vote
     micro-batcher calls verify() from an executor thread while the event
-    loop verifies serially."""
+    loop verifies serially, and a warm runs on a thread of its own. A
+    build holds the lock, so `lookup` finds and snapshots under one hold
+    and nothing can evict a round's rows in between."""
 
     def __init__(
         self, lock, build_fn, entry_shape, capacity, nshards, registry=None,
-        tier="small",
+        tier="small", sharding=None,
     ):
         self._lock = lock
         self._build_fn = build_fn
         self._entry_shape = entry_shape  # per-key table dims after the row
-        self._capacity = capacity
+        self._capacity = max(1, capacity)
         self._nshards = nshards
         self._registry = registry or default_shape_registry()
+        self._sharding = sharding  # replicated over the mesh, or None
         self.tier = tier
         self._idx: dict[bytes, int] = {}
+        self._keys: list[bytes] = []  # row -> pubkey, for the rows in use
+        # row -> the last lookup (or warm) that named its key; one slot
+        # more, at the end, for a key without a row (-1) to stamp
+        self._used = np.zeros(self._capacity + 1, dtype=np.int64)
+        self._tick = 0
+        self._lent = False
         self.tables: jnp.ndarray | None = None
         self.valid: jnp.ndarray | None = None
         self.built = 0  # keys a table was built for, ever
+        self.evictions = 0  # keys that gave their row to another, ever
+        self.fallback_rounds = 0  # lookups the store could not hold, ever
 
     def _grow(self, needed_rows: int) -> None:
         rows = TABLE_ROWS_MIN
         while rows < needed_rows:
             rows *= 2
-        rows = min(rows, max(1, self._capacity))
+        rows = min(rows, self._capacity)
         cur = 0 if self.tables is None else self.tables.shape[0]
         if rows <= cur:
             return
-        # canonical uint8 limbs (neg_pubkey_table): 128 KiB/key big tier
-        tables = jnp.zeros((rows, *self._entry_shape), dtype=jnp.uint8)
-        valid = jnp.zeros(rows, dtype=bool)
         if cur:
-            tables = tables.at[:cur].set(self.tables)
-            valid = valid.at[:cur].set(self.valid)
-        self.tables, self.valid = tables, valid
+            self.tables, self.valid = _grown(self.tables, self.valid, rows)
+        else:
+            # canonical uint8 limbs (neg_pubkey_table): 128 KiB/key big tier
+            self.tables = jnp.zeros(
+                (rows, *self._entry_shape), dtype=jnp.uint8,
+                device=self._sharding,
+            )
+            self.valid = jnp.zeros(rows, dtype=bool, device=self._sharding)
+        self._lent = False
+
+    def _build(self, new: list[bytes], abort=None) -> int:
+        """Build + install tables for `new` (distinct, none resident; at
+        most `capacity` with the keys of this tick), a chunk at a time,
+        each chunk into fresh rows first and then into the rows of the
+        least recently used keys. Returns the keys evicted. Caller holds
+        the lock."""
+        in_use = len(self._keys)
+        self._grow(in_use + len(new))
+        spill = len(new) - (self._capacity - in_use)
+        victims = []
+        if spill > 0:
+            # rows no key of this tick names, the longest unused first
+            idle = np.flatnonzero(self._used[:in_use] < self._tick)
+            order = np.argsort(self._used[idle], kind="stable")
+            victims = idle[order[:spill]].tolist()
+        evicted = 0
+        for lo in range(0, len(new), TABLE_BUILD_CHUNK):
+            if abort is not None and abort.is_set():
+                break  # partial warm is fine; a later ensure finishes it
+            chunk = new[lo : lo + TABLE_BUILD_CHUNK]
+            b = self._registry.bucket_for(
+                len(chunk), multiple_of=self._nshards
+            )
+            # builds always shard over the full mesh (batch_verifier
+            # compiles the build fns with sharded inputs)
+            self._registry.record_dispatch(
+                "build_" + self.tier, b, devices=self._nshards
+            )
+            arr = np.zeros((b, 32), dtype=np.uint8)
+            for i, pk in enumerate(chunk):
+                arr[i] = np.frombuffer(pk, dtype=np.uint8)
+            with _traced(
+                "crypto.table_build",
+                keys=len(chunk), bucket=b, tier=self.tier,
+            ):
+                tables, valid = self._build_fn(jnp.asarray(arr))
+                first = len(self._keys)
+                rows = np.zeros(b, dtype=np.int32)
+                for i, pk in enumerate(chunk):
+                    if len(self._keys) < self._capacity:
+                        row = len(self._keys)
+                        self._keys.append(pk)
+                    else:
+                        row = victims[evicted]
+                        evicted += 1
+                        del self._idx[self._keys[row]]
+                        self._keys[row] = pk
+                    self._idx[pk] = row
+                    rows[i] = row
+                self._used[rows[: len(chunk)]] = self._tick
+                if self._lent:
+                    # a round may hold these arrays: they stay its own,
+                    # the install is written into a copy
+                    self.tables = jnp.copy(self.tables)
+                    self.valid = jnp.copy(self.valid)
+                    self._lent = False
+                if first + b <= self.tables.shape[0]:
+                    self.tables, self.valid = _install_block(
+                        self.tables, self.valid, first, tables, valid
+                    )
+                else:
+                    self.tables, self.valid = _install_rows(
+                        self.tables, self.valid, jnp.asarray(rows),
+                        len(chunk), tables, valid,
+                    )
+                self.built += len(chunk)
+                # the span is the build, not its enqueue, and the next
+                # chunk's output is not allocated while this one runs:
+                # enqueued back to back, 20 chunks held 0.9 GB more
+                # (3.35 against 2.45 GB while 10,000 keys were built)
+                self.tables.block_until_ready()
+        self.evictions += evicted
+        return evicted
+
+    def _rows_of(self, pubkeys: list[bytes]) -> np.ndarray:
+        """Each key's row, -1 without one; the rows found are stamped
+        with this tick. Caller holds the lock. One numpy call a step:
+        each one may hand the GIL to the host-prep thread for a whole
+        switch interval (`table_lookup_ms.catchup` read 17.4 ms with
+        seven of them where the parent's per-row loop read 13.5)."""
+        get = self._idx.get
+        found = np.array([get(pk, -1) for pk in pubkeys], dtype=np.int32)
+        self._used[found] = self._tick
+        return found
+
+    def _find_or_build(self, pubkeys: list[bytes], abort=None):
+        """(each key's row or None where the distinct keys exceed the
+        capacity, keys of `pubkeys` without a table on entry, keys
+        evicted). Caller holds the lock."""
+        self._tick += 1
+        found = self._rows_of(pubkeys)
+        if not len(found) or found.min() >= 0:
+            return found, 0, 0
+        missing = np.flatnonzero(found < 0)
+        new = list(dict.fromkeys(pubkeys[j] for j in missing))
+        if len(self._idx) + len(new) > self._capacity and (
+            len(set(pubkeys)) > self._capacity
+        ):
+            return None, len(missing), 0
+        evicted = self._build(new, abort)
+        return self._rows_of(pubkeys), len(missing), evicted
 
     def ensure(self, pubkeys: list[bytes], abort=None) -> bool:
-        """Build + install tables for unseen pubkeys. Returns False when
-        the batch alone exceeds capacity. The cache resets when full
-        (validator rotation must not silently degrade the hot path).
-        `abort` (threading.Event) stops between chunks — shutdown must
-        not wait for a multi-chunk build."""
+        """Build + install tables for the unseen keys among `pubkeys`
+        (a warm). Returns False, and builds nothing, when the keys alone
+        exceed the capacity. `abort` (threading.Event) stops between
+        chunks — shutdown must not wait for a multi-chunk build."""
         with self._lock:
-            new = []
-            seen = set()
-            for pk in pubkeys:
-                if pk not in self._idx and pk not in seen:
-                    seen.add(pk)
-                    new.append(pk)
-            if not new:
-                return True
-            if len(self._idx) + len(new) > self._capacity:
-                uniq = list(dict.fromkeys(pubkeys))
-                if len(uniq) > self._capacity:
-                    return False
-                self._idx.clear()
-                if self.valid is not None:
-                    self.valid = jnp.zeros_like(self.valid)
-                new = uniq
-            self._grow(len(self._idx) + len(new))
-            for lo in range(0, len(new), TABLE_BUILD_CHUNK):
-                if abort is not None and abort.is_set():
-                    return True  # partial warm is fine; ensure is idempotent
-                chunk = new[lo : lo + TABLE_BUILD_CHUNK]
-                b = self._registry.bucket_for(
-                    len(chunk), multiple_of=self._nshards
-                )
-                # builds always shard over the full mesh (batch_verifier
-                # compiles the build fns with sharded inputs)
-                self._registry.record_dispatch(
-                    "build_" + self.tier, b, devices=self._nshards
-                )
-                arr = np.zeros((b, 32), dtype=np.uint8)
-                for i, pk in enumerate(chunk):
-                    arr[i] = np.frombuffer(pk, dtype=np.uint8)
-                with _traced(
-                    "crypto.table_build",
-                    keys=len(chunk), bucket=b, tier=self.tier,
-                ) as traced:
-                    tables, valid = self._build_fn(jnp.asarray(arr))
-                    rows = []
-                    for pk in chunk:
-                        row = len(self._idx)
-                        self._idx[pk] = row
-                        rows.append(row)
-                    rows_j = jnp.asarray(np.asarray(rows, dtype=np.int32))
-                    self.tables = self.tables.at[rows_j].set(
-                        tables[: len(chunk)]
-                    )
-                    self.valid = self.valid.at[rows_j].set(
-                        valid[: len(chunk)]
-                    )
-                    self.built += len(chunk)
-                    if traced:
-                        # the span is the build, not its enqueue (a
-                        # build is rare: a restart, a new validator)
-                        self.tables.block_until_ready()
-            return True
+            return self._find_or_build(pubkeys, abort)[0] is not None
 
-    def snapshot(self, row_pubkeys: list[tuple[int, bytes]], b: int):
-        """(tables, valid, idx[b]) for the given (row, pubkey) pairs, or
-        None if any pubkey was concurrently evicted (caller retries)."""
+    def lookup(self, pubkeys: list[bytes], positions: list[int], b: int):
+        """One round's tables: `pubkeys[j]` is the key of batch row
+        `positions[j]`. Returns ((tables, valid, idx[b]) or None where
+        the round's distinct keys exceed the capacity, rows whose key
+        had no table when the lookup began, keys evicted). Finding,
+        building and the snapshot happen under one hold of the lock."""
         with self._lock:
+            found, missing, evicted = self._find_or_build(pubkeys)
+            if found is None:
+                self.fallback_rounds += 1
+                return None, missing, 0
             idx = np.full(b, -1, dtype=np.int32)
-            for i, pk in row_pubkeys:
-                row = self._idx.get(pk)
-                if row is None:
-                    return None
-                idx[i] = row
-            return self.tables, self.valid, idx
+            if positions[-1] == len(positions) - 1:  # every row well formed
+                idx[: len(positions)] = found
+            else:
+                idx[positions] = found
+            self._lent = True
+            return (self.tables, self.valid, idx), missing, evicted
+
+    def arrays(self):
+        """(tables, valid) as they stand, for a caller that runs a
+        program over them (the prewarm), or None before the first key."""
+        with self._lock:
+            if self.tables is None:
+                return None
+            self._lent = True
+            return self.tables, self.valid
+
+    def stats(self) -> dict:
+        """What the store holds and what it has done, for the service
+        dump's `table_store` block. Takes no lock: a dump must not wait
+        for a build."""
+        tables = self.tables
+        rows = 0 if tables is None else int(tables.shape[0])
+        return {
+            "rows_allocated": rows,
+            "keys_resident": len(self._idx),
+            "bytes": rows * (int(np.prod(self._entry_shape)) + 1),
+            "evictions": self.evictions,
+            "fallback_rounds": self.fallback_rounds,
+        }
 
 
 class BatchVerifier:
@@ -368,7 +546,7 @@ class BatchVerifier:
         self,
         mesh: Mesh | None = None,
         min_device_batch: int = 8,
-        table_cache_capacity: int = TABLE_CACHE_CAPACITY,
+        table_cache_capacity: int | None = None,
         device_challenge_min: int | None = None,
         bigtable_min: int = BIGTABLE_MIN,
         shape_registry: ShapeRegistry | None = None,
@@ -378,6 +556,10 @@ class BatchVerifier:
         — a device round-trip costs more than a handful of host verifies
         (the adaptive micro-batching tradeoff, SURVEY.md §7.3 hard part 3).
         Set to 0 to force everything onto the device.
+
+        table_cache_capacity: keys each table store holds before the
+        least recently used give way. None (default) derives it from the
+        device's memory (_device_table_capacity).
 
         device_challenge_min: batches >= this size compute the SHA-512
         challenges on device (fused into the verify program) instead of on
@@ -429,6 +611,11 @@ class BatchVerifier:
         # node on stop, cleared on start (the default verifier is shared
         # process-wide).
         self.shutdown_event = threading.Event()
+        if table_cache_capacity is None:
+            table_cache_capacity = _device_table_capacity(
+                jax.devices()[0] if mesh is None else mesh.devices.flat[0]
+            )
+        rep = None  # the stores' sharding: replicated over the mesh
         if mesh is None:
             self._nshards = 1
             # device count -> program family; meshless has only the
@@ -479,6 +666,7 @@ class BatchVerifier:
             self._nshards,
             registry=self._registry,
             tier="small",
+            sharding=rep,
         )
         self._big = _TableCache(
             threading.Lock(),
@@ -488,6 +676,7 @@ class BatchVerifier:
             self._nshards,
             registry=self._registry,
             tier="big",
+            sharding=rep,
         )
 
     # --- mesh topology -----------------------------------------------------
@@ -587,20 +776,19 @@ class BatchVerifier:
         if abort is None:
             abort = self.shutdown_event
         ladder = tuple(buckets) if buckets else self._registry.ladder
-        rows_small = (
-            int(self._small.tables.shape[0])
-            if self._small.tables is not None
-            else TABLE_ROWS_MIN
+        # the live stores where they exist (every lane is rejected, so
+        # what the tables hold is never read into a verdict): a second
+        # store of zeros would hold 2 GiB beside a 10,000-key committee
+        small_tables, tvalid_small = self._small.arrays() or (
+            jnp.zeros((TABLE_ROWS_MIN, 16, 4, 32), dtype=jnp.uint8),
+            jnp.zeros(TABLE_ROWS_MIN, dtype=bool),
         )
-        rows_big = (
-            int(self._big.tables.shape[0])
-            if self._big.tables is not None
-            else TABLE_ROWS_MIN
+        big_tables, tvalid_big = self._big.arrays() or (
+            jnp.zeros((TABLE_ROWS_MIN, 64, 16, 4, 32), dtype=jnp.uint8),
+            jnp.zeros(TABLE_ROWS_MIN, dtype=bool),
         )
-        small_tables = jnp.zeros((rows_small, 16, 4, 32), dtype=jnp.uint8)
-        big_tables = jnp.zeros((rows_big, 64, 16, 4, 32), dtype=jnp.uint8)
-        tvalid_small = jnp.zeros(rows_small, dtype=bool)
-        tvalid_big = jnp.zeros(rows_big, dtype=bool)
+        rows_small = int(small_tables.shape[0])
+        rows_big = int(big_tables.shape[0])
         out: list[dict] = []
         seen_prog: set[tuple[str, int, int]] = set()
         rungs = sorted({int(b) for b in ladder})
@@ -702,28 +890,30 @@ class BatchVerifier:
     def _table_lookup(self, cache, items, rows, b: int, n: int):
         """One round's way to its tables: (tables, valid, idx[b]) for
         the well-formed `rows` of `items` from `cache`, or None where
-        the cache cannot hold the batch. Two attempts: a concurrent
-        verify() can trigger the cache-reset path between ensure() and
-        snapshot(), evicting our rows; on a second miss the caller
-        falls through to the generic path rather than mis-rejecting (or
-        crashing on) valid signatures. Traced as `crypto.table_lookup`,
-        around the `crypto.table_build` of every chunk it had to
-        build."""
+        the round has more distinct keys than the store holds (the
+        caller then runs the generic program). Traced as
+        `crypto.table_lookup`, around the `crypto.table_build` of every
+        chunk it had to build: `built` keys without a table, `resident`
+        rows of the round that needed none built (their key had one when
+        the lookup began; a malformed row has no key to look up),
+        `evicted` keys that gave their rows up, `fallback`."""
         built = cache.built
-        snap = None
         with default_tracer().span(
             "crypto.table_lookup", n=n, tier=cache.tier
         ) as span:
-            row_pubkeys = [(i, items[i].pubkey) for i in rows]
-            pubkeys = [pk for _, pk in row_pubkeys]
-            for _ in range(2):
-                if not cache.ensure(pubkeys):
-                    break  # cache cannot hold this batch
-                snap = cache.snapshot(row_pubkeys, b)
-                if snap is not None:
-                    break
-            span.set(built=cache.built - built)
+            snap, missing, evicted = cache.lookup(
+                [items[i].pubkey for i in rows], rows, b
+            )
+            span.set(
+                built=cache.built - built, resident=n - missing,
+                evicted=evicted, fallback=snap is None,
+            )
         return snap
+
+    def table_store_stats(self) -> dict:
+        """Per tier: rows_allocated, keys_resident, bytes, evictions,
+        fallback_rounds (the last two cumulative)."""
+        return {c.tier: c.stats() for c in (self._small, self._big)}
 
     def verify(self, items: list[SigItem]) -> np.ndarray:
         """Returns a bool accept bitmap aligned with `items`.
@@ -912,9 +1102,9 @@ class BatchVerifier:
                     )
                 return out[:n]
 
-            # cache full: generic path (decompress in-batch; host
-            # challenges — this fallback is the validator-churn edge,
-            # not the bulk path)
+            # more distinct keys than the store holds: the generic
+            # program (decompress in-batch; host challenges), exact and
+            # counted in the store's fallback_rounds
             gkb = kb
             if gkb is None:
                 gkb = np.zeros((b, 32), dtype=np.uint8)

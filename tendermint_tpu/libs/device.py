@@ -28,13 +28,14 @@ def device_stamp() -> dict:
 
 
 def device_info() -> dict:
-    """device_stamp() plus bytes_in_use and peak_bytes_in_use where the
-    backend keeps memory stats (TPU does, XLA:CPU returns None)."""
+    """device_stamp() plus bytes_in_use, peak_bytes_in_use and
+    bytes_limit where the backend keeps memory stats (TPU does, XLA:CPU
+    returns None)."""
     import jax
 
     info = device_stamp()
     stats = jax.devices()[0].memory_stats() or {}
-    for key in ("bytes_in_use", "peak_bytes_in_use"):
+    for key in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit"):
         if key in stats:
             info[key] = int(stats[key])
     return info

@@ -703,6 +703,7 @@ class VerifyServiceServer:
                 "compile": compile_log().snapshot(),
                 "error_frames": self.error_frames,
                 "submit_frames": dict(self.submit_frames),
+                "table_store": self._table_store(),
             },
             "summary": ledger.summary(),
             "entries": ledger.entries(limit=entries) if entries > 0 else [],
@@ -711,6 +712,15 @@ class VerifyServiceServer:
                 k: dict(v) for k, v in sorted(self.client_stats.items())
             },
         }
+
+    def _table_store(self) -> dict:
+        """The verifier's key-table stores, per tier: rows_allocated,
+        keys_resident, bytes, and the cumulative evictions and
+        fallback_rounds (rounds with more distinct keys than the store
+        holds, answered by the generic program). Empty for a verifier
+        that keeps no such store."""
+        stats = getattr(self.scheduler.verifier, "table_store_stats", None)
+        return stats() if stats is not None else {}
 
     def _trace(self):
         from ..obs import default_tracer as _dt
