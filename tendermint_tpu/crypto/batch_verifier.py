@@ -43,9 +43,11 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import hashlib
 import threading
 import time
 from dataclasses import dataclass
+from itertools import compress
 
 import numpy as np
 
@@ -55,7 +57,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs import default_tracer
 from ..ops import ed25519_batch
-from .ed25519 import L, challenge
+from .ed25519 import L
 from .shape_registry import (
     DEFAULT_BUCKET_LADDER,
     ShapeRegistry,
@@ -145,6 +147,52 @@ class SigItem:
     msg: bytes
     sig: bytes  # 64 bytes
     key_type: str = "ed25519"
+
+
+# L's 32 bytes, most significant first
+_L_BE = np.frombuffer(L.to_bytes(32, "big"), dtype=np.uint8)
+
+
+def _column(chunks: list, width: int) -> np.ndarray:
+    """[len(chunks), width] uint8 over one join of `chunks`, each
+    `width` bytes (any buffer)."""
+    return np.frombuffer(b"".join(chunks), dtype=np.uint8).reshape(-1, width)
+
+
+def _rows32(b: int, at: np.ndarray, rows: np.ndarray) -> np.ndarray:
+    """A [b, 32] uint8 operand: batch row `at[j]` is `rows[j]`, every
+    other row (malformed, or padding up to the bucket) zero."""
+    out = np.zeros((b, 32), dtype=np.uint8)
+    out[at] = rows
+    return out
+
+
+def _below_l(s: np.ndarray) -> np.ndarray:
+    """s < L for each row of `s` ([m, 32] uint8, little-endian scalars):
+    the most significant byte that differs from L's decides, and s == L
+    (no byte differs: argmax reads 0, where both are equal) is False."""
+    s_be = s[:, ::-1]
+    first = (s_be != _L_BE).argmax(axis=1)
+    return s_be[np.arange(len(s)), first] < _L_BE[first]
+
+
+def _challenge_rows(b: int, at: np.ndarray, good: list) -> np.ndarray:
+    """The [b, 32] operand of challenges k = SHA-512(R || A || M) mod L
+    (crypto.ed25519.challenge, little-endian) of the well-formed items
+    `good`, at batch rows `at`. hashlib's hash, the reduction and
+    `to_bytes` are what a row costs on the host."""
+    sha512, from_bytes, cat = hashlib.sha512, int.from_bytes, b"".join
+    ks = [
+        (
+            from_bytes(
+                sha512(cat((it.sig[:32], it.pubkey, it.msg))).digest(),
+                "little",
+            )
+            % L
+        ).to_bytes(32, "little")
+        for it in good
+    ]
+    return _rows32(b, at, _column(ks, 32))
 
 
 class _PreparedBatch:
@@ -969,9 +1017,16 @@ class BatchVerifier:
 
     def prepare(self, items: list[SigItem]) -> "_PreparedBatch":
         """Host-side assembly of one batch: partition decisions, bucket
-        padding, array fills and sign-bytes challenge hashing — the
-        ~70 us/sig host work the §10 profile attributed to the bulk
-        path. Returns a handle whose `run()` performs the device
+        padding, array fills and sign-bytes challenge hashing. The
+        operands are built by columns: one join and one array a column,
+        the well-formed rows scattered by index, and per row only a
+        field, a length and (`_challenge_rows`) the hash and its
+        reduction. On the chip's host a 10,000-row round read 62.2 ms,
+        6.2 us a row, while a Python loop filled the arrays row by row
+        (PERF_LEDGER.jsonl, PR 27, `host_prep_ms.live50` of
+        `c10k.live`) and reads 22.7 ms, 2.3 us a row, by columns, of
+        which 1.6 are hashlib's SHA-512, `% L` and `to_bytes` (PERF.md
+        section 6, PR 28). Returns a handle whose `run()` performs the device
         dispatch (cache ensure/snapshot + jitted program) and blocks for
         the verdicts. `verify()` is `prepare(items).run()`; the dispatch
         scheduler splits the two so batch N+1's host assembly overlaps
@@ -979,10 +1034,9 @@ class BatchVerifier:
         n = len(items)
         if n == 0:
             return _PreparedBatch(0, lambda: np.zeros(0, dtype=bool))
-        other_idx = [
-            i for i, it in enumerate(items) if it.key_type != "ed25519"
-        ]
-        if other_idx:
+        kinds = [it.key_type for it in items]
+        if kinds.count("ed25519") != n:
+            other_idx = [i for i, k in enumerate(kinds) if k != "ed25519"]
             # mixed-key batches recurse through verify(); host-bound, so
             # the work stays on the dispatch side
             return _PreparedBatch(
@@ -1002,6 +1056,14 @@ class BatchVerifier:
                 )
 
             return _PreparedBatch(n, _run_host)
+        # a malformed row stays zeroed with s_ok False -> reject
+        ok = [len(it.pubkey) == 32 and len(it.sig) == 64 for it in items]
+        well_formed = list(compress(range(n), ok))
+        if not well_formed:
+            # nothing to verify on device (malformed pubkey/sig lengths);
+            # also keeps the lazy table stores untouched
+            return _PreparedBatch(n, lambda: np.zeros(n, dtype=bool))
+        good = list(compress(items, ok))
         # mesh decision: bulk rounds shard over every device (bucket
         # rounded up so the row slab divides evenly — the uneven tail is
         # verdict-inert padding), small rounds keep devices=1
@@ -1016,51 +1078,32 @@ class BatchVerifier:
             # its length class (pad_messages pads batch-wide); cap the
             # device-hash path at 2 KiB messages — vote/commit sign-bytes
             # are ~200 bytes, so the cap only excludes pathological rows
-            and all(
-                len(it.msg) + 64 <= 2048
-                for it in items
-                if len(it.pubkey) == 32 and len(it.sig) == 64
-            )
+            and max(len(it.msg) for it in good) + 64 <= 2048
         )
-        rb = np.zeros((b, 32), dtype=np.uint8)
-        sb = np.zeros((b, 32), dtype=np.uint8)
-        kb = None if device_hash else np.zeros((b, 32), dtype=np.uint8)
-        msgs = [b""] * b if device_hash else None
-        prefixes = [b""] * b if device_hash else None
+        at = np.array(well_formed, dtype=np.intp)
+        sig = _column([it.sig for it in good], 64)
+        rb = _rows32(b, at, sig[:, :32])
+        sb = _rows32(b, at, sig[:, 32:])
         s_ok = np.zeros(b, dtype=bool)
-        well_formed = []
-        for i, it in enumerate(items):
-            if len(it.pubkey) != 32 or len(it.sig) != 64:
-                continue  # leave row zeroed; s_ok stays False -> reject
-            r, s = it.sig[:32], it.sig[32:]
-            if device_hash:
-                # challenge k = SHA-512(R||A||M) computed on device, fused
-                # into the verify program (bulk-replay path)
-                msgs[i] = it.msg
-                prefixes[i] = r + it.pubkey
-            else:
-                k = challenge(r, it.pubkey, it.msg)
-                kb[i] = np.frombuffer(
-                    k.to_bytes(32, "little"), dtype=np.uint8
-                )
-            rb[i] = np.frombuffer(r, dtype=np.uint8)
-            sb[i] = np.frombuffer(s, dtype=np.uint8)
-            s_ok[i] = int.from_bytes(s, "little") < L
-            well_formed.append(i)
-
-        if not well_formed:
-            # nothing to verify on device (malformed pubkey/sig lengths);
-            # also keeps the lazy table stores untouched
-            return _PreparedBatch(n, lambda: np.zeros(n, dtype=bool))
-
+        s_ok[at] = _below_l(sig[:, 32:])
         if device_hash:
+            # challenge k = SHA-512(R||A||M) computed on device, fused
+            # into the verify program (bulk-replay path)
             from ..ops import sha512 as dev_sha512
 
+            pad = [b""] * (b - n)
             msg_buf, n_blocks = dev_sha512.pad_messages(
-                msgs, prefix_pairs=prefixes
+                [it.msg if k else b"" for it, k in zip(items, ok)] + pad,
+                prefix_pairs=[
+                    it.sig[:32] + it.pubkey if k else b""
+                    for it, k in zip(items, ok)
+                ]
+                + pad,
             )
+            kb = None
         else:
             msg_buf = n_blocks = None
+            kb = _challenge_rows(b, at, good)
 
         family = self._progs.get(devs) or self._progs[1]
 
@@ -1105,20 +1148,10 @@ class BatchVerifier:
             # more distinct keys than the store holds: the generic
             # program (decompress in-batch; host challenges), exact and
             # counted in the store's fallback_rounds
-            gkb = kb
-            if gkb is None:
-                gkb = np.zeros((b, 32), dtype=np.uint8)
-                for i in well_formed:
-                    it = items[i]
-                    k = challenge(it.sig[:32], it.pubkey, it.msg)
-                    gkb[i] = np.frombuffer(
-                        k.to_bytes(32, "little"), dtype=np.uint8
-                    )
-            pub = np.zeros((b, 32), dtype=np.uint8)
-            for i in well_formed:
-                pub[i] = np.frombuffer(items[i].pubkey, dtype=np.uint8)
+            pub = _rows32(b, at, _column([it.pubkey for it in good], 32))
             out = self._dispatch(
-                family["generic"], "generic", b, n, pub, rb, sb, gkb,
+                family["generic"], "generic", b, n, pub, rb, sb,
+                _challenge_rows(b, at, good) if kb is None else kb,
                 jnp.asarray(s_ok),
                 devices=devs,
             )

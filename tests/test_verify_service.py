@@ -192,10 +192,13 @@ def test_wire_codec_rejects_malformed():
 
 # --- the columnar submit frame ----------------------------------------------
 
+# "stamped" also carries t_submit, taken from the clock when the test
+# runs: the encoder stamps t_encoded from the same clock, which counts
+# from boot, so a constant would lie in a fresh machine's future
 TRAILERS = {
     "no-trailer": None,
     "old-trailer": (42, 1, "nodeA"),
-    "stamped": (42, 1, "nodeA", 1_234_567_890_123),
+    "stamped": (42, 1, "nodeA"),
 }
 
 
@@ -214,6 +217,9 @@ def test_submit_codec_round_trips(case, trailer):
     same."""
     items, uniform = CODEC_CASES[case]
     ctx = TRAILERS[trailer]
+    if trailer == "stamped":
+        t_submit_ns = time.perf_counter_ns()
+        ctx += (t_submit_ns,)
     frame = encode_submit(7, items, "blocksync", ctx=ctx)
     cur = body_of(frame, MSG_SUBMIT)
     assert decode_submit(cur) == (items, "blocksync", uniform)
@@ -228,8 +234,8 @@ def test_submit_codec_round_trips(case, trailer):
         assert c.off == len(c.buf)
         assert got_ctx == (None if ctx is None else (42, 1, "nodeA", 7))
         if trailer == "stamped":
-            assert stamps[0] == pytest.approx(1234.567890123)
-            assert stamps[1] >= stamps[0]
+            assert stamps[0] == pytest.approx(t_submit_ns * 1e-9, abs=1e-9)
+            assert stamps[0] <= stamps[1] <= time.perf_counter()
         else:
             assert stamps is None
     for it in decode_submit(body_of(frame, MSG_SUBMIT))[0]:
