@@ -194,8 +194,7 @@ class ConsensusTimeoutsConfig:
     # until PR 11): HasVotes possession-digest broadcast cadence, and
     # how many batch-capable peers a freshly-accepted vote chunk
     # eagerly relays to (0 disables eager relay; the paced pull plane
-    # still covers dissemination). Config-driven so the committee and
-    # sequencer bench families can sweep them without editing source.
+    # still covers dissemination).
     digest_interval: float = 0.2
     vote_forward_fanout: int = 3
 
@@ -372,19 +371,12 @@ class SchedulerConfig:
     # Off by default: short-lived/test nodes shouldn't pay the ladder.
     prewarm: bool = False
     prewarm_manifest: str = "data/prewarm_manifest.json"
-    # recent-round telemetry ring (scheduler.dispatch_log). Debug view
-    # only: entries past the cap age out silently, so stats tooling
-    # reads the device-cost LEDGER (obs/ledger.py, never truncates)
-    # instead — PR 8 hit this cap reading dispatch stats from the ring
-    dispatch_log_size: int = 1024
 
     def validate_basic(self) -> None:
         if self.max_batch < 1:
             raise ValueError("scheduler.max_batch must be >= 1")
         if self.mesh_min_rows < 1:
             raise ValueError("scheduler.mesh_min_rows must be >= 1")
-        if self.dispatch_log_size < 1:
-            raise ValueError("scheduler.dispatch_log_size must be >= 1")
         ladder = self.ladder()
         if ladder is not None and (not ladder or min(ladder) < 1):
             raise ValueError(

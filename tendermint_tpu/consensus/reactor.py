@@ -171,7 +171,7 @@ class ConsensusReactor(Reactor):
         self.cs = cs
         # gossip-pacing knobs ([consensus] digest_interval /
         # vote_forward_fanout): module constants stay the defaults, but
-        # bench sweeps and deployments drive them from config
+        # deployments drive them from config
         self.digest_interval = float(digest_interval)
         self.vote_forward_fanout = max(0, int(vote_forward_fanout))
         # committee-scale batched vote gossip ([consensus]
@@ -180,7 +180,7 @@ class ConsensusReactor(Reactor):
         # the pre-batch reactor, kept for mixed-version interop tests
         self.vote_batch = bool(vote_batch)
         self.vote_batch_max = max(1, int(vote_batch_max))
-        # gossip-efficiency telemetry (bench --family committee_scale):
+        # gossip-efficiency telemetry:
         # a "tick" is one vote-gossip loop pass that shipped >= 1 vote;
         # the one-vote-per-tick baseline pins votes/tick at 1, batching
         # lifts it toward vote_batch_max

@@ -18,10 +18,10 @@ This module owns that ladder and the process-wide accounting:
   DIFFERENT XLA program than the single-device round at the same
   bucket (the sharding is part of the lowering), so `devices` is a
   first-class shape dimension: per-mesh programs stay inside the same
-  budget accounting as everything else. bench.py snapshots this around
-  each metric so shape/dispatch regressions land in the JSON artifact
-  instead of cProfile archaeology, and the shape-budget regression
-  test asserts the bench verify family stays within a bounded ladder.
+  budget accounting as everything else. The service dump and the
+  `dump_dispatch_ledger` RPC carry a snapshot, and the shape-budget
+  regression test (tests/test_prewarm.py) asserts the vote and commit
+  verify shapes stay within a bounded ladder.
 
 Stdlib only; thread-safe (dispatches happen from executor threads, the
 scheduler's dispatch thread, and test harness threads concurrently).
@@ -133,8 +133,7 @@ class ShapeRegistry:
             }
 
     def snapshot(self) -> dict:
-        """Point-in-time view; feed two of these to `delta` for the
-        per-metric bench accounting."""
+        """Point-in-time view; feed two of these to `delta`."""
         with self._lock:
             return {
                 "distinct_program_shapes": sum(
@@ -151,8 +150,8 @@ class ShapeRegistry:
     @staticmethod
     def delta(before: dict, after: dict) -> dict:
         """New-shapes/dispatches between two snapshots. The sharded
-        count rides next to device_dispatch_count so a bench artifact
-        shows whether a metric's rounds actually went through the mesh
+        count rides next to device_dispatch_count so a reader sees
+        whether the rounds in between actually went through the mesh
         (a meshless run records sharded = 0)."""
         return {
             "distinct_program_shapes": (

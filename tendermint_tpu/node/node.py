@@ -485,7 +485,6 @@ class Node(Service):
                 VerifyScheduler(
                     max_batch=config.scheduler.max_batch,
                     logger=self.logger,
-                    dispatch_log_size=config.scheduler.dispatch_log_size,
                 )
             )
             if self.health_monitor is not None:

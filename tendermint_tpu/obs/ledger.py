@@ -28,14 +28,12 @@ the stream into:
 Determinism and shape follow `obs/health.py`: every entry takes an
 explicit event time `t` (the scheduler stamps its own perf_counter
 values); nothing here reads a clock. Stdlib only, thread-safe (the
-scheduler records from its event loop; bench/RPC/soak read from
-other threads).
+scheduler records from its event loop; RPC, soak and the service's
+stats port read from other threads).
 
 Accounting truth lives in the CUMULATIVE totals, which never cap; the
 bounded entry ring is a recent-detail view (the RPC dump's `entries`,
 and the fill percentiles, which are computed over retained entries).
-The scheduler's `dispatch_log` deque is telemetry only — PR 8 already
-hit its 1024-cap reading stats from it; read this ledger instead.
 """
 
 from __future__ import annotations
@@ -46,8 +44,8 @@ from typing import Optional
 
 from .report import pct
 
-# entry ring default: enough to hold several bench families' worth of
-# rounds; totals are exact regardless
+# entry ring default: a few minutes of live rounds; totals are exact
+# regardless
 DEFAULT_ENTRY_RING = 4096
 
 
@@ -248,9 +246,9 @@ class DispatchLedger:
             }
 
     def mark(self) -> dict:
-        """Opaque position for `summary(since=...)` — bench families
-        bracket a run with mark()/summary() the way they bracket the
-        shape registry with snapshot()/delta()."""
+        """Opaque position for `summary(since=...)`: a reader brackets
+        a run with mark()/summary() the way it brackets the shape
+        registry with snapshot()/delta()."""
         return self.totals()
 
     def entries(self, since_seq: int = 0, limit: int = 0) -> list[dict]:
@@ -386,7 +384,7 @@ _default_lock = threading.Lock()
 def default_ledger() -> DispatchLedger:
     """Process-wide ledger every VerifyScheduler records into unless
     handed an explicit one (tests isolate with their own instance) —
-    the default-shape-registry pattern, so bench/soak capture every
+    the default-shape-registry pattern, so a soak captures every
     scheduler in the process with one mark()/summary() pair."""
     global _default
     if _default is None:

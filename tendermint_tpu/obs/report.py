@@ -28,7 +28,7 @@ STEP_ORDER = (
 
 def pct(xs: list[float], q: float) -> float:
     """Index-based percentile (0 on empty) — the one implementation the
-    attribution tables, cluster reports, and bench artifacts share."""
+    attribution tables and cluster reports share."""
     if not xs:
         return 0.0
     xs = sorted(xs)
@@ -40,8 +40,8 @@ _pct = pct
 
 def attribution(records: list[dict]) -> dict:
     """Per-span-name p50/p95/max duration (ms) + count over span records.
-    The bench/soak artifacts attach this so a throughput scalar comes
-    with its breakdown."""
+    The soak artifact attaches this so a throughput scalar comes with
+    its breakdown."""
     durs: dict[str, list[float]] = {}
     heights = set()
     for r in records:
@@ -71,8 +71,7 @@ def attribution(records: list[dict]) -> dict:
     }
 
 
-# wall-per-height attribution buckets (tools/pacing_report.py + the
-# consensus_pacing/committee_scale/sequencer_stream bench families).
+# wall-per-height attribution buckets (tools/pacing_report.py).
 # For the consensus family the cs.* step spans partition a height's
 # wall clock by construction (each closes at the transition to the
 # next), so bucketing THEM — not the nested exec/store spans, which
@@ -83,17 +82,14 @@ def attribution(records: list[dict]) -> dict:
 # The sequencer family maps the post-upgrade streaming plane's seq.*
 # spans (broadcast_reactor.py) the same way: parked fallback waits are
 # the floor, catchup/fan-out the gossip bucket, apply/verify compute.
-# committee_scale nets run the same cs.* state machine, so they share
-# the consensus classification.
 WALL_FLOOR_SPANS = frozenset(
     {"cs.new_height", "cs.prevote_wait", "cs.precommit_wait"}
 )
 WALL_GOSSIP_SPANS = frozenset({"cs.propose", "cs.prevote", "cs.precommit"})
 WALL_COMPUTE_SPANS = frozenset({"cs.commit", "cs.new_round"})
 
-# family name -> (floor, gossip, compute) span sets. "consensus" also
-# serves the committee_scale bench family; "sequencer" covers the
-# BlockV2 streaming plane (heights there are V2/L2 heights).
+# family name -> (floor, gossip, compute) span sets. "sequencer" covers
+# the BlockV2 streaming plane (heights there are V2/L2 heights).
 FAMILY_WALL_SPANS: dict[str, tuple[frozenset, frozenset, frozenset]] = {
     "consensus": (WALL_FLOOR_SPANS, WALL_GOSSIP_SPANS, WALL_COMPUTE_SPANS),
     "sequencer": (
@@ -385,13 +381,13 @@ def wall_conservation(records: list[dict], n_heights: int = 64) -> dict:
 
 
 def check_conservation(block: dict, tolerance: float = 0.002) -> list[str]:
-    """Schema validation for a wall_conservation block (bench artifacts,
-    tools/bench_trend.py): every height's buckets must sum to its wall
-    within `tolerance` (fractional), and the aggregate must carry the
-    dark_fraction fields. Under height pipelining buckets may exceed the
-    wall, but only by the explicitly booked `pipeline_overlap_ms` —
-    unbooked excess is still a violation. Pre-pipelining artifacts carry
-    no overlap key, which reads as 0.0: their check is unchanged.
+    """Schema validation for a wall_conservation block: every height's
+    buckets must sum to its wall within `tolerance` (fractional), and
+    the aggregate must carry the dark_fraction fields. Under height
+    pipelining buckets may exceed the wall, but only by the explicitly
+    booked `pipeline_overlap_ms` — unbooked excess is still a
+    violation. Pre-pipelining artifacts carry no overlap key, which
+    reads as 0.0: their check is unchanged.
     Returns a list of violation strings (empty = valid)."""
     errs: list[str] = []
     if not isinstance(block, dict):

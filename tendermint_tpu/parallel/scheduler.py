@@ -188,7 +188,6 @@ class VerifyScheduler:
         logger: Optional[Logger] = None,
         metrics: Optional[SchedulerMetrics] = None,
         ledger: Optional[DispatchLedger] = None,
-        dispatch_log_size: int = 1024,
         tracer=None,
     ):
         self.verifier = verifier or default_verifier()
@@ -213,15 +212,6 @@ class VerifyScheduler:
         self._accepting = False
         self._prep_pool: Optional[ThreadPoolExecutor] = None
         self._dispatch_pool: Optional[ThreadPoolExecutor] = None
-        # telemetry for tests/debugging ONLY: recent rounds as
-        # {n, subs, classes, fill} dicts, bounded at dispatch_log_size
-        # ([scheduler] dispatch_log_size, default 1024) — entries past
-        # the cap silently age out, so the LEDGER above, whose totals
-        # never truncate, is the accounting source of truth (PR 8 hit
-        # the 1024-cap reading stats from this ring)
-        self.dispatch_log: deque = deque(
-            maxlen=max(1, int(dispatch_log_size))
-        )
 
     # --- lifecycle ---------------------------------------------------------
 
@@ -613,10 +603,6 @@ class VerifyScheduler:
             sub = round_[1]
             if not sub.future.done():
                 sub.future.set_result(verdicts)
-            self.dispatch_log.append(
-                {"n": sub.n, "subs": 1, "classes": [sub.klass],
-                 "fn": True, "engine": sub.engine}
-            )
             wait = t0 - sub.t_enq
             self.metrics.device_seconds.inc(dur, klass=sub.klass)
             # fn engines pad INTERNALLY (a 150-signer bls_agg group runs
@@ -705,11 +691,6 @@ class VerifyScheduler:
             class_queue_wait=class_wait,
             host_prep_s=prep_s,
             device_s=dur,
-        )
-        self.dispatch_log.append(
-            {"n": total, "subs": n_subs, "classes": classes,
-             "fill": round(fill, 4), "sharded": devices > 1,
-             "devices": devices}
         )
         tracer.add_span(
             "scheduler.queue_wait", oldest, t0 - oldest, n=total

@@ -664,18 +664,26 @@ class VerifyServiceServer:
         )
 
     async def stop(self) -> None:
-        for srv in (self._server, self._stats_server):
-            if srv is not None:
-                srv.close()
-                await srv.wait_closed()
+        servers = [
+            s for s in (self._server, self._stats_server) if s is not None
+        ]
         self._server = self._stats_server = None
-        for t in list(self._conn_tasks):
+        for srv in servers:
+            srv.close()
+        # the connection tasks end before wait_closed(): since Python
+        # 3.12 it waits for every open connection, so awaiting it first
+        # never returns while a client is attached, and the client
+        # never learns that the service is gone
+        tasks = list(self._conn_tasks)
+        for t in tasks:
             t.cancel()
-        for t in list(self._conn_tasks):
+        for t in tasks:
             try:
                 await t
             except (asyncio.CancelledError, Exception):
                 pass
+        for srv in servers:
+            await srv.wait_closed()
         if self.profiler.active:
             await self._profile_stop()
         self._profile_pool.shutdown(wait=False)

@@ -50,7 +50,6 @@ _SLOW_MODULES = {
     "test_ops_bls_g1",
     "test_ops_bls_g2",
     "test_ops_bls_pairing",
-    "test_bench_scenarios",
     "test_ops_secp",
     "test_blocksync",
     "test_light",

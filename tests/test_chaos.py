@@ -306,10 +306,8 @@ def test_chunk_fetch_rotates_away_from_failing_peer():
 
 
 # --- the strict device rule --------------------------------------------------
-# One test per test of the removed guard (probe classification, the
-# always-parseable multichip artifact, degrade-to-JSON with exit 0,
-# --require-backend): a run that finds no chip fails, and so does a run
-# in which a metric block raised.
+# A run that finds no chip fails: no fallback that hides the device.
+# (chip_smoke.py and the benchmark hold the same rule in their own tests.)
 
 
 def _env_without_platform() -> dict:
@@ -354,88 +352,6 @@ def test_multichip_capture_exits_nonzero_without_a_chip():
     assert proc.returncode != 0, proc.stdout
     assert "no accelerator" in proc.stderr
     assert not proc.stdout.strip()  # no artifact, no fallback row
-
-
-def test_bench_exits_nonzero_without_a_chip():
-    """bench.py's device suite with no chip and no explicit
-    JAX_PLATFORMS=cpu: non-zero exit, nothing on stdout — where the
-    guard used to re-capture on the CPU and exit 0."""
-    import subprocess
-
-    proc = subprocess.run(
-        [sys.executable, "bench.py"],
-        capture_output=True,
-        text=True,
-        timeout=300,
-        env=_env_without_platform(),
-        cwd="/root/repo",
-    )
-    assert proc.returncode != 0, proc.stdout
-    assert "no accelerator" in proc.stderr
-    assert not proc.stdout.strip()
-
-
-def test_bench_exits_nonzero_when_a_metric_block_raised(capsys, monkeypatch):
-    """A raised metric block keeps the later blocks running and ends
-    the run non-zero, naming the block in the artifact. Two blocks of
-    the real _extra_metrics raise here (the replay's fixture and the
-    commit path); the expensive later helpers are stubbed, the cheap
-    earlier blocks run as they are."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    import bench
-    import tests.helpers
-
-    def boom(*a, **k):
-        raise RuntimeError("boom")
-
-    monkeypatch.setattr(tests.helpers, "make_validators", boom)
-    monkeypatch.setattr(bench, "_bench_commit_path", boom)
-    monkeypatch.setattr(bench, "_bench_light_bisection", lambda: (1.0, 2, 3.0))
-    monkeypatch.setattr(
-        bench, "_bench_light_bisection_1k", lambda: (1.0, 2, 3.0)
-    )
-    monkeypatch.setattr(
-        bench, "_bench_table_build", lambda: [{"metric": "table_build"}]
-    )
-    monkeypatch.setattr(bench, "_bench_churn_throughput", lambda: (1.0, 2.0))
-    monkeypatch.setattr(
-        bench, "_bench_vote_latency", lambda: [{"metric": "vote_latency"}]
-    )
-    z = jnp.zeros(4, dtype=jnp.int32)
-    failed: list = []
-    out = bench._extra_metrics(
-        lambda *a: np.ones(4, dtype=bool), z, z, z, z, z, z, z, failed
-    )
-    assert failed == ["blocksync replay metric", "commit-path family"]
-    err = capsys.readouterr().err
-    assert "blocksync replay metric failed" in err
-    assert "commit-path family failed" in err
-    names = [m["metric"] for m in out]
-    assert "blocksync_replay_commits_per_s" not in names
-    # the blocks before the raise and every block after it still report
-    assert names[:4] == [
-        "ed25519_commit10k_latency",
-        "bls_aggregate_verify_1k",
-        "secp256k1_verify_throughput",
-        "sha256_kernel_throughput",
-    ]
-    assert names[4:] == [
-        "light_bisection_throughput",
-        "light_bisection_1k",
-        "table_build",
-        "ed25519_churn_throughput",
-        "vote_latency",
-    ]
-    with pytest.raises(SystemExit) as e:
-        bench._finish({"metric": "m", "value": 1.0}, failed)
-    assert e.value.code == 1
-    art = json.loads(capsys.readouterr().out.strip())
-    assert art["failed_phases"] == failed
-    # and a clean run prints the artifact and returns
-    bench._finish({"metric": "m", "value": 1.0}, [])
-    assert "failed_phases" not in json.loads(capsys.readouterr().out)
 
 
 # --- scenario e2e on a 4-validator mesh -------------------------------------

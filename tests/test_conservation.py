@@ -7,15 +7,12 @@ its tracer pull seam, the UDS trace-context propagation (client stamps
 span context on each verify submission; the service records
 queue/device sub-spans under it into its own ring with a dump
 endpoint), the cluster merge of service dumps alongside validator dumps
-(wall-anchor fallback for nodes outside the NTP peer graph), the
-bench_trend conservation schema validation + dark-time gate, and the
+(wall-anchor fallback for nodes outside the NTP peer graph), and the
 4-validator acceptance: attribution buckets cover >= 95% of measured
 wall per height on a live net with tracing on."""
 
 import asyncio
 import json
-import subprocess
-import sys
 from types import SimpleNamespace
 
 import numpy as np
@@ -492,80 +489,6 @@ def test_dump_traces_conservation_and_injected_empty_tracer():
     assert cons["schema"] == obs.CONSERVATION_SCHEMA
     assert cons["heights"]["6"]["dark_time_ms"] == pytest.approx(0.0)
     assert json.loads(json.dumps(dump))  # artifact-grade JSON
-
-
-# --- bench_trend: schema validation + dark gate (satellite) -----------------
-
-
-def _artifact(round_no, dark_fraction, tamper=False):
-    recs = _height_records(1, 0.0)
-    if dark_fraction:
-        recs = [
-            _span("cs.new_height", 0.0, 1.0 - dark_fraction, height=1),
-            _span("cs.commit", 1.0, 0.001, height=1),
-        ]
-    block = wall_conservation(recs)
-    if tamper:
-        block["heights"][1]["gossip_ms"] += 500.0
-    return {
-        "metric": "ed25519_vote_verify_throughput",
-        "value": 70000.0,
-        "unit": "sigs/s/chip",
-        "meta": {"backend": "cpu", "device_count": 1},
-        "wall_conservation": block,
-    }
-
-
-def test_bench_trend_conservation_validation_and_gate(tmp_path):
-    import tools.bench_trend as bt
-
-    ok = tmp_path / "BENCH_r90.json"
-    ok.write_text(json.dumps(_artifact(90, 0.0)))
-    rows, skipped, cons = bt.ingest([str(ok)])
-    assert rows and not skipped
-    assert cons and cons[0]["dark_fraction"] <= 0.001
-    assert bt.check_dark(cons, threshold=0.05) == []
-
-    # buckets that fail the sum check reject the artifact's rows
-    bad = tmp_path / "BENCH_r91.json"
-    bad.write_text(json.dumps(_artifact(91, 0.0, tamper=True)))
-    rows, skipped, _ = bt.ingest([str(bad)])
-    assert not rows and skipped
-    assert "conservation violation" in skipped[0]["reason"]
-
-    # dark fraction past the threshold fails the gate on the LATEST
-    # round only (older rounds already landed)
-    dark = tmp_path / "BENCH_r92.json"
-    dark.write_text(json.dumps(_artifact(92, 0.5)))
-    _, _, cons = bt.ingest([str(ok), str(dark)])
-    fails = bt.check_dark(cons, threshold=0.05)
-    assert len(fails) == 1 and fails[0]["file"] == "BENCH_r92.json"
-
-    # CLI contract: rc=1 with the dark-gate failure named
-    out = subprocess.run(
-        [
-            sys.executable, "tools/bench_trend.py", "--check", "--no-scan",
-            str(ok), str(dark),
-        ],
-        capture_output=True,
-        text=True,
-        cwd="/root/repo",
-        timeout=120,
-    )
-    assert out.returncode == 1, out.stderr
-    assert "dark-time gate" in out.stderr
-    # ...and rc=0 once the dark artifact is out of the set
-    out = subprocess.run(
-        [
-            sys.executable, "tools/bench_trend.py", "--check", "--no-scan",
-            str(ok),
-        ],
-        capture_output=True,
-        text=True,
-        cwd="/root/repo",
-        timeout=120,
-    )
-    assert out.returncode == 0, out.stderr
 
 
 # --- the 4-validator acceptance ---------------------------------------------

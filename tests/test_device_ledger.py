@@ -266,24 +266,6 @@ def test_scheduler_records_sig_and_fn_rounds():
     assert s.metrics.padding_rows.value() == summ["padding_rows"]
 
 
-def test_scheduler_dispatch_log_size_configurable():
-    s = _sched(dispatch_log_size=4)
-    assert s.dispatch_log.maxlen == 4
-    # ledger is the accounting source of truth past the ring cap: the
-    # docstring note is load-bearing, the behavior is what we pin
-    led = s.ledger
-
-    async def run():
-        await s.start()
-        for i in range(10):
-            await s.submit([_item(i)], "consensus")
-        await s.stop()
-
-    asyncio.run(run())
-    assert len(s.dispatch_log) == 4  # ring truncated
-    assert led.summary()["rounds"] == 10  # ledger did not
-
-
 def test_scheduler_ledger_reconciles_with_shape_registry():
     """Acceptance: ledger totals reconcile with the shape-registry
     dispatch counters — in steady state (key tables warm) every
@@ -618,43 +600,3 @@ def test_device_report_cli_roundtrip(tmp_path, capsys, monkeypatch):
     bad.write_text("{}")
     monkeypatch.setattr("sys.argv", ["device_report.py", str(bad)])
     assert device_report.main() == 1
-
-
-# --- bench/trend integration -------------------------------------------------
-
-
-def test_bench_trend_ingests_device_cost_block():
-    import tools.bench_trend as bt
-
-    payload = {
-        "metric": "x_throughput",
-        "value": 1.0,
-        "device_cost": dict(
-            _sample_summary(), fill_ratio_p50=0.9, fill_ratio_p95=0.2
-        ),
-    }
-    rows = bt._ledger_rows(payload)
-    by_metric = {r["metric"]: r for r in rows}
-    assert by_metric["scheduler_fill_ratio_p50"]["value"] == 0.9
-    assert by_metric["scheduler_fill_ratio_p95"]["value"] == 0.2
-    frac = by_metric["scheduler_padding_fraction"]["value"]
-    assert frac == pytest.approx(412 / 576, abs=1e-4)
-    # padding regresses UPWARD: direction must be "lower is better"
-    assert bt.direction_of("scheduler_padding_fraction") == "lower"
-    assert bt.family_of("scheduler_padding_fraction") == "scheduler"
-    # a zero-round block emits nothing (no eternal fill-0 regression)
-    assert bt._ledger_rows({"device_cost": DispatchLedger().summary()}) == []
-    # ...and so does a span of ONLY fn-lane rounds, whose fill
-    # percentiles are a meaningless 0.0
-    fn_led = DispatchLedger()
-    fn_led.record_round(
-        1.0, class_rows={"sequencer": 9}, requested=9, dispatched=9,
-        device_s=0.01, engine="fn",
-    )
-    assert bt._ledger_rows({"device_cost": fn_led.summary()}) == []
-    # and the rows ride _metric_rows as non-headline entries
-    pairs = bt._metric_rows(payload)
-    assert any(
-        r["metric"] == "scheduler_padding_fraction" and not headline
-        for r, headline in pairs
-    )

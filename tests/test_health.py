@@ -1,5 +1,4 @@
-"""Live health plane (tendermint_tpu/obs/health.py) + bench-trend gate
-(tools/bench_trend.py).
+"""Live health plane (tendermint_tpu/obs/health.py).
 
 Three layers, mirroring the PR 7 pacing suite:
 
@@ -16,21 +15,10 @@ Three layers, mirroring the PR 7 pacing suite:
   the PR 5 weighted-quorum topology must flip the victim's quorum-lag
   detector to warn — and only consensus-plane detectors — within K=10
   heights, with the `health.incident` record landing in the node's
-  dump_traces ring and zero false-critical on the clean phase;
-
-plus the bench-trajectory regression gate: unit tests of the backend
-partition / direction / gate math, and CLI smoke over the checked-in
-BENCH_r* artifacts (all CPU captures) plus two chip-stamped rows built
-under tmp_path (exit 0; the honest-CPU rows sit at ~3% of the chip rows
-and must NOT flag) and over a synthetic 20%-regressed row on a matching
-backend (exit non-zero).
+  dump_traces ring and zero false-critical on the clean phase.
 """
 
 import asyncio
-import json
-import os
-import subprocess
-import sys
 import time
 
 import pytest
@@ -59,8 +47,6 @@ from tendermint_tpu.obs.health import (
 )
 
 pytestmark = pytest.mark.health
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _slo(objective=0.9, short=30.0, long=300.0, **kw):
@@ -812,245 +798,3 @@ def test_chaos_straggler_flips_quorum_lag_to_warn():
     for sub in ("scheduler", "wal", "sequencer", "lightserve", "p2p",
                 "runtime"):
         assert g.value(subsystem=sub) == OK, sub
-
-
-# --- bench-trend: backend-partitioned regression gate -----------------------
-
-
-def _bt():
-    sys.path.insert(0, REPO)
-    from tools import bench_trend
-
-    return bench_trend
-
-
-def test_trend_family_and_direction_classification():
-    bt = _bt()
-    assert bt.family_of("ed25519_vote_verify_throughput") == "crypto"
-    assert bt.family_of("consensus_pacing_wall_per_height") == (
-        "consensus_pacing"
-    )
-    assert bt.family_of("sequencer_stream_blocks_per_s") == (
-        "sequencer_stream"
-    )
-    assert bt.family_of("lightserve_clients_per_s") == "lightserve"
-    assert bt.direction_of("ed25519_vote_verify_throughput") == "higher"
-    assert bt.direction_of("consensus_pacing_wall_per_height") == "lower"
-    assert bt.direction_of("sequencer_apply_latency_p95") == "lower"
-    assert bt.direction_of("bls_aggregate_verify_1k") == "lower"  # override
-
-
-def test_trend_backend_partition_and_gate_math(tmp_path):
-    bt = _bt()
-
-    def art(name, metric, value, backend, rnd, extra=None):
-        p = tmp_path / f"BENCH_{name}_r{rnd:02d}.json"
-        doc = {
-            "metric": metric,
-            "value": value,
-            "unit": "sigs/s",
-            "meta": {"backend": backend, "device_count": 1},
-        }
-        if extra:
-            doc["extra_metrics"] = extra
-        p.write_text(json.dumps(doc))
-        return str(p)
-
-    files = [
-        art("tpu_a", "ed25519_vote_verify_throughput", 77000.0, "tpu", 2),
-        art("tpu_b", "ed25519_vote_verify_throughput", 75000.0, "tpu", 3),
-        art("cpu_a", "ed25519_vote_verify_throughput", 2300.0, "cpu", 4),
-        art("cpu_b", "ed25519_vote_verify_throughput", 2250.0, "cpu", 6),
-    ]
-    rows, skipped, _ = bt.ingest(files)
-    assert not skipped and len(rows) == 4
-    groups = bt.build_groups(rows)
-    # rows partition by backend: the 2.3k CPU rows NEVER compare
-    # against the 77k TPU captures
-    assert len(groups) == 2
-    by_backend = {g["backend"]: g for g in groups}
-    assert by_backend["cpu"]["best"] == 2300.0
-    assert by_backend["cpu"]["regression"] == pytest.approx(
-        (2300.0 - 2250.0) / 2300.0, abs=1e-4
-    )
-    assert by_backend["tpu"]["regression"] == pytest.approx(
-        (77000.0 - 75000.0) / 77000.0, abs=1e-4
-    )
-    failures, warnings = bt.check_gate(groups, threshold=0.15)
-    assert not failures and not warnings
-
-    # a 20% same-backend regression of a tier-1 headline fails the gate
-    files.append(
-        art("cpu_c", "ed25519_vote_verify_throughput", 1840.0, "cpu", 7)
-    )
-    rows, _, _ = bt.ingest(files)
-    failures, _ = bt.check_gate(bt.build_groups(rows), threshold=0.15)
-    assert len(failures) == 1
-    assert failures[0]["backend"] == "cpu"
-    assert failures[0]["regression"] > 0.15
-
-    # extra-metric regressions warn instead of failing (strict flips)
-    files = files[:4] + [
-        art(
-            "cpu_x",
-            "ed25519_vote_verify_throughput",
-            2290.0,
-            "cpu",
-            8,
-            extra=[
-                {"metric": "ed25519_commit10k_latency", "value": 100.0,
-                 "unit": "ms"},
-            ],
-        ),
-        art(
-            "cpu_y",
-            "ed25519_vote_verify_throughput",
-            2280.0,
-            "cpu",
-            9,
-            extra=[
-                {"metric": "ed25519_commit10k_latency", "value": 150.0,
-                 "unit": "ms"},
-            ],
-        ),
-    ]
-    rows, _, _ = bt.ingest(files)
-    failures, warnings = bt.check_gate(bt.build_groups(rows), 0.15)
-    assert not failures and len(warnings) == 1
-    failures, warnings = bt.check_gate(
-        bt.build_groups(rows), 0.15, strict=True
-    )
-    assert len(failures) == 1 and not warnings
-
-
-def test_trend_ingest_normalizes_historical_shapes(tmp_path):
-    bt = _bt()
-    # the wrapped {rc, tail, parsed} shape of the earliest artifacts,
-    # with the device named by a meta stamp (both spellings of it)
-    wrapped = tmp_path / "BENCH_r90.json"
-    wrapped.write_text(json.dumps({
-        "rc": 0,
-        "tail": "...",
-        "parsed": {"metric": "ed25519_vote_verify_throughput",
-                   "value": 70000.0, "unit": "sigs/s/chip",
-                   "meta": {"backend": "tpu", "device_count": 1}},
-    }))
-    stamped = tmp_path / "BENCH_r93.json"
-    stamped.write_text(json.dumps({
-        "metric": "ed25519_vote_verify_throughput",
-        "value": 71000.0, "unit": "sigs/s/chip",
-        "meta": {"platform": "tpu", "device_kind": "TPU v5 lite",
-                 "device_count": 1},
-    }))
-    # structured backend-mismatch failure: a skip, never a value
-    failed = tmp_path / "BENCH_r91.json"
-    failed.write_text(json.dumps({
-        "rc": 1, "error": "no TPU endpoint", "kind": "backend_mismatch",
-        "backend": "cpu",
-    }))
-    # unreadable artifact: a skip, not a crash
-    broken = tmp_path / "BENCH_r92.json"
-    broken.write_text("{not json")
-    rows, skipped, _ = bt.ingest(
-        [str(wrapped), str(failed), str(broken), str(stamped)]
-    )
-    assert len(rows) == 2
-    assert {r["backend"] for r in rows} == {"tpu"}  # from the stamps
-    assert sorted(r["round"] for r in rows) == [90, 93]
-    # an unlabeled row is a CPU row: no tail is ever sniffed
-    bare = tmp_path / "BENCH_r94.json"
-    bare.write_text(json.dumps({
-        "rc": 0, "tail": "Platform 'tpu' is experimental",
-        "parsed": {"metric": "ed25519_vote_verify_throughput",
-                   "value": 2000.0, "unit": "sigs/s/chip"},
-    }))
-    assert bt.ingest([str(bare)])[0][0]["backend"] == "cpu"
-    assert {s["file"] for s in skipped} == {
-        "BENCH_r91.json", "BENCH_r92.json",
-    }
-
-
-def test_trend_cli_check_over_checked_in_artifacts(tmp_path):
-    """The acceptance gate: --check over the checked-in BENCH_r* +
-    MULTICHIP_r* (all CPU captures) plus two chip-stamped rows exits 0
-    — the honest-CPU rows (ed25519 vote verify ~2.1k sigs/s) must NOT
-    flag against chip rows 30x above them because the backend
-    partition keeps them in separate groups — and exits non-zero when
-    fed a synthetic 20%-regressed row on a MATCHING backend."""
-    bt = _bt()
-    chip_rows = []
-    for rnd, value in ((2, 70000.0), (3, 77000.0)):
-        f = tmp_path / f"BENCH_r{rnd:02d}.json"
-        f.write_text(json.dumps({
-            "metric": "ed25519_vote_verify_throughput",
-            "value": value, "unit": "sigs/s/chip",
-            "meta": {"backend": "tpu", "device_count": 1},
-        }))
-        chip_rows.append(str(f))
-    out = subprocess.run(
-        [sys.executable, "tools/bench_trend.py", "--check", "--json",
-         *chip_rows],
-        capture_output=True, text=True, cwd=REPO, timeout=120,
-    )
-    assert out.returncode == 0, out.stderr
-    doc = json.loads(out.stdout)
-    assert doc["check"]["ok"] is True
-    # both backend groups of the same metric coexist, 33x apart
-    groups = {
-        (g["metric"], g["backend"]): g for g in doc["groups"]
-    }
-    tpu = groups[("ed25519_vote_verify_throughput", "tpu")]
-    cpu = groups[("ed25519_vote_verify_throughput", "cpu")]
-    assert tpu["best"] > 10 * cpu["best"]
-    assert tpu["regression"] <= 0.15 and cpu["regression"] <= 0.15
-
-    # synthetic regression: consensus_pacing wall/height 25% WORSE on
-    # the same (cpu, 1-device) group as the checked-in r08 capture
-    reg_row = tmp_path / "BENCH_r99.json"
-    reg_row.write_text(json.dumps({
-        "metric": "consensus_pacing_wall_per_height",
-        "value": 567.4,  # r08 recorded 453.9 ms/height
-        "unit": "ms/height",
-        "meta": {"backend": "cpu", "device_count": 1},
-    }))
-    out = subprocess.run(
-        [sys.executable, "tools/bench_trend.py", "--check", str(reg_row)],
-        capture_output=True, text=True, cwd=REPO, timeout=120,
-    )
-    assert out.returncode == 1
-    assert "consensus_pacing_wall_per_height" in out.stderr
-    assert "FAIL tier-1 regression" in out.stderr
-
-    # the SAME row on a different backend cannot flag: partition holds
-    mismatched = tmp_path / "BENCH_r98.json"
-    mismatched.write_text(json.dumps({
-        "metric": "consensus_pacing_wall_per_height",
-        "value": 567.4,
-        "unit": "ms/height",
-        "meta": {"backend": "tpu", "device_count": 1},
-    }))
-    out = subprocess.run(
-        [sys.executable, "tools/bench_trend.py", "--check",
-         str(mismatched)],
-        capture_output=True, text=True, cwd=REPO, timeout=120,
-    )
-    assert out.returncode == 0, out.stderr
-
-
-def test_trend_write_renders_tables(tmp_path):
-    """--write produces TREND.md + TREND.json; the table marks the
-    tier-1 families and the skip section lists failure artifacts."""
-    out = subprocess.run(
-        [sys.executable, "tools/bench_trend.py", "--write", "--dir",
-         str(tmp_path), "--no-scan",
-         os.path.join(REPO, "BENCH_r08.json"),
-         os.path.join(REPO, "BENCH_r07.json")],
-        capture_output=True, text=True, cwd=REPO, timeout=120,
-    )
-    assert out.returncode == 0, out.stderr
-    md = (tmp_path / "TREND.md").read_text()
-    assert "consensus_pacing (tier-1)" in md
-    assert "BENCH_r07.json" in md  # the structured failure is a skip
-    doc = json.loads((tmp_path / "TREND.json").read_text())
-    assert doc["schema"] == "tm-tpu/bench-trend/v1"
-    assert doc["skipped"] and doc["groups"]

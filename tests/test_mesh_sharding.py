@@ -195,16 +195,19 @@ def test_per_mesh_shape_count_within_budget(regs_and_verifiers):
 
 def test_scheduler_round_dispatches_sharded(regs_and_verifiers):
     """Coalesced submissions from two classes ride ONE sharded round:
-    the dispatch log and device_round telemetry carry sharded/devices,
+    the ledger's entry and device_round telemetry carry sharded/devices,
     and the verify_mesh_devices gauge reflects the mesh."""
     import asyncio
 
     from tendermint_tpu.libs.metrics import Registry, SchedulerMetrics
+    from tendermint_tpu.obs.ledger import DispatchLedger
     from tendermint_tpu.parallel.scheduler import VerifyScheduler
 
     _, _, reg_mesh, v_mesh = regs_and_verifiers
     metrics = SchedulerMetrics(Registry("mesh_test"))
-    s = VerifyScheduler(v_mesh, max_batch=16384, metrics=metrics)
+    s = VerifyScheduler(
+        v_mesh, max_batch=16384, metrics=metrics, ledger=DispatchLedger()
+    )
     items_a = _items(96)
     items_b = _items(32, corrupt=(3,))
 
@@ -225,8 +228,8 @@ def test_scheduler_round_dispatches_sharded(regs_and_verifiers):
     assert np.asarray(a).all()
     assert np.asarray(b).tolist() == [i != 3 for i in range(32)]
     assert metrics.mesh_devices.value() == N_DEV
-    sharded = [d for d in s.dispatch_log if d.get("sharded")]
-    assert sharded, f"no sharded round in {list(s.dispatch_log)}"
+    sharded = [d for d in s.ledger.entries() if d["sharded"]]
+    assert sharded, f"no sharded round in {s.ledger.entries()}"
     assert sharded[-1]["devices"] == N_DEV
     assert metrics.dispatch_sharded.value() >= 1
 

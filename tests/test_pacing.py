@@ -408,11 +408,15 @@ def test_four_validator_net_tightens_commit_wait():
     """In-proc 4-validator net with adaptive pacing: the chain commits,
     the commit controller collects straggler samples through BOTH feed
     paths (same-height post-quorum and the LastCommit branch), and the
-    effective commit wait drops below the static floor once learned."""
+    effective commit wait drops below the static ceiling once learned.
+    The ceiling is the test's own: a second, an order of magnitude over
+    what the fourth precommit of an in-process net trails the third by
+    (60-70 ms on an idle box, all of it compute, so it grows with
+    whatever else the box is running)."""
     from tests.helpers import make_genesis, make_validators
     from tests.test_consensus import make_node, wire_net
 
-    cfg = _adaptive_cfg()
+    cfg = _adaptive_cfg(timeout_commit=1.0)
     tracer = obs.Tracer(enabled=True, ring_size=16384)
 
     async def run():
@@ -445,11 +449,11 @@ def test_four_validator_net_tightens_commit_wait():
         commit = snap["steps"]["commit"]
         # both straggler feed paths ran: ~1 sample/height
         assert commit["samples"] >= 4, snap
-        # the learned tail sits below the static floor (this box's
-        # straggler lag is tens of ms; static is 100 ms) and the
-        # effective wait left the ceiling
-        assert commit["learned_s"] < 0.1, snap
-        assert commit["effective_s"] < 0.1, snap
+        # the learned tail sits below the configured ceiling and the
+        # effective wait left it
+        assert commit["static_s"] == cfg.timeout_commit
+        assert commit["learned_s"] < cfg.timeout_commit, snap
+        assert commit["effective_s"] < cfg.timeout_commit, snap
         assert snap["steps"]["prevote"]["samples"] >= 8, snap
     # node 0's tracer carries the per-height decision events
     decisions = [
@@ -463,8 +467,9 @@ def test_four_validator_net_tightens_commit_wait():
     summary = pacing_decisions(
         [r.to_json() for r in tracer.records()]
     )
-    assert summary["commit"]["static_ms"] == pytest.approx(100.0)
-    assert summary["commit"]["learned_ms_last"] < 100.0
+    static_ms = cfg.timeout_commit * 1e3
+    assert summary["commit"]["static_ms"] == pytest.approx(static_ms)
+    assert summary["commit"]["learned_ms_last"] < static_ms
 
 
 def test_late_straggler_feeds_commit_sketch():
@@ -556,8 +561,11 @@ def test_chaos_straggler_forces_backoff_without_stall(tmp_path):
     K=10 chaos heights the victim's controllers must LEARN the injected
     tail (heavy's votes arrive ~50 ms behind the first vote at the
     victim, every height), consensus must keep committing on all nodes,
-    and no height may take longer than the static config would allow
-    (round 0 + one full retry round + the commit wait)."""
+    and no height may take longer than the static config would allow:
+    round 0 or one full retry round, read from the round of the commit
+    each node stored (a height's span of records on one node's ring
+    also holds whatever a lagging peer sent late, so it measures that
+    peer and the box, not the schedule)."""
     from tendermint_tpu.chaos.link import LinkPolicy
     from tendermint_tpu.chaos.network import ChaosNetwork
 
@@ -608,25 +616,30 @@ def test_chaos_straggler_forces_backoff_without_stall(tmp_path):
             h_clear = max(
                 h.cs.state.last_block_height for h in handles
             )
-            t0 = time.perf_counter()
             await asyncio.gather(
                 *(
                     h.cs.wait_for_height(h_clear + K, timeout=180)
                     for h in handles
                 )
             )
-            chaos_wall = time.perf_counter() - t0
             post = handles[victim_idx].cs.pacing.snapshot()
             dump = node_dump(handles[victim_idx])
             hashes = {
                 h.block_store.load_block(h_clear + K).hash()
                 for h in handles
             }
-            return pre, post, dump, hashes, chaos_wall, h_clear
+            rounds = {
+                ht: {
+                    h.block_store.load_seen_commit(ht).round
+                    for h in handles
+                }
+                for ht in range(h_clear + 1, h_clear + K + 1)
+            }
+            return pre, post, dump, hashes, rounds
         finally:
             await stop_mesh(handles)
 
-    pre, post, dump, hashes, chaos_wall, h_clear = asyncio.run(run())
+    pre, post, dump, hashes, rounds = asyncio.run(run())
 
     # liveness + agreement through the degraded regime
     assert len(hashes) == 1, "nodes disagree under the straggler link"
@@ -642,22 +655,9 @@ def test_chaos_straggler_forces_backoff_without_stall(tmp_path):
     # the static ceiling
     assert 0.05 <= post["steps"]["prevote"]["effective_s"] <= 0.2, post
 
-    # never slower than the static config would allow: per-height wall
-    # bounded by one full round-0 schedule + one retry round + the
-    # commit wait + a generous compute allowance for this host
-    att = obs.wall_attribution(dump["records"])
-    walls = [
-        v["wall_ms"]
-        for h, v in att["heights"].items()
-        if h > h_clear + 1  # first post-clear height straddles the clear
-    ]
-    assert walls, att
-    static_allowance_ms = (
-        (cfg.propose(0) + cfg.prevote(0) + cfg.precommit(0))
-        + (cfg.propose(1) + cfg.prevote(1) + cfg.precommit(1))
-        + cfg.timeout_commit
-    ) * 1e3 + 1500.0
-    assert max(walls) <= static_allowance_ms, (max(walls), walls)
+    # no stall: every node stored every chaos height's commit, and
+    # none was decided later than the one retry round
+    assert max(max(rs) for rs in rounds.values()) <= 1, rounds
 
     # report smoke on the real dump: the attribution + decision tables
     # render from exactly this artifact

@@ -717,44 +717,6 @@ def test_window_falls_back_when_qc_verdicts_fail(monkeypatch):
     assert consumer.qc_verified_blocks == 0  # every window re-judged
 
 
-def test_bench_trend_ingests_qc_catchup():
-    """Satellite: the qc_catchup family gates like every other plane —
-    headline blocksync_commits_per_s@100, direction higher, tier-1."""
-    import importlib
-    import sys as _sys
-
-    _sys.path.insert(
-        0, os.path.join(os.path.dirname(__file__), "..", "tools")
-    )
-    bt = importlib.import_module("bench_trend")
-    assert bt.family_of("blocksync_commits_per_s@100") == "qc_catchup"
-    assert bt.family_of("qc_verify_wall_per_block_n100") == "qc_catchup"
-    assert bt.family_of("qc_proof_compression_n32") == "qc_catchup"
-    # plain blocksync metrics keep their family
-    assert bt.family_of("blocksync_replay_throughput") == "blocksync"
-    assert "qc_catchup" in bt.TIER1_FAMILIES
-    assert bt.direction_of("blocksync_commits_per_s@100", "commits/s") == (
-        "higher"
-    )
-    assert bt.direction_of(
-        "qc_verify_wall_per_block_n100", "ms/block"
-    ) == "lower"
-    # a synthetic regressed headline fails the gate
-    rows = [
-        {
-            "file": f"BENCH_r{r}.json", "round": r,
-            "metric": "blocksync_commits_per_s@100", "value": v,
-            "unit": "commits/s", "family": "qc_catchup",
-            "direction": "higher", "backend": "cpu", "devices": 1,
-            "headline": True,
-        }
-        for r, v in ((1, 775.0), (2, 300.0))
-    ]
-    groups = bt.build_groups(rows)
-    failures, _warnings = bt.check_gate(groups, threshold=0.15)
-    assert failures, "regressed qc headline did not gate"
-
-
 def test_rpc_light_block_qc_param(qc_commit, committee):
     """The light_block route's proof=qc negotiation (handler-level):
     compressed shape drops the commit, carries the qc, and unknown
@@ -857,11 +819,22 @@ def test_l2_rotation_carries_bls_key_into_next_qc_bitset():
     from .test_consensus import make_node, wire_net
 
     vs, pvs, privs = make_qc_validators(4, seed=b"rotate")
+    rot_idx = 2
+    key_backfill = vs.validators[rot_idx].bls_pub_key
     # strip one member's BLS key from genesis: the set starts NOT
-    # qc_capable, so no height can carry a QC until the rotation lands
-    bare = vs.validators[2]
-    key_backfill = bare.bls_pub_key
-    bare.bls_pub_key = b""
+    # qc_capable, so no height can carry a QC until the rotation lands.
+    # That member holds 20 of 50: no 2/3 closes without it, so it signs
+    # every commit and no certificate can be assembled without it,
+    # whichever precommit reaches the next proposer last
+    vs = ValidatorSet(
+        [
+            Validator(v.pub_key, 20, bls_pub_key=b"")
+            if i == rot_idx
+            else Validator(v.pub_key, 10, bls_pub_key=v.bls_pub_key)
+            for i, v in enumerate(vs.validators)
+        ]
+    )
+    bare = vs.validators[rot_idx]
     genesis = make_genesis(vs)
     rotate_h, last_h = 3, 9
     # the update applied at rotate_h becomes next_validators(rotate_h+1)
@@ -900,7 +873,6 @@ def test_l2_rotation_carries_bls_key_into_next_qc_bitset():
         return nodes[0][1], nodes[0][2]
 
     bs, ss = asyncio.run(run())
-    rot_idx = 2
     # pre-rotation heights can never carry a QC (set not capable)
     for h in range(2, capable_h):
         blk = bs.load_block(h + 1)

@@ -15,6 +15,7 @@ import numpy as np
 from tendermint_tpu.crypto import ed25519 as host
 from tendermint_tpu.crypto.batch_verifier import BatchVerifier, SigItem
 from tendermint_tpu.libs.metrics import Registry, SchedulerMetrics
+from tendermint_tpu.obs.ledger import DispatchLedger
 from tendermint_tpu.parallel.scheduler import (
     VerifyScheduler,
     default_dispatch,
@@ -44,6 +45,7 @@ class StubVerifier:
 
 
 def _sched(stub=None, **kw) -> VerifyScheduler:
+    kw.setdefault("ledger", DispatchLedger())
     return VerifyScheduler(
         verifier=stub or StubVerifier(),
         metrics=SchedulerMetrics(Registry("test")),
@@ -79,7 +81,7 @@ def test_cross_subsystem_coalescing():
     assert first.tolist() == [True]
     sizes = sorted(len(batch) for batch in stub.batches)
     assert sizes == [1, 4], f"expected one coalesced round, got {sizes}"
-    coalesced = [d for d in s.dispatch_log if d["subs"] >= 2]
+    coalesced = [d for d in s.ledger.entries() if d["submissions"] >= 2]
     assert coalesced and set(coalesced[0]["classes"]) == {
         "consensus", "blocksync", "light",
     }
@@ -232,7 +234,7 @@ def test_fn_lane_serializes_with_priority():
     assert sig_out.tolist() == [True]
     assert fn_out == [True]
     assert fn_batches == [[("pk", "msg", "sig")]]
-    assert any(d.get("fn") for d in s.dispatch_log)
+    assert any(d["engine"] == "fn" for d in s.ledger.entries())
 
 
 def test_failed_partial_submission_drops_remainder():
@@ -424,8 +426,8 @@ def test_default_dispatch_plumbing():
 
 def test_vote_batcher_routes_via_scheduler():
     """VoteBatcher bound to the shared verifier rides the installed
-    scheduler; its batches appear in the scheduler's dispatch log under
-    the consensus class."""
+    scheduler; its batches appear in the scheduler's ledger under the
+    consensus class."""
     from tendermint_tpu.consensus.vote_batcher import VoteBatcher
 
     stub = StubVerifier()
@@ -451,7 +453,7 @@ def test_vote_batcher_routes_via_scheduler():
         assert all(outs)
         assert sum(len(b) for b in stub.batches) == 6
         assert all(
-            d["classes"] == ["consensus"] for d in s.dispatch_log
+            d["classes"] == ["consensus"] for d in s.ledger.entries()
         )
     finally:
         set_default_scheduler(None)

@@ -604,6 +604,7 @@ def test_verify_batcher_coalesces_burst_into_one_round():
     """Tentpole: a burst of follower-side ECDSA checks coalesces into
     fn-lane scheduler rounds under the `sequencer` class instead of one
     on-loop recover per block."""
+    from tendermint_tpu.obs.ledger import DispatchLedger
     from tendermint_tpu.parallel.scheduler import (
         CLASS_ORDER,
         VerifyScheduler,
@@ -621,7 +622,7 @@ def test_verify_batcher_coalesces_burst_into_one_round():
     forged.signature = bytes([chain[0].signature[0] ^ 1]) + chain[0].signature[1:]
 
     async def run():
-        sched = VerifyScheduler()
+        sched = VerifyScheduler(ledger=DispatchLedger())
         await sched.start()
         set_default_scheduler(sched)
         try:
@@ -631,8 +632,8 @@ def test_verify_batcher_coalesces_burst_into_one_round():
             verdicts = await batcher.submit_items(chain + [forged])
             batcher.stop()
             rounds = [
-                d for d in sched.dispatch_log
-                if d.get("fn") and d["classes"] == ["sequencer"]
+                d for d in sched.ledger.entries()
+                if d["engine"] != "sig" and d["classes"] == ["sequencer"]
             ]
             return verdicts, rounds
         finally:
@@ -645,7 +646,7 @@ def test_verify_batcher_coalesces_burst_into_one_round():
     # 17 checks -> a handful of coalesced fn rounds (first may dispatch
     # alone while the rest accumulate), every one under `sequencer`
     assert rounds and len(rounds) <= 3
-    assert sum(d["n"] for d in rounds) == 17
+    assert sum(d["requested"] for d in rounds) == 17
 
 
 @pytest.mark.chaos

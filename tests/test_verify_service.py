@@ -10,9 +10,9 @@ with a structured event — never a hang, never a dropped verdict),
 reconnect-with-backoff, the service's stats/dump surface, node assembly
 under `[scheduler] remote_socket`, the ipc_round_trip health detector,
 the chaos kill/restart liveness property, and the satellite tooling
-(testnet generator flag, device-report tenant table, bench-trend
-ingestion). One test crosses a REAL process boundary via the
-`python -m tendermint_tpu verify-service` entrypoint.
+(testnet generator flag, device-report tenant table). One test crosses
+a REAL process boundary via the `python -m tendermint_tpu
+verify-service` entrypoint.
 """
 
 from __future__ import annotations
@@ -1360,66 +1360,6 @@ def test_device_report_renders_tenant_table():
     assert "client-1" in text and "client-2" in text
     # biggest tenant first
     assert text.index("client-1") < text.index("client-2")
-
-
-def test_bench_trend_ingests_verify_service_family(tmp_path):
-    from tools.bench_trend import (
-        TIER1_FAMILIES,
-        build_groups,
-        check_gate,
-        direction_of,
-        family_of,
-        ingest,
-    )
-
-    assert family_of("verify_service_wall_per_height_n32") == (
-        "verify_service"
-    )
-    assert "verify_service" in TIER1_FAMILIES
-    assert (
-        direction_of("verify_service_wall_per_height_n32", "ms/height")
-        == "lower"
-    )
-    assert (
-        direction_of(
-            "verify_service_requests_per_dispatch_n32", "submissions"
-        )
-        == "higher"
-    )
-
-    def artifact(round_, wall):
-        return {
-            "metric": "verify_service_wall_per_height_n32",
-            "value": wall,
-            "unit": "ms/height",
-            "meta": {"backend": "cpu", "device_count": 1},
-            "extra_metrics": [
-                {
-                    "metric": "verify_service_requests_per_dispatch_n32",
-                    "value": 3.0,
-                    "unit": "submissions per round",
-                }
-            ],
-        }
-
-    p1 = tmp_path / "BENCH_r90.json"
-    p2 = tmp_path / "BENCH_r91.json"
-    p1.write_text(json.dumps(artifact(90, 1000.0)))
-    p2.write_text(json.dumps(artifact(91, 1300.0)))  # 30% worse
-    rows, skipped, _ = ingest([str(p1), str(p2)])
-    assert not skipped
-    groups = build_groups(rows)
-    head = next(
-        g
-        for g in groups
-        if g["metric"] == "verify_service_wall_per_height_n32"
-    )
-    assert head["family"] == "verify_service" and head["headline"]
-    failures, _ = check_gate(groups, threshold=0.15)
-    assert any(
-        f["metric"] == "verify_service_wall_per_height_n32"
-        for f in failures
-    )
 
 
 # --- the multi-process harness itself ----------------------------------------

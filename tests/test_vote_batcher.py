@@ -54,8 +54,7 @@ def test_latency_adapts_with_concurrency():
     concurrency 1 a vote rides a batch of 1; at concurrency 256 batches
     grow to the verifier's appetite and the p99 per-vote latency stays
     FAR below the serial-drain model (256 sequential verifier calls).
-    bench.py records the real-device p50/p99 numbers; this pins the
-    mechanism with a deterministic stub."""
+    This pins the mechanism with a deterministic stub."""
     import time
 
     stub = SlowStubVerifier(delay=0.02)

@@ -10,8 +10,7 @@ Reads any surface the device-cost ledger (obs/ledger.py) lands on:
   `per_client` tenant table — the multi-tenant device bill with real
   tenants, rendered as per-client submission/row counts next to the
   per-class cost shares,
-- a bench artifact carrying a `device_cost` block (every family stamps
-  one since PR 12),
+- a document carrying the summary as a `device_cost` block,
 - a bare `device_cost`/summary dict,
 
 and renders the questions the ledger exists to answer: which submitter
@@ -24,7 +23,7 @@ or the ladder is mispriced.
 
 Usage:
     curl -s localhost:26657/dump_dispatch_ledger | python tools/device_report.py -
-    python tools/device_report.py BENCH_r12.json [more.json ...] [--json]
+    python tools/device_report.py dump.json [more.json ...] [--json]
 """
 
 from __future__ import annotations
@@ -64,7 +63,7 @@ def extract_summary(doc: dict) -> dict:
         return doc  # already a bare summary
     raise ValueError(
         "no device-cost block found (expected a dump_dispatch_ledger "
-        "response, a bench artifact with 'device_cost', or a bare "
+        "response, a document with 'device_cost', or a bare "
         "summary)"
     )
 
@@ -204,11 +203,11 @@ def report_text(summary: dict, name: str = "") -> str:
 def main() -> int:
     ap = argparse.ArgumentParser(
         description="per-class device-cost tables + amortization curve "
-        "from dump_dispatch_ledger dumps or bench artifacts"
+        "from dump_dispatch_ledger dumps"
     )
     ap.add_argument(
         "paths", nargs="+",
-        help="dump/bench JSON files ('-' = stdin)",
+        help="dump JSON files ('-' = stdin)",
     )
     ap.add_argument(
         "--json", action="store_true", dest="as_json",

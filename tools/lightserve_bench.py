@@ -21,8 +21,8 @@ provider — no sockets) at one node's `tendermint_tpu/lightserve` plane:
 
 The result records clients/s, cache hit-rate, verify dedup rate, and
 the shape-registry delta (distinct_program_shapes /
-device_dispatch_count) across the swarm sync — the sublinearity proof
-the BENCH artifact carries (`bench.py --family lightserve`).
+device_dispatch_count) across the swarm sync: the swarm's cost is
+sublinear in clients when both stay flat as clients grow.
 
   python tools/lightserve_bench.py --clients 1000 --heights 8
 """
@@ -294,8 +294,8 @@ def run_swarm(
                 "cache": plane.cache.stats(),
                 "verify": plane.verifier.stats(),
                 "registry_delta": delta,
-                # the metrics counters, NOT dispatch_log (a deque capped
-                # at 1024 — a big swarm would silently under-report)
+                # counters, not a ring of recent rounds: a big swarm
+                # outruns any bounded ring
                 "scheduler_rounds": int(
                     scheduler.metrics.dispatches.value()
                 ),

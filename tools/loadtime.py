@@ -23,7 +23,6 @@ Beyond the original burst tool, this grows two sustained-load pieces
   both planes (BFT gossip pre-upgrade, BlockV2 streaming post-upgrade),
   event-driven apply latency, encode-once fan-out, a chaos-shaped slow
   subscriber, and partition/heal catchup over the 0x51 sync channel.
-  `bench.py --family sequencer_stream` drives it.
 
 Usage:
     python tools/loadtime.py run     # in-proc node, burst load, report
@@ -196,8 +195,7 @@ class SustainedLoadGenerator:
 
 def _pct(xs, q):
     """Shared percentile rule (obs.report.pct): the sequencer_stream
-    rows must use the same index semantics as every other bench
-    family's latency scalars."""
+    rows use the same index semantics as every other report."""
     from tendermint_tpu.obs.report import pct
 
     return pct(list(xs), q)
@@ -553,8 +551,8 @@ def run_sequencer_stream(
     chaos_latency_s: float = 0.25,
     timeout: float = 240.0,
 ) -> dict:
-    """Entry point for bench.py --family sequencer_stream and the
-    `stream` CLI below. Returns the stats dict of _stream_net."""
+    """Entry point for the `stream` CLI below. Returns the stats dict
+    of _stream_net."""
     os.environ.setdefault("TM_TPU_SKIP_WARM", "1")
     return asyncio.run(
         _stream_net(

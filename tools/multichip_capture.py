@@ -22,7 +22,7 @@ The artifact line:
 
 The capture needs the chip: unless JAX_PLATFORMS=cpu is set explicitly,
 a resolved platform other than `tpu` ends the run non-zero
-(libs/device.require_chip) — same rule as bench.py and chip_smoke.py.
+(libs/device.require_chip) — same rule as chip_smoke.py.
 A stage that raises ends the run with its traceback and a non-zero
 exit; there is no fallback row.
 

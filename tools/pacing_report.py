@@ -143,7 +143,7 @@ def main() -> int:
         choices=sorted(FAMILY_WALL_SPANS),
         default="consensus",
         help="wall-attribution span classification: 'consensus' (cs.* "
-        "step spans; also the committee_scale bench family) or "
+        "step spans) or "
         "'sequencer' (seq.* spans of the BlockV2 streaming plane, "
         "heights are V2 heights)",
     )

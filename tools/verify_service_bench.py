@@ -1,5 +1,4 @@
-"""Multi-process verify-service bench harness (bench.py --family
-verify_service).
+"""Multi-process verify-service bench harness.
 
 The missing measurement behind ROADMAP's verify-as-a-service item:
 PR 9's committee-scale live nets stub signature verification above 32
@@ -20,8 +19,7 @@ it on the production topology instead of a bigger event loop:
   group on the wire fn lane (`bls_agg`: real BLS12-381 keys, one
   random-linear-combination aggregate per group). A node's height
   completes when BOTH verdict sets return all-true — the verify
-  critical path of a consensus round, without the gossip plane the
-  committee_scale family already prices.
+  critical path of a consensus round, without the gossip plane.
 
 Per size the harness records wall-per-height, the service-side
 DispatchLedger summary (requests-per-dispatch proves CROSS-PROCESS
@@ -501,8 +499,8 @@ def run_family(
     max_procs: int = 8,
     service_max_batch: int = DEFAULT_SERVICE_MAX_BATCH,
 ) -> dict:
-    """The bench.py --family verify_service payload: one row per
-    committee size, headline wall-per-height at 32 validators."""
+    """One row per committee size, headline wall-per-height at 32
+    validators."""
     rows = []
     for n in sizes:
         try:
@@ -533,8 +531,6 @@ def run_family(
     head = next(
         (r for r in ok if r["n"] == 32), ok[-1] if ok else None
     )
-    # per-size extra_metrics rows are assembled by bench.py (the
-    # artifact owner); this payload carries the raw rows
     head_n = head["n"] if head else 0
     return {
         "metric": f"verify_service_wall_per_height_n{head_n}",
