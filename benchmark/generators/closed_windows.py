@@ -11,7 +11,11 @@ plants them. The pool of signed commits is made during set-up, sized
 window ends there, and the rate is still all work over all time.
 
 The timed window starts at a window's submission and ends at the
-completion of the first window that finishes after `seconds`.
+completion of the first window that finishes after `seconds`, or of
+the pool's last. The traced span is the last `trace_seconds` of the
+timed window, wherever that ends (`trace_due`): it opens at the first
+request boundary `trace_seconds` before `seconds`, or, where the pool
+will be dry sooner, `trace_seconds` before that by the pace so far.
 
 Parameters (traffic file, a cell's own file over it): window_commits,
 class, warm_windows, pool_commits_per_s, trace_seconds.
@@ -53,20 +57,50 @@ def window_closed(elapsed: float, seconds: float) -> bool:
     return elapsed >= seconds
 
 
+def trace_due(elapsed: float, done: int, pool: int, seconds: float,
+              trace_seconds: float) -> bool:
+    """Whether the traced span opens before the next request: the clock
+    is `trace_seconds` short of `seconds`; or the windows left, at the
+    mean pace of those done, take `trace_seconds` or less; or only the
+    pool's last window is left, so that a traced run holds one."""
+    left = pool - done
+    return (
+        elapsed >= seconds - trace_seconds
+        or left <= 1
+        or (done >= 1 and left * elapsed / done <= trace_seconds)
+    )
+
+
 class _Spy:
     """The classed verifier, with the benchmark's span around its
     `verify`: what the client spends around it is its own gather and
-    tally, and the row bitmap it returns is what `correct` compares."""
+    tally, and the row bitmap it returns is what `correct` compares.
+    Every call of a window is kept: the bitmaps joined in call order,
+    the seconds summed. A client that submits a window's chunks out of
+    row order fails `rows_wrong`."""
 
     def __init__(self, inner):
         self.inner = inner
-        self.last = None
+        self.calls: list = []
 
     def verify(self, items):
         t0 = time.perf_counter()
         ok = self.inner.verify(items)
-        self.last = (time.perf_counter() - t0, np.asarray(ok, dtype=bool))
+        self.calls.append(
+            (time.perf_counter() - t0, np.asarray(ok, dtype=bool))
+        )
         return ok
+
+    def take(self) -> tuple:
+        """(seconds inside `verify`, the window's bitmap or None), and
+        the next window starts empty."""
+        calls, self.calls = self.calls, []
+        if not calls:
+            return 0.0, None
+        return (
+            sum(s for s, _ in calls),
+            np.concatenate([bits for _, bits in calls]),
+        )
 
 
 class Session:
@@ -87,7 +121,6 @@ class Session:
     async def request(self, entries: list) -> dict:
         """One window through the program; never raises."""
         loop = asyncio.get_running_loop()
-        self.spy.last = None
         t0 = time.perf_counter()
         try:
             verdicts = await loop.run_in_executor(
@@ -100,20 +133,21 @@ class Session:
         except Exception as e:  # a degrade raises out of the tripwire
             verdicts, error = None, repr(e)
         t1 = time.perf_counter()
-        inner_s, bits = self.spy.last or (0.0, None)
+        inner_s, bits = self.spy.take()
         return {
             "t_due": t0, "t_sent": t0, "t_done": t1, "error": error,
             "inner_s": inner_s, "verdicts": verdicts, "bits": bits,
         }
 
     async def drive(self, requests: list, seconds: float, tracer) -> dict:
-        trace_from = seconds - float(self.traffic["trace_seconds"])
+        trace_seconds = float(self.traffic["trace_seconds"])
         tracing = False
         done = []
         t_start = time.perf_counter()
         for entries in requests:
-            if tracer and not tracing and (
-                time.perf_counter() - t_start >= trace_from
+            if tracer and not tracing and trace_due(
+                time.perf_counter() - t_start, len(done), len(requests),
+                seconds, trace_seconds,
             ):
                 await tracer.start()
                 tracing = True

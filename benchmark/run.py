@@ -104,11 +104,55 @@ def run_cell(args, service_command=procs.service_command) -> dict:
         running.stop_all()
 
 
+def trace_marks(report: dict) -> dict:
+    return {m["word"]: m for m in report["trace_marks"]}
+
+
+def window_of(report: dict) -> dict:
+    """What the run was, for the line: the timed window as driven, and
+    where in it the traced span lay."""
+    w = report["window"]
+    seconds = w["t_end"] - w["t_start"]
+    start = trace_marks(report).get("start")
+    return {
+        "seconds": seconds,
+        "requests": len(report["requests"]),
+        "pool_requests": report["pool_requests"],
+        # an open loop sends its whole schedule by design; a closed one
+        # whose pool ends before `seconds` was cut short by its pool
+        "pool_ran_dry": report["loop"] == "closed"
+        and len(report["requests"]) == report["pool_requests"]
+        and seconds < w["seconds"],
+        "trace_from_s": start and start["client_pc"] - w["t_start"],
+        "traced_requests": sum(r["traced"] for r in report["requests"]),
+    }
+
+
+def require_traced_span(report: dict, w: dict, trace_seconds: float) -> None:
+    """A traced run in which tracing never began has nothing to say
+    per layer: it ends here, not as a line that lacks the trace. `w`
+    is the report's `window_of`."""
+    missing = [
+        f"no {word} mark" for word in ("start", "stop")
+        if word not in trace_marks(report)
+    ] + ["no traced request"] * (w["traced_requests"] == 0)
+    if missing:
+        raise SystemExit(
+            f"--trace 1, and the report holds {', '.join(missing)}: the "
+            f"timed window ended after {w['requests']} requests of "
+            f"pool_requests {w['pool_requests']} at window.seconds "
+            f"{w['seconds']:.3f} of {report['window']['seconds']}, before "
+            f"the generator opened its traced span of trace_seconds "
+            f"{trace_seconds}"
+        )
+
+
 def traced(report: dict, work: str) -> dict | None:
-    """The traced span reduced, with its length on the service's clock."""
-    marks = {m["word"]: m for m in report["trace_marks"]}
+    """The traced span reduced, with its length on the service's clock;
+    None where the trace holds no device plane (a CPU rehearsal)."""
+    marks = trace_marks(report)
     path = trace_mod.newest_xplane(os.path.join(ROOT, work, "trace"))
-    if not path or "start" not in marks or "stop" not in marks:
+    if not path:
         return None
     out = trace_mod.reduce(trace_mod.read_planes(path))
     if out is None:
@@ -195,10 +239,13 @@ def main(argv=None) -> int:
     if args.sweep:
         print(json.dumps(report["sweep"], indent=1))
         return 0
-    trace = (
-        traced(report, os.path.join(WORK, args.workload))
-        if args.trace else None
-    )
+    window = window_of(report)
+    trace = None
+    if args.trace:
+        require_traced_span(
+            report, window, args.cell.traffic["trace_seconds"]
+        )
+        trace = traced(report, os.path.join(WORK, args.workload))
     ctx = context(report, trace)
     wanted = args.cell.per_layer if args.trace else args.cell.end_to_end
     metrics = {}
@@ -225,14 +272,19 @@ def main(argv=None) -> int:
         device["busy_s"] = trace["busy_s"]
         device["window_s"] = trace["window_s"]
         line["breakdown"] = breakdown(trace, ctx["spans"])
-    line["window"] = {
-        "seconds": report["window"]["t_end"] - report["window"]["t_start"],
-        "requests": len(report["requests"]),
-        "pool_requests": report["pool_requests"],
-        "compile": {k: report["compile"][k] for k in
-                    ("compilations", "seconds", "cache_hits", "cache_misses")},
-        "setup": report["setup"],
-    }
+    line["window"] = dict(
+        window,
+        compile={k: report["compile"][k] for k in
+                 ("compilations", "seconds", "cache_hits", "cache_misses")},
+        setup=report["setup"],
+    )
+    if window["pool_ran_dry"]:
+        print(
+            "pool ran dry: the timed window ended at "
+            f"{window['seconds']:.3f} s of {args.seconds} with all "
+            f"{report['pool_requests']} pool requests served",
+            file=sys.stderr,
+        )
     line["checks"] = {k: {"value": v, "limit": lim} for k, v, lim in checks}
     for k, v, lim in checks:
         print(f"check {k} {v} limit {lim}", file=sys.stderr)

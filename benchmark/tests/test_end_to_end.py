@@ -1,5 +1,6 @@
 """One tiny cell through run.py on the CPU: the result line's keys, a
-run with no chip, and a run with the timed path broken underneath."""
+run with no chip, a traced run whose pool runs dry, one in which
+tracing never began, and a run with the timed path broken underneath."""
 
 import argparse
 import json
@@ -19,9 +20,9 @@ RUN = [
 ]
 
 
-def run(workload, trace, env):
+def run(workload, trace, env, more=()):
     return subprocess.run(
-        RUN + ["--workload", workload, "--trace", str(trace)],
+        RUN + ["--workload", workload, "--trace", str(trace), *more],
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=600,
     )
 
@@ -62,6 +63,63 @@ def test_result_line_has_the_contracts_keys(workload, trace, metric):
     # a share of a roofline or of the device is left out, never 0, where
     # there was no device trace to read
     assert not any("roofline" in k or "idle" in k for k in line["metrics"])
+
+
+def test_a_pool_that_runs_dry_is_still_traced():
+    """Five windows against 5 s: the pool is dry within a second, and
+    the traced span lies before the pool's end, not before `seconds`."""
+    cell = "rehearsal.catchup-dry"
+    done = run(cell, 1, cpu_env(), ["--seconds", "5"])
+    assert done.returncode == 0, done.stderr[-2000:]
+    line = json.loads(done.stdout.strip().splitlines()[-1])
+    window = line["window"]
+    assert window["pool_ran_dry"] is True
+    assert window["requests"] == window["pool_requests"] == 5
+    assert window["traced_requests"] >= 1
+    assert 0 < window["trace_from_s"] < window["seconds"] < 5
+    assert line["correct"] is True and line["failed"] == 0
+    stderr = done.stderr.strip().splitlines()
+    assert any(s.startswith("pool ran dry") for s in stderr)
+    assert stderr[-1] == "correct True"
+    with open(os.path.join(ROOT, ".bench_work", cell, "report.json")) as f:
+        report = json.load(f)
+    assert [m["word"] for m in report["trace_marks"]] == ["start", "stop"]
+    assert report["requests"][-1]["traced"]
+
+
+@pytest.mark.parametrize(
+    "marks,traced,said",
+    [
+        ([], False, "no start mark, no stop mark, no traced request"),
+        (["start"], True, "no stop mark"),
+        (["start", "stop"], False, "no traced request"),
+    ],
+)
+def test_a_traced_run_without_a_traced_span_prints_no_line(
+    monkeypatch, capsys, marks, traced, said
+):
+    sys.path.insert(0, BENCH_DIR)
+    import run as bench_run
+
+    report = {
+        "loop": "closed",
+        "window": {"t_start": 100.0, "t_end": 125.03, "seconds": 30.0},
+        "requests": [{"traced": traced}] * 188,
+        "pool_requests": 188,
+        "trace_marks": [{"word": w, "client_pc": 120.0} for w in marks],
+    }
+    monkeypatch.setattr(bench_run, "run_cell", lambda args: report)
+    with pytest.raises(SystemExit) as e:
+        bench_run.main([
+            "--workload", "rehearsal.catchup", "--seed", "1", "--seconds",
+            "30", "--trace", "1", "--benchmark-file", REHEARSAL,
+        ])
+    sentence = str(e.value.code)
+    assert said + ":" in sentence
+    for part in ("188 requests", "pool_requests 188",
+                 "window.seconds 25.030 of 30.0", "trace_seconds 1"):
+        assert part in sentence
+    assert capsys.readouterr().out == ""
 
 
 def test_no_chip_no_result():
