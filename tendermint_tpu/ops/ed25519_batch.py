@@ -23,6 +23,14 @@ host-computed input mask (`s_ok`).
 Verification equation (cofactorless, matching Go x/crypto semantics):
     [s]B == R + [k]A   ⇔   encode([s]B + [k](-A)) == R_bytes
 
+Layout: the programs' operands and results are row-major (``[B, 32]`` uint8
+encodings and scalars, ``[B]`` verdicts, the key-table stores ``[cap, ..., 4,
+32]`` uint8), which is what the host assembles, the scheduler shards on the
+batch axis and the stores hold. Inside, every field element has the batch on
+the minor-most axis (``ops/field25519.py``): the operands are turned onto the
+lanes where they enter (`fe.from_bytes`) and the encodings turned back where
+they leave (`curve.compress`, `fe.to_bytes`).
+
 The stages of the cached programs carry `jax.named_scope` names that a
 trace viewer shows and a refactor keeps: `base_mult` ([s]B), `key_mult`
 ([k](-A) from the big cache), `double_mult` (the small tier's fused
@@ -63,7 +71,7 @@ def neg_pubkey_table(pubkeys: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     (canonicalization is value-preserving mod p; the group ops accept
     any loose input)."""
     a_point, a_valid = curve.decompress(pubkeys)
-    table = curve.window_table(curve.neg(a_point))
+    table = curve.window_table(curve.neg(a_point))  # [16, 4, 32, N]
     return fe.to_bytes(table), a_valid
 
 
@@ -77,7 +85,10 @@ def verify_prehashed_table(
 ) -> jnp.ndarray:
     """Returns [B] bool accept bitmap (cached-pubkey hot path)."""
     with jax.named_scope("double_mult"):
-        q = curve.double_scalar_mult_base_table(s_bytes, k_bytes, tables)
+        # the gathered rows' bytes go onto the lanes once, still uint8
+        q = curve.double_scalar_mult_base_table(
+            s_bytes, k_bytes, jnp.moveaxis(tables, 0, -1)
+        )
     with jax.named_scope("compress"):
         encoded = curve.compress(q)
     r_match = jnp.all(encoded == r_bytes, axis=-1)
@@ -96,7 +107,7 @@ def neg_pubkey_bigtable(
     doublings.
     """
     a_point, a_valid = curve.decompress(pubkeys)
-    table = curve.big_window_table(curve.neg(a_point))
+    table = curve.big_window_table(curve.neg(a_point))  # [64, 16, 4, 32, N]
     return fe.to_bytes(table), a_valid
 
 

@@ -44,8 +44,6 @@ _SLOW_MODULES = {
     "test_e2e_multiprocess",
     "test_e2e_perturb",
     "test_multichip",
-    "test_ops_curve25519",
-    "test_ops_field25519",
     "test_ops_sha",
     "test_ops_bls_g1",
     "test_ops_bls_g2",
