@@ -4,9 +4,10 @@ commits outstanding.
 Each request is the call blocksync makes for a window,
 `ValidatorSet.verify_commits_light(chain_id, entries, verifier=
 remote.classed(<class>))`, over `window_commits` fresh commits of the
-configuration's validator set; the next window goes out when the
-verdicts of the last came back. Bad rows as `fixtures.plan_window`
-plants them. The pool of signed commits is made during set-up, sized
+configuration's validator set (the committee kind's, at the window's
+first height); the next window goes out when the verdicts of the last
+came back. Bad rows as `fixtures.plan_window` plants them. The pool
+of signed commits is made during set-up, sized
 `pool_commits_per_s` x seconds; if the program runs it dry the timed
 window ends there, and the rate is still all work over all time.
 
@@ -34,20 +35,19 @@ from harness import fixtures
 LOOP = "closed"
 
 
-def plan(traffic: dict, n: int, seed: int, seconds: float,
+def plan(traffic: dict, committee, seed: int, seconds: float,
          first_height: int = 1) -> dict:
     """Units of (height, bad-row plan), one unit per window: the warm-up
     windows, then the pool."""
     w = int(traffic["window_commits"])
     warm = int(traffic["warm_windows"])
     pool = max(2, math.ceil(traffic["pool_commits_per_s"] * seconds / w))
-    units = [
-        [
-            (first_height + k * w + c, p)
-            for c, p in enumerate(fixtures.plan_window(seed, k, w, n))
-        ]
-        for k in range(warm + pool)
-    ]
+    units = []
+    for k in range(warm + pool):
+        heights = [first_height + k * w + c for c in range(w)]
+        units.append(list(zip(
+            heights, fixtures.plan_window(committee, seed, k, heights)
+        )))
     return {"warm": units[:warm], "pool": units[warm:]}
 
 
@@ -107,25 +107,38 @@ class Session:
     def __init__(self, traffic: dict, committee, remote, objects):
         self.traffic = traffic
         self.committee = committee
-        self.vs = objects.validator_set(committee)
         self.objects = objects
+        self.sets: dict = {}
         self.spy = _Spy(remote.classed(traffic["class"]))
 
+    def validator_set(self, height: int):
+        """The program's validator set at a height, built once for
+        every tuple of validators the committee hands out."""
+        validators = self.committee.validators(height)
+        if validators not in self.sets:
+            self.sets[validators] = self.objects.validator_set(validators)
+        return self.sets[validators]
+
     def load(self, units: list) -> list:
-        """Commit records -> what one request submits."""
+        """Commit records -> what one request submits: the validator
+        set of the window's first height, and its entries."""
         return [
-            [self.objects.entry(self.committee, rec) for rec in unit]
+            (
+                self.validator_set(unit[0][0]),
+                [self.objects.entry(self.committee, rec) for rec in unit],
+            )
             for unit in units
         ]
 
-    async def request(self, entries: list) -> dict:
+    async def request(self, window: tuple) -> dict:
         """One window through the program; never raises."""
+        vs, entries = window
         loop = asyncio.get_running_loop()
         t0 = time.perf_counter()
         try:
             verdicts = await loop.run_in_executor(
                 None,
-                lambda: self.vs.verify_commits_light(
+                lambda: vs.verify_commits_light(
                     fixtures.CHAIN_ID, entries, verifier=self.spy
                 ),
             )
@@ -144,14 +157,14 @@ class Session:
         tracing = False
         done = []
         t_start = time.perf_counter()
-        for entries in requests:
+        for window in requests:
             if tracer and not tracing and trace_due(
                 time.perf_counter() - t_start, len(done), len(requests),
                 seconds, trace_seconds,
             ):
                 await tracer.start()
                 tracing = True
-            r = await self.request(entries)
+            r = await self.request(window)
             r["traced"] = tracing
             done.append(r)
             if window_closed(r["t_done"] - t_start, seconds):

@@ -1,6 +1,7 @@
 """Finding a cell's files by the names in BENCHMARK.json.
 
-    configs/<config>.json     the deployment, as run
+    configs/<config>.json     the deployment, as run; `committee` names
+                              committees/<kind>.py (or the default kind)
     traffic/<traffic>.json    the mix's parameters; `generator` names
                               generators/<kind>.py
     cells/<cell>.json         optional: this cell's own values for keys
@@ -9,7 +10,7 @@
                               readers/<reader>.py, the rest are the
                               reader's parameters
 
-Nothing here knows a cell, a mix or a metric by name.
+Nothing here knows a cell, a mix, a metric or a committee kind by name.
 """
 
 from __future__ import annotations
@@ -17,6 +18,8 @@ from __future__ import annotations
 import importlib
 import json
 import os
+
+import committees
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
@@ -61,6 +64,11 @@ class Cell:
         return importlib.import_module(
             "generators." + self.traffic["generator"]
         )
+
+    def committee_kind(self):
+        """The module that says what this configuration's validators
+        are, how they sign and what the reference answers."""
+        return committees.load(self.config)
 
 
 def _lists(metric: dict, cell: str) -> bool:

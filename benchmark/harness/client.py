@@ -25,7 +25,6 @@ sys.path[:0] = [BENCH_DIR, os.path.dirname(BENCH_DIR)]
 from harness import fixtures  # noqa: E402
 from harness.procs import service_get  # noqa: E402
 from harness.stats import percentile  # noqa: E402
-from reference import ed25519_plain  # noqa: E402
 
 
 class NoLocalVerify:
@@ -80,13 +79,14 @@ def workers() -> int:
     return max(1, min(len(os.sched_getaffinity(0)) - 1, 12))
 
 
-def answers(pool, commits: list, s_range: bool) -> dict:
-    """{height: [bool per row]} from the reference (s_range True) or
-    the control (False), on the pool's workers."""
+def answers(pool, commits: list, control: str = "") -> dict:
+    """{height: [bool per row]} from the reference or, with one of the
+    kind's guarantees named, from the control that drops it, on the
+    pool's workers."""
     per = len(commits) // (4 * workers()) + 1
     parts = pool.map(
         fixtures.reference_unit,
-        [(commits[i : i + per], s_range)
+        [(commits[i : i + per], control)
          for i in range(0, len(commits), per)],
         1,
     )
@@ -95,14 +95,15 @@ def answers(pool, commits: list, s_range: bool) -> dict:
     }
 
 
-async def sweep(args, session, gen, traffic, pool, n, first_height) -> list:
+async def sweep(args, session, gen, traffic, pool, first_height) -> list:
     """The one-off search for the highest rate an open-loop cell
     sustains: one set-up, the rates stepped inside this process."""
     rows = []
     for rate in [float(r) for r in args.sweep.split(",")]:
         session.traffic = dict(traffic, rate_per_s=rate)
         units = gen.plan(
-            session.traffic, n, args.seed, args.seconds, first_height
+            session.traffic, session.committee, args.seed, args.seconds,
+            first_height,
         )["pool"]
         first_height = units[-1][-1][0] + 1
         requests = session.load(pool.map(fixtures.build_unit, units, 1))
@@ -143,20 +144,24 @@ async def run(args) -> dict:
     traffic = dict(cell.traffic)
     if args.rate:
         traffic["rate_per_s"] = args.rate
-    n = int(cell.config["validators"])
     gen = cell.generator()
-    units = gen.plan(traffic, n, args.seed, args.seconds)
+    committee = cell.committee_kind().Committee(args.seed, cell.config)
+    units = gen.plan(traffic, committee, args.seed, args.seconds)
 
     # the pool is signed on the host's cores while the service loads
     # its programs for the warm-up requests
     pool = multiprocessing.get_context("spawn").Pool(
         workers(), initializer=fixtures.init_worker,
-        initargs=(args.seed, n),
+        initargs=(args.seed, cell.config),
     )
     try:
         warm_job = pool.map_async(fixtures.build_unit, units["warm"], 1)
-        pool_job = pool.map_async(fixtures.build_unit, units["pool"], 1)
-        committee = fixtures.Committee(args.seed, n)
+        t_pool = time.perf_counter()
+        signed_s: list = []  # pool start -> last unit signed, by callback
+        pool_job = pool.map_async(
+            fixtures.build_unit, units["pool"], 1,
+            callback=lambda _: signed_s.append(time.perf_counter() - t_pool),
+        )
         remote = await attach(args.socket)
         tracer = TraceControl(args.control) if args.control else None
         try:
@@ -171,7 +176,7 @@ async def run(args) -> dict:
             requests = session.load(pool_recs)
             if args.sweep:
                 rows = await sweep(
-                    args, session, gen, traffic, pool, n,
+                    args, session, gen, traffic, pool,
                     units["pool"][-1][-1][0] + 1,
                 )
                 return {"sweep": rows}
@@ -200,26 +205,26 @@ async def run(args) -> dict:
         ]
         t0 = time.perf_counter()
         commits = [(h, sigs) for recs, _, _ in served for h, sigs, _ in recs]
-        reference = answers(pool, commits, True)
-        if args.control_guarantee == "s_range":
-            # the control: the reference without its s < L rule, put in
-            # the program's place and judged like the program
-            control = answers(pool, commits, False)
+        reference = answers(pool, commits)
+        if args.control_guarantee:
+            # the control: the reference without the one guarantee
+            # named, put in the program's place and judged like the
+            # program
+            control = answers(pool, commits, args.control_guarantee)
             served = [
                 (
                     recs,
                     [ok for h, _, _ in recs for ok in control[h]],
                     verdicts and [
-                        ed25519_plain.quorum(control[h], committee.powers)
-                        for h, _, _ in recs
+                        committee.quorum(h, control[h]) for h, _, _ in recs
                     ],
                 )
                 for recs, _, verdicts in served
             ]
-        numbers = correct.judge(served, reference, committee.powers)
-        numbers["rfc8032_vs_openssl"] = correct.sample_rfc8032(
-            committee, served, reference, args.seed
-        )
+        numbers = correct.judge(served, reference, committee)
+        numbers.update(committee.cross_check(
+            correct.sample_rows(served, args.seed), reference
+        ))
         reference_s = time.perf_counter() - t0
     finally:
         pool.close()
@@ -246,13 +251,18 @@ async def run(args) -> dict:
                 "error": r["error"], "inner_s": r["inner_s"],
                 "traced": r["traced"],
                 "rows": 0 if r["bits"] is None else len(r["bits"]),
+                "rows_by_key_type": {} if r["bits"] is None else
+                fixtures.rows_by_key_type(
+                    committee, [h for h, _, _ in recs]
+                ),
                 "commits": len(recs),
             }
             for recs, r in zip(pool_recs, done)
         ],
         "pool_requests": len(requests),
         "numbers": numbers,
-        "setup": {"warm_s": warm_s, "reference_s": reference_s,
+        "setup": {"warm_s": warm_s, "signed_s": signed_s[0],
+                  "reference_s": reference_s,
                   "rows_judged": sum(len(a) for a in reference.values())},
         "ledger0": dump0["summary"], "ledger1": dump1["summary"],
         "compile": comp1,

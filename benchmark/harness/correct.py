@@ -13,9 +13,10 @@ and its limit is 0.
     plan_vs_reference  commits where the rows the generator made bad
                        are not exactly the rows the reference rejects
                        (a fault of the yardstick, not of the program)
-    rfc8032_vs_openssl rows of a seeded sample on which the pure-Python
-                       RFC 8032 verifier and the OpenSSL-backed one
-                       disagree (the same)
+    <the kind's cross-checks>   rows of a seeded sample on which an
+                       independent implementation and the window-wide
+                       reference disagree (the same); the committee
+                       kind names them
     compiles_in_window programs the service compiled or loaded between
                        the two dumps around the window
     degrades, error_frames   the client's and the service's own counts
@@ -26,15 +27,13 @@ from __future__ import annotations
 
 import random
 
-from harness import fixtures
-from reference import ed25519_plain
-
 SAMPLE_ROWS = 24
 
 
-def judge(served: list, reference: dict, powers: list) -> dict:
+def judge(served: list, reference: dict, committee) -> dict:
     """served: [(commit records, bits or None, verdicts or None)], one
-    per request. reference: {height: [bool per row]}."""
+    per request. reference: {height: [bool per row]}. The committee
+    says whether a commit stands on given row verdicts."""
     failed = rows_wrong = commits_wrong = plan_wrong = resubmitted = 0
     seen: set = set()
     for recs, bits, verdicts in served:
@@ -55,7 +54,7 @@ def judge(served: list, reference: dict, powers: list) -> dict:
             )
         if verdicts is not None:
             for (height, _, _), got in zip(recs, verdicts):
-                stands = ed25519_plain.quorum(reference[height], powers)
+                stands = committee.quorum(height, reference[height])
                 commits_wrong += bool(got) != stands
             commits_wrong += abs(len(verdicts) - len(recs))
     return {
@@ -67,27 +66,23 @@ def judge(served: list, reference: dict, powers: list) -> dict:
     }
 
 
-def sample_rfc8032(committee, served: list, reference: dict, seed: int) -> int:
-    """The OpenSSL-backed reference held against the pure-Python one on
-    a seeded sample of served rows, one bad row of each kind among them
-    where the window served one."""
+def sample_rows(served: list, seed: int) -> list:
+    """[(commit record, row)]: a seeded sample of served rows for the
+    kind's cross-check, one bad row of each kind among them where the
+    window served one."""
     rng = random.Random(seed * 1_000_003 + 29)
     recs = [rec for unit, _, _ in served for rec in unit]
     if not recs:
-        return 0
+        return []
     sample = {}
     for rec in rng.sample(recs, len(recs)):
         for i, kind in rec[2].items():
             sample.setdefault(kind, (rec, i))
     rows = list(sample.values())
     while len(rows) < SAMPLE_ROWS:
-        rows.append((rng.choice(recs), rng.randrange(committee.n)))
-    wrong = 0
-    for (height, sigs, _), i in rows:
-        msg = fixtures.messages(committee.seed, height, committee.n)[i]
-        plain = ed25519_plain.verify_rfc8032(committee.pubs[i], msg, sigs[i])
-        wrong += plain != reference[height][i]
-    return wrong
+        rec = rng.choice(recs)
+        rows.append((rec, rng.randrange(len(rec[1]))))
+    return rows
 
 
 def verdict(numbers: dict) -> tuple:

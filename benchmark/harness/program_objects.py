@@ -1,57 +1,60 @@
 """Commit records turned into the program's own types: the only place
 the load generators touch `tendermint_tpu.types`. What the program then
 does with them (shape checks, sign-bytes gather, batching) is the timed
-path."""
+path. Key types are the committee kind's: a public key is built through
+the program's own `pubkey_from_type`."""
 
 from __future__ import annotations
 
-from tendermint_tpu.crypto import ed25519
 from tendermint_tpu.crypto.batch_verifier import SigItem
 from tendermint_tpu.types.block import BlockIDFlag, Commit, CommitSig
 from tendermint_tpu.types.block_id import BlockID
 from tendermint_tpu.types.part_set import PartSetHeader
-from tendermint_tpu.types.validator import Validator
+from tendermint_tpu.types.validator import Validator, pubkey_from_type
 from tendermint_tpu.types.validator_set import ValidatorSet
 
 from harness import fixtures
 
 
-def validator_set(committee: fixtures.Committee) -> ValidatorSet:
+def validator_set(validators: tuple) -> ValidatorSet:
+    """The program's set of a committee's `validators(height)`."""
     vs = ValidatorSet(
         [
-            Validator(ed25519.PubKey(pub), power)
-            for pub, power in zip(committee.pubs, committee.powers)
+            Validator(pubkey_from_type(v.key_type, v.pub), v.power)
+            for v in validators
         ]
     )
-    if [v.pub_key.data for v in vs.validators] != committee.pubs:
+    if [(v.pub_key.data, v.address) for v in vs.validators] != [
+        (v.pub, v.address) for v in validators
+    ]:
         raise RuntimeError("validator-set order differs from the fixture's")
     return vs
 
 
-def entry(committee: fixtures.Committee, rec: tuple) -> tuple:
+def entry(committee, rec: tuple) -> tuple:
     """(block_id, height, Commit) as blocksync hands it to
-    verify_commits_light."""
+    verify_commits_light: a signature for each signer, the others
+    absent."""
     height, sigs, _ = rec
     bh = fixtures.block_hash(committee.seed, height)
     bid = BlockID(bh, PartSetHeader(1, bh))
-    commit = Commit(
-        height, 0, bid,
-        [
-            CommitSig(
-                BlockIDFlag.COMMIT, addr,
-                fixtures.timestamp_ns(height, i), sig,
-            )
-            for i, (addr, sig) in enumerate(zip(committee.addresses, sigs))
-        ],
-    )
-    return bid, height, commit
+    validators = committee.validators(height)
+    commit_sigs = [CommitSig.absent()] * len(validators)
+    for i, sig in zip(committee.signers(height), sigs):
+        commit_sigs[i] = CommitSig(
+            BlockIDFlag.COMMIT, validators[i].address,
+            fixtures.timestamp_ns(height, i), sig,
+        )
+    return bid, height, Commit(height, 0, bid, commit_sigs)
 
 
-def sig_items(committee: fixtures.Committee, rec: tuple) -> list:
+def sig_items(committee, rec: tuple) -> list:
     """One commit's rows as a node submits them to the scheduler."""
     height, sigs, _ = rec
-    msgs = fixtures.messages(committee.seed, height, committee.n)
+    validators = committee.validators(height)
     return [
-        SigItem(pub, msg, sig)
-        for pub, msg, sig in zip(committee.pubs, msgs, sigs)
+        SigItem(validators[i].pub, msg, sig, validators[i].key_type)
+        for i, msg, sig in zip(
+            committee.signers(height), committee.sign_bytes(height), sigs
+        )
     ]

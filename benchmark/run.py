@@ -19,6 +19,7 @@ import time
 T_PROCESS_START = time.time()
 
 import argparse  # noqa: E402
+import collections  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
@@ -43,9 +44,10 @@ def parse(argv=None):
     p.add_argument("--seconds", type=float, required=True)
     p.add_argument("--trace", type=int, choices=(0, 1), default=0)
     # not for the driver: the control of `correct` (one stated guarantee
-    # broken in the reference, put in the program's place), a rate for
-    # the one-off sweep, and another BENCHMARK.json for the tests
-    p.add_argument("--control-guarantee", default="", choices=("", "s_range"))
+    # broken in the reference, put in the program's place; the cell's
+    # committee kind says which it has), a rate for the one-off sweep,
+    # and another BENCHMARK.json for the tests
+    p.add_argument("--control-guarantee", default="")
     p.add_argument("--rate", type=float, default=0.0)
     p.add_argument("--sweep", default="")
     p.add_argument("--benchmark-file", default="")
@@ -179,6 +181,12 @@ def service_spans(report: dict) -> list:
 def context(report: dict, trace: dict | None) -> dict:
     service = report["service"]
     ipc0, ipc1 = report["ipc0"], report["ipc1"]
+    traced = [
+        r for r in report["requests"] if r["traced"] and not r["failed"]
+    ]
+    by_key_type: collections.Counter = collections.Counter()
+    for r in traced:
+        by_key_type.update(r["rows_by_key_type"])
     return {
         "window": report["window"],
         "requests": report["requests"],
@@ -195,10 +203,8 @@ def context(report: dict, trace: dict | None) -> dict:
         },
         "trace": trace,
         "spans": service_spans(report),
-        "traced_rows": sum(
-            r["rows"] for r in report["requests"]
-            if r["traced"] and not r["failed"]
-        ),
+        "traced_rows": sum(r["rows"] for r in traced),
+        "traced_rows_by_key_type": dict(by_key_type),
     }
 
 
@@ -235,6 +241,13 @@ def main(argv=None) -> int:
     if not os.path.isdir(os.path.join(ROOT, "tendermint_tpu")):
         raise SystemExit("no program beside the benchmark: nothing to run")
     args.cell = Cell(args.workload, args.benchmark_file or None)
+    if args.control_guarantee:
+        controls = args.cell.committee_kind().CONTROLS
+        if args.control_guarantee not in controls:
+            raise SystemExit(
+                f"--control-guarantee {args.control_guarantee!r}: this "
+                f"cell's committee kind has {', '.join(controls)}"
+            )
     report = run_cell(args)
     if args.sweep:
         print(json.dumps(report["sweep"], indent=1))

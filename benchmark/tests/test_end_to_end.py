@@ -1,6 +1,7 @@
-"""One tiny cell through run.py on the CPU: the result line's keys, a
-run with no chip, a traced run whose pool runs dry, one in which
-tracing never began, and a run with the timed path broken underneath."""
+"""One tiny cell through run.py on the CPU: the result line's keys (for
+the default committee kind and for `mixed_keys`), a run with no chip, a
+traced run whose pool runs dry, one in which tracing never began, and a
+run with the timed path broken underneath."""
 
 import argparse
 import json
@@ -36,6 +37,8 @@ def cpu_env():
     [
         ("rehearsal.catchup", 0, "catchup_commits_per_s"),
         ("rehearsal.live", 1, "queue_wait_ms.live"),
+        ("rehearsal.mixed-catchup", 0, "catchup_commits_per_s"),
+        ("rehearsal.mixed-live", 1, "queue_wait_ms.live"),
     ],
 )
 def test_result_line_has_the_contracts_keys(workload, trace, metric):
@@ -63,6 +66,81 @@ def test_result_line_has_the_contracts_keys(workload, trace, metric):
     # a share of a roofline or of the device is left out, never 0, where
     # there was no device trace to read
     assert not any("roofline" in k or "idle" in k for k in line["metrics"])
+    assert set(line["window"]["setup"]) == {
+        "warm_s", "signed_s", "reference_s", "rows_judged"
+    }
+    assert line["window"]["setup"]["signed_s"] > 0
+    mixed = "mixed" in workload
+    assert ("secp256k1_plain_vs_openssl" in line["checks"]) == mixed
+    for check in ("rows_wrong", "commits_wrong", "plan_vs_reference",
+                  "degrades", "rfc8032_vs_openssl"):
+        assert line["checks"][check] == {"value": 0, "limit": 0}
+    # the generator's own tally of rows, by key type, as the readers get it
+    sys.path.insert(0, BENCH_DIR)
+    import run as bench_run
+
+    with open(os.path.join(ROOT, ".bench_work", workload, "report.json")) as f:
+        report = json.load(f)
+    ctx = bench_run.context(report, None)
+    by_type = ctx["traced_rows_by_key_type"]
+    assert sum(by_type.values()) == ctx["traced_rows"]
+    assert (ctx["traced_rows"] > 0) == (trace == 1)
+    if trace:
+        assert set(by_type) == (
+            {"ed25519", "secp256k1"} if mixed else {"ed25519"}
+        )
+        assert not mixed or by_type["ed25519"] == 3 * by_type["secp256k1"]
+
+
+def test_the_open_loop_sends_within_half_a_millisecond_of_due():
+    """A rehearsal run of 40 requests: the generator keeps its own
+    schedule (`generators/open_arrivals.py`), so the median request
+    goes out within half a millisecond of its due time (a timer alone
+    reads 0.9 ms here, 1.3 on the chip's host). The median and not
+    `gen_late_p95_ms`: on the CPU the service's "device" is the cores
+    this process runs on, and a few requests of 40 are descheduled for
+    milliseconds whatever the generator does."""
+    done = run("rehearsal.live", 1, cpu_env(), ["--seconds", "8"])
+    assert done.returncode == 0, done.stderr[-2000:]
+    line = json.loads(done.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True and line["attempted"] == 40
+    assert line["metrics"]["gen_late_p95_ms.live"]["value"] > 0
+    with open(
+        os.path.join(ROOT, ".bench_work", "rehearsal.live", "report.json")
+    ) as f:
+        requests = json.load(f)["requests"]
+    late = sorted(r["t_sent"] - r["t_due"] for r in requests)
+    assert 0 <= late[0] and late[len(late) // 2] < 0.0005
+
+
+def test_the_roofline_counts_the_rows_of_the_key_type_its_file_names(
+    monkeypatch
+):
+    sys.path.insert(0, BENCH_DIR)
+    import work
+    from readers import kernel_roofline
+
+    ctx = {
+        "trace": {"modules": {"jit__verify_cached_big": (2, 0.5)}},
+        "traced_rows": 400,
+        "traced_rows_by_key_type": {"ed25519": 300, "secp256k1": 100},
+        "device": {"kind": "TPU v5 lite"},
+    }
+    spec = {"programs": "^jit__verify_cached_"}
+    least = lambda rows: work.least_seconds(rows, "TPU v5 lite")[0]  # noqa: E731
+    assert kernel_roofline.read(ctx, spec) == 100.0 * least(400) / 0.5
+    assert kernel_roofline.read(
+        ctx, dict(spec, key_type="ed25519")
+    ) == 100.0 * least(300) / 0.5
+    # nothing of that type traced: nothing to read, never a share of 0
+    assert kernel_roofline.read(ctx, dict(spec, key_type="bls12-381")) is None
+
+
+def test_a_control_the_kind_does_not_have_is_refused():
+    done = run("rehearsal.catchup", 0, cpu_env(),
+               ["--control-guarantee", "low_s"])
+    assert done.returncode != 0 and done.stdout.strip() == ""
+    assert "has s_range" in done.stderr
 
 
 def test_a_pool_that_runs_dry_is_still_traced():
