@@ -22,18 +22,22 @@ Reference: types/validator_set.go. Two things matter here:
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
 from typing import Optional
+
+import numpy as np
 
 from ..crypto import merkle
 from ..crypto.batch_verifier import BatchVerifier, SigItem, default_verifier
 from ..libs import protoio as pio
+from ..obs.tracer import default_tracer
 from .block import BlockIDFlag, Commit
 from .block_id import BlockID
 from .validator import Validator, pubkey_from_type, pubkey_type_name
 
 PRIORITY_WINDOW_SIZE_FACTOR = 2
 MAX_TOTAL_VOTING_POWER = 2**63 // 8
+_COMMIT = BlockIDFlag.COMMIT
+_ABSENT = BlockIDFlag.ABSENT
 
 
 def _default_qc_engine():
@@ -248,49 +252,109 @@ class ValidatorSet:
 
     # --- commit verification (the TPU batch path) -------------------------
 
-    def _gather_items(
+    def _gather(
         self,
         chain_id: str,
-        commit: Commit,
+        commits: list,
         only_for_block: bool,
-    ) -> tuple[list[SigItem], list[int]]:
-        """(items, indices): one SigItem per counted commit signature.
+    ) -> tuple[list[SigItem], list, np.ndarray, Optional[np.ndarray]]:
+        """(items, idxs, vals, for_block) for the counted signatures of
+        `commits`, one SigItem a row, commit after commit, index order:
+        idxs[k] the validator indices of commit k's rows, and per row
+        its validator index and (None where `only_for_block`) whether
+        it signed the block.
 
-        The per-commit (prefix, suffix) sign-bytes parts are built ONCE
-        ahead of the per-validator loop — within a commit only the
-        timestamp field differs, so each row is a cheap three-way concat
-        (the §10 commit-encode fix, hoisted; this gather is what every
-        commit-verify caller — consensus gossip, blocksync, light
-        client, evidence — runs per batch)."""
+        By columns (this gather is what every commit-verify caller —
+        consensus gossip, blocksync, light client, evidence — runs per
+        batch): each commit's flags, timestamps and signatures are read
+        in one pass, every row's sign-bytes come from ONE
+        `votes_from_parts` call over the commits' cached (prefix,
+        suffix) parts (within a commit only the timestamp differs, and
+        the block id of a nil vote), and the validators' keys are read
+        once a call. One `types.gather` span a call."""
+        with default_tracer().span("types.gather") as span:
+            parts: list = []
+            part_of_row, for_block, idxs_of, ts, sigs, vals = (
+                [], [], [], [], [], []
+            )
+            for commit in commits:
+                css = commit.signatures
+                flags = [cs.block_id_flag for cs in css]
+                p_for = len(parts)
+                parts.append(commit._sign_bytes_parts(chain_id, True))
+                if only_for_block:
+                    idxs = [i for i, f in enumerate(flags) if f == _COMMIT]
+                    part_of_row += [p_for] * len(idxs)
+                else:
+                    idxs = [i for i, f in enumerate(flags) if f != _ABSENT]
+                    signed = [flags[i] == _COMMIT for i in idxs]
+                    if not all(signed):  # the nil part, only where needed
+                        parts.append(commit._sign_bytes_parts(chain_id, False))
+                    part_of_row += [p_for if s else p_for + 1 for s in signed]
+                    for_block += signed
+                rows = [css[i] for i in idxs]
+                ts += [cs.timestamp_ns for cs in rows]
+                sigs += [cs.signature for cs in rows]
+                vals += idxs
+                idxs_of.append(idxs)
+            items = self._items(parts, part_of_row, ts, sigs, vals, span)
+        return (
+            items,
+            idxs_of,
+            np.asarray(vals, dtype=np.intp),
+            None if only_for_block else np.asarray(for_block, dtype=bool),
+        )
+
+    def _items(
+        self, parts, part_of_row, ts, sigs, vals, span
+    ) -> list[SigItem]:
+        """The SigItems of rows given by columns: their sign-bytes parts,
+        timestamps, signatures and validator indices. Sets `span`'s
+        `rows`, `columnar` and `fallback`."""
         from .canonical import CanonicalVoteEncoder
 
-        items, idxs = [], []
-        parts_for = commit._sign_bytes_parts(chain_id, True)
-        parts_nil = None  # lazily: absent in the light (ForBlock) paths
-        for i, cs in enumerate(commit.signatures):
-            if cs.is_absent():
-                continue
-            if cs.for_block():
-                prefix, suffix = parts_for
-            elif only_for_block:
-                continue
-            else:
-                if parts_nil is None:
-                    parts_nil = commit._sign_bytes_parts(chain_id, False)
-                prefix, suffix = parts_nil
-            val = self.validators[i]
-            items.append(
-                SigItem(
-                    val.pub_key.data,
-                    CanonicalVoteEncoder.vote_from_parts(
-                        prefix, suffix, cs.timestamp_ns
-                    ),
-                    cs.signature,
-                    key_type=getattr(val.pub_key, "type_name", "ed25519"),
-                )
+        msgs, fallback = CanonicalVoteEncoder.votes_from_parts(
+            parts, ts, part_of_row
+        )
+        span.set(
+            rows=len(msgs), columnar=len(msgs) - fallback, fallback=fallback
+        )
+        pubs = [v.pub_key.data for v in self.validators]
+        kinds = [pubkey_type_name(v.pub_key) for v in self.validators]
+        return list(
+            map(
+                SigItem,
+                map(pubs.__getitem__, vals),
+                msgs,
+                sigs,
+                map(kinds.__getitem__, vals),
             )
-            idxs.append(i)
-        return items, idxs
+        )
+
+    def _powers(self) -> np.ndarray:
+        """The validators' voting powers as a column: int64 where every
+        power lies in [0, MAX_TOTAL_VOTING_POWER], so that a tally of
+        one commit (its validators once each, at most the total) is
+        exact; Python ints otherwise."""
+        powers = [v.voting_power for v in self.validators]
+        exact = all(0 <= p <= MAX_TOTAL_VOTING_POWER for p in powers)
+        return np.array(powers, dtype=np.int64 if exact else object)
+
+    def _tallies(
+        self, ok, vals: np.ndarray, counts: list, counted=None
+    ) -> list[int]:
+        """Each commit's voting power over its accepted rows: commit k
+        owns the next `counts[k]` rows; a row counts where `ok` (and
+        `counted`) holds. One numpy sum a commit, exact (`_powers`)."""
+        take = np.asarray(ok, dtype=bool)
+        if counted is not None:
+            take = take & counted
+        power = np.where(take, self._powers()[vals], 0)
+        out, start = [], 0
+        for n in counts:
+            out.append(int(power[start : start + n].sum()))
+            start += n
+        return out
 
     def verify_commits_light(
         self,
@@ -309,32 +373,27 @@ class ValidatorSet:
         only across heights with an unchanged set.
         """
         verifier = verifier or default_verifier()
-        all_items: list[SigItem] = []
-        spans = []  # (start, idxs); idxs=None -> malformed entry
+        well_formed, commits = [], []
         for block_id, height, commit in entries:
             try:
                 if commit is None:
                     raise ValueError("nil commit")
                 self._check_commit_shape(block_id, height, commit)
             except ValueError:
-                spans.append((len(all_items), None))
+                well_formed.append(False)
                 continue
-            items, idxs = self._gather_items(chain_id, commit, True)
-            spans.append((len(all_items), idxs))
-            all_items.extend(items)
-        ok = verifier.verify(all_items) if all_items else []
+            well_formed.append(True)
+            commits.append(commit)
+        items, idxs_of, vals, _ = self._gather(chain_id, commits, True)
+        ok = verifier.verify(items) if items else []
+        tallies = iter(self._tallies(ok, vals, [len(i) for i in idxs_of]))
         out = []
-        for start, idxs in spans:
-            if idxs is None:
+        for formed in well_formed:
+            if not formed:
                 out.append(False)
                 continue
-            tallied = sum(
-                self.validators[i].voting_power
-                for valid, i in zip(ok[start : start + len(idxs)], idxs)
-                if valid
-            )
             try:
-                self._check_maj23(tallied)
+                self._check_maj23(next(tallies))
                 out.append(True)
             except ValueError:
                 out.append(False)
@@ -352,14 +411,14 @@ class ValidatorSet:
         must be valid AND >2/3 of total power must have signed the block."""
         self._check_commit_shape(block_id, height, commit)
         verifier = verifier or default_verifier()
-        items, idxs = self._gather_items(chain_id, commit, False)
-        ok = verifier.verify(items)
-        tallied = 0
-        for valid, i in zip(ok, idxs):
-            if not valid:
-                raise ValueError(f"wrong signature at index {i}")
-            if commit.signatures[i].for_block():
-                tallied += self.validators[i].voting_power
+        items, (idxs,), vals, for_block = self._gather(
+            chain_id, [commit], False
+        )
+        ok = np.asarray(verifier.verify(items), dtype=bool)
+        bad = np.flatnonzero(~ok)
+        if bad.size:
+            raise ValueError(f"wrong signature at index {idxs[bad[0]]}")
+        (tallied,) = self._tallies(ok, vals, [len(idxs)], for_block)
         self._check_maj23(tallied)
 
     def verify_commit_light(
@@ -375,13 +434,9 @@ class ValidatorSet:
         the serial 2/3 early exit."""
         self._check_commit_shape(block_id, height, commit)
         verifier = verifier or default_verifier()
-        items, idxs = self._gather_items(chain_id, commit, True)
+        items, (idxs,), vals, _ = self._gather(chain_id, [commit], True)
         ok = verifier.verify(items)
-        tallied = sum(
-            self.validators[i].voting_power
-            for valid, i in zip(ok, idxs)
-            if valid
-        )
+        (tallied,) = self._tallies(ok, vals, [len(idxs)])
         self._check_maj23(tallied)
 
     def verify_commit_light_trusting(
@@ -397,36 +452,30 @@ class ValidatorSet:
         own power. Signers are matched by address, not index."""
         if trust_denominator == 0:
             raise ValueError("trust level has zero denominator")
-        from .canonical import CanonicalVoteEncoder
-
         verifier = verifier or default_verifier()
-        items, powers = [], []
+        vals, ts, sigs = [], [], []
         seen: set[bytes] = set()
-        # parts hoisted out of the per-validator loop (only ForBlock rows
-        # are gathered here, so one (prefix, suffix) covers every row)
-        prefix, suffix = commit._sign_bytes_parts(chain_id, True)
-        for i, cs in enumerate(commit.signatures):
-            if not cs.for_block():
-                continue
-            idx, val = self.get_by_address(cs.validator_address)
-            if idx < 0 or val is None:
-                continue
-            if val.address in seen:
-                raise ValueError("double vote from validator")
-            seen.add(val.address)
-            items.append(
-                SigItem(
-                    val.pub_key.data,
-                    CanonicalVoteEncoder.vote_from_parts(
-                        prefix, suffix, cs.timestamp_ns
-                    ),
-                    cs.signature,
-                    key_type=getattr(val.pub_key, "type_name", "ed25519"),
-                )
-            )
-            powers.append(val.voting_power)
+        with default_tracer().span("types.gather") as span:
+            # only ForBlock rows are gathered here, so one (prefix,
+            # suffix) covers every row
+            parts = [commit._sign_bytes_parts(chain_id, True)]
+            for cs in commit.signatures:
+                if not cs.for_block():
+                    continue
+                idx, val = self.get_by_address(cs.validator_address)
+                if idx < 0 or val is None:
+                    continue
+                if val.address in seen:
+                    raise ValueError("double vote from validator")
+                seen.add(val.address)
+                vals.append(idx)
+                ts.append(cs.timestamp_ns)
+                sigs.append(cs.signature)
+            items = self._items(parts, None, ts, sigs, vals, span)
         ok = verifier.verify(items)
-        tallied = sum(p for valid, p in zip(ok, powers) if valid)
+        (tallied,) = self._tallies(
+            ok, np.asarray(vals, dtype=np.intp), [len(vals)]
+        )
         needed = (
             self.total_voting_power() * trust_numerator
         ) // trust_denominator
