@@ -201,14 +201,17 @@ class _PreparedBatch:
     what lets parallel/scheduler overlap the next batch's host assembly
     with the current batch's device round. `devices` is the mesh shard
     count the dispatch will use (1 = unsharded — the scheduler stamps
-    its device_round span with it)."""
+    its device_round span with it). `host_rows` counts the rows of
+    other key types, verified beside the ed25519 device batch (the
+    ledger books them apart from that batch's padded bucket)."""
 
-    __slots__ = ("n", "run", "devices")
+    __slots__ = ("n", "run", "devices", "host_rows")
 
-    def __init__(self, n: int, run, devices: int = 1):
+    def __init__(self, n: int, run, devices: int = 1, host_rows: int = 0):
         self.n = n
         self.run = run
         self.devices = devices
+        self.host_rows = host_rows
 
 
 def _verify_cached_small(tables, tvalid, idx, rb, sb, kb, s_ok):
@@ -975,7 +978,10 @@ class BatchVerifier:
 
     def _verify_mixed(self, items: list[SigItem], other_idx: list[int]):
         """Mixed-key partition: ed25519 rows ride the device batch, other
-        types verify on host, and the bitmap is re-interleaved."""
+        types verify on host, and the bitmap is re-interleaved. The
+        secp256k1 share is traced as `crypto.secp_verify` (`rows`,
+        `rejected`, `engine` host or device), around the
+        `crypto.secp_prep` of the native path (secp_native.py)."""
         n = len(items)
         out = np.zeros(n, dtype=bool)
         ed_idx = [
@@ -991,23 +997,31 @@ class BatchVerifier:
         if secp_idx:
             import os as _os
 
-            if (
+            # device kernel (SURVEY §2.2 secp row): gated like
+            # TM_TPU_MXU_GATHER — the native host batch won on the
+            # earlier executor; not measured on the current chip
+            device = (
                 _os.environ.get("TM_TPU_SECP_DEVICE") == "1"
                 and len(secp_idx) >= 32
-            ):
-                # device kernel (SURVEY §2.2 secp row): gated like
-                # TM_TPU_MXU_GATHER — the native host batch won on the
-                # earlier executor; not measured on the current chip
-                verdicts = _verify_secp_device(
-                    [items[i] for i in secp_idx]
-                )
-            else:
-                from . import secp_native
+            )
+            with default_tracer().span(
+                "crypto.secp_verify", rows=len(secp_idx),
+                engine="device" if device else "host",
+            ) as span:
+                if device:
+                    verdicts = _verify_secp_device(
+                        [items[i] for i in secp_idx]
+                    )
+                else:
+                    from . import secp_native
 
-                verdicts = secp_native.verify_msgs_batch(
-                    [items[i].pubkey for i in secp_idx],
-                    [items[i].msg for i in secp_idx],
-                    [items[i].sig for i in secp_idx],
+                    verdicts = secp_native.verify_msgs_batch(
+                        [items[i].pubkey for i in secp_idx],
+                        [items[i].msg for i in secp_idx],
+                        [items[i].sig for i in secp_idx],
+                    )
+                span.set(
+                    rejected=len(secp_idx) - int(np.count_nonzero(verdicts))
                 )
             out[secp_idx] = verdicts
         for i in other_idx:
@@ -1040,7 +1054,8 @@ class BatchVerifier:
             # mixed-key batches recurse through verify(); host-bound, so
             # the work stays on the dispatch side
             return _PreparedBatch(
-                n, lambda: self._verify_mixed(items, other_idx)
+                n, lambda: self._verify_mixed(items, other_idx),
+                host_rows=len(other_idx),
             )
         if n < self._min_device_batch:
 

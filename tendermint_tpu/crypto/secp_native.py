@@ -16,6 +16,7 @@ import ctypes
 import hashlib
 from typing import Optional
 
+from ..obs import default_tracer
 from ._native_build import NativeLoader
 from .secp256k1 import N, _HALF_N, decompress_point, verify_digest
 
@@ -79,22 +80,24 @@ def verify_digest_batch(
                 out[i] = verify_digest(digests[i], sigs[i], pt)
         return out
 
-    # python-side cheap work: parse/range-check, decompress, u1/u2
+    # python-side cheap work: parse/range-check, decompress, u1/u2,
+    # traced as crypto.secp_prep (the native Shamir step is the rest)
     idx = []
     pub_buf = bytearray()
     u1_buf = bytearray()
     u2_buf = bytearray()
     rs: list[int] = []
-    for i in range(n):
-        prep = prep_digest_item(pub33s[i], digests[i], sigs[i])
-        if prep is None:
-            continue
-        r, pt, u1, u2 = prep
-        idx.append(i)
-        rs.append(r)
-        pub_buf += pt[0].to_bytes(32, "big") + pt[1].to_bytes(32, "big")
-        u1_buf += u1.to_bytes(32, "big")
-        u2_buf += u2.to_bytes(32, "big")
+    with default_tracer().span("crypto.secp_prep", rows=n):
+        for i in range(n):
+            prep = prep_digest_item(pub33s[i], digests[i], sigs[i])
+            if prep is None:
+                continue
+            r, pt, u1, u2 = prep
+            idx.append(i)
+            rs.append(r)
+            pub_buf += pt[0].to_bytes(32, "big") + pt[1].to_bytes(32, "big")
+            u1_buf += u1.to_bytes(32, "big")
+            u2_buf += u2.to_bytes(32, "big")
     if not idx:
         return out
     out_x = ctypes.create_string_buffer(33 * len(idx))

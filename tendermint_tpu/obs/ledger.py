@@ -88,6 +88,9 @@ class DispatchLedger:
         self._sharded_rounds = 0
         self._rows_requested = 0  # sig rounds only (fn rows below)
         self._rows_dispatched = 0  # padded bucket rows, sig rounds only
+        # rows of other key types a sig round verified beside its
+        # ed25519 device batch (secp256k1 on the host): in no bucket
+        self._host_rows = 0
         self._fn_rows = 0
         self._submissions = 0
         self._device_seconds = 0.0
@@ -125,17 +128,22 @@ class DispatchLedger:
         host_prep_s: float = 0.0,
         device_s: float = 0.0,
         engine: str = "sig",
+        host_rows: int = 0,
     ) -> None:
         """Book one device round. `class_rows` maps submitter class ->
         rows it contributed (requested, pre-padding); `requested` is
-        their sum, `dispatched` the padded bucket actually sent to the
-        device (== requested for fn-lane rounds, which pad internally).
+        their sum less `host_rows`, `dispatched` the padded bucket
+        actually sent to the device (== requested for fn-lane rounds,
+        which pad internally). `host_rows` are the rows of a mixed-key
+        round verified beside its device batch, in no bucket.
         `t` is the caller's event time for the dispatch start — the
         ledger never reads a clock. `class_subs`/`class_queue_wait`
         optionally map class -> merged-submission count / summed
         enqueue->dispatch wait."""
         requested = int(requested)
+        host_rows = int(host_rows)
         dispatched = max(int(dispatched), requested)
+        rows_total = requested + host_rows
         # every engine other than the coalesced ed25519 plane is an
         # fn-lane round (anonymous closures book as "fn"; wire engines
         # carry their name) — its rows/fill live on the fn axis
@@ -167,6 +175,7 @@ class DispatchLedger:
             },
             "requested": requested,
             "dispatched": dispatched,
+            "host_rows": host_rows,
             "fill": round(fill, 4),
             "devices": int(devices),
             "sharded": devices > 1,
@@ -186,6 +195,7 @@ class DispatchLedger:
             else:
                 self._rows_requested += requested
                 self._rows_dispatched += dispatched
+                self._host_rows += host_rows
             eng = self._per_engine.get(engine)
             if eng is None:
                 eng = self._per_engine[engine] = {
@@ -211,11 +221,11 @@ class DispatchLedger:
                 acct.rounds += 1
                 # device time attributed by row share (fn/single-class
                 # rounds book whole: rows == requested)
-                if requested > 0:
-                    acct.device_seconds += device_s * (rows / requested)
+                if rows_total > 0:
+                    acct.device_seconds += device_s * (rows / rows_total)
                 acct.queue_wait_seconds += class_queue_wait.get(klass, 0.0)
                 acct.submissions += int(class_subs.get(klass, 0))
-            if not fn:
+            if not fn and dispatched:
                 b = self._by_bucket.get(dispatched)
                 if b is None:
                     b = self._by_bucket[dispatched] = {
@@ -238,6 +248,7 @@ class DispatchLedger:
                 "sharded_rounds": self._sharded_rounds,
                 "rows_requested": self._rows_requested,
                 "rows_dispatched": self._rows_dispatched,
+                "host_rows": self._host_rows,
                 "fn_rows": self._fn_rows,
                 "submissions": self._submissions,
                 "device_seconds": self._device_seconds,
@@ -278,7 +289,10 @@ class DispatchLedger:
         # internal buckets are honest now, but blending a 0.59-full
         # bls_agg aggregate with a 0.95-full ed25519 bucket prices
         # nothing — each plane reads its own axis (per_engine below)
-        sig_fills = sorted(e["fill"] for e in span if e["engine"] == "sig")
+        sig_fills = sorted(
+            e["fill"] for e in span
+            if e["engine"] == "sig" and e["dispatched"]
+        )
         rounds = now["rounds"] - base.get("rounds", 0)
         fn_rounds = now["fn_rounds"] - base.get("fn_rounds", 0)
         requested = now["rows_requested"] - base.get("rows_requested", 0)
@@ -300,7 +314,7 @@ class DispatchLedger:
             # when the ring held the whole span; flagged below when not)
             accts: dict[str, _ClassAccount] = {}
             for e in span:
-                e_req = e["requested"] or 1
+                e_req = (e["requested"] + e["host_rows"]) or 1
                 for klass, rows in e["rows"].items():
                     acct = accts.setdefault(klass, _ClassAccount())
                     acct.rows += rows
@@ -334,7 +348,7 @@ class DispatchLedger:
             ) if eng["rounds"] else 0.0
         by_bucket: dict[int, dict] = {}
         for e in span:
-            if e["engine"] != "sig":
+            if e["engine"] != "sig" or not e["dispatched"]:
                 continue
             b = by_bucket.setdefault(
                 e["dispatched"],
@@ -351,6 +365,7 @@ class DispatchLedger:
             ),
             "rows_requested": requested,
             "rows_dispatched": dispatched,
+            "host_rows": now["host_rows"] - base.get("host_rows", 0),
             "fn_rows": now["fn_rows"] - base.get("fn_rows", 0),
             "padding_rows": max(0, dispatched - requested),
             "fill_ratio": round(requested / dispatched, 4) if dispatched

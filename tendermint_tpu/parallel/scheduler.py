@@ -493,7 +493,7 @@ class VerifyScheduler:
                 prep = await self._host_prep(loop, round_)
                 if prep is None:
                     continue  # prep failed; futures already resolved
-                run, devices, prep_s = prep
+                run, devices, prep_s, host_rows = prep
                 # serialize device rounds: round N completes (and its
                 # verdicts resolve) before round N+1 dispatches — while
                 # N executes, the loop above already prepped N+1
@@ -501,7 +501,7 @@ class VerifyScheduler:
                     await inflight
                     inflight = None
                 inflight = loop.create_task(
-                    self._execute(round_, run, devices, prep_s)
+                    self._execute(round_, run, devices, prep_s, host_rows)
                 )
         except asyncio.CancelledError:
             pass  # forced cancel (loop teardown): fall through to drain
@@ -517,11 +517,12 @@ class VerifyScheduler:
         """Stage 1 of the pipeline: host-side batch assembly (padding,
         sign-bytes challenge hashing) on the prep thread. Returns
         (device-run callable, mesh device count of the dispatch,
-        host-prep seconds), or None after resolving failures."""
+        host-prep seconds, rows verified beside the device batch), or
+        None after resolving failures."""
         kind = round_[0]
         if kind == "fn":
             sub = round_[1]
-            return (lambda: sub.fn(sub.items)), 1, 0.0
+            return (lambda: sub.fn(sub.items)), 1, 0.0, 0
         _, slices, total = round_
         flat: list[SigItem] = []
         for sub, lo, take in slices:
@@ -530,7 +531,7 @@ class VerifyScheduler:
         if prep_fn is None:
             # plain .verify-only verifier (test stubs): no split, the
             # whole call runs on the dispatch thread
-            return (lambda: self.verifier.verify(flat)), 1, 0.0
+            return (lambda: self.verifier.verify(flat)), 1, 0.0, 0
         t0 = time.perf_counter()
         try:
             prepared = await loop.run_in_executor(
@@ -547,7 +548,10 @@ class VerifyScheduler:
             prep_s,
             n=total,
         )
-        return prepared.run, getattr(prepared, "devices", 1), prep_s
+        return (
+            prepared.run, getattr(prepared, "devices", 1), prep_s,
+            getattr(prepared, "host_rows", 0),
+        )
 
     def _trace(self):
         return self.tracer if self.tracer is not None else default_tracer()
@@ -578,7 +582,8 @@ class VerifyScheduler:
         )
 
     async def _execute(
-        self, round_, run, devices: int = 1, prep_s: float = 0.0
+        self, round_, run, devices: int = 1, prep_s: float = 0.0,
+        host_rows: int = 0,
     ) -> None:
         loop = asyncio.get_running_loop()
         kind = round_[0]
@@ -656,8 +661,15 @@ class VerifyScheduler:
         registry = getattr(
             self.verifier, "_registry", None
         ) or default_shape_registry()
-        bucket = registry.bucket_for(total, multiple_of=max(1, devices))
-        fill = total / bucket if bucket else 0.0
+        # a mixed-key round pads only its ed25519 rows; the rows of
+        # other key types are verified beside that batch and booked as
+        # host_rows
+        device_rows = total - host_rows
+        bucket = (
+            registry.bucket_for(device_rows, multiple_of=max(1, devices))
+            if device_rows else 0
+        )
+        fill = device_rows / bucket if bucket else 0.0
         if n_subs >= 2:
             self.metrics.dispatch_coalesced.inc()
         self.metrics.batch_fill_ratio.set(round(fill, 4))
@@ -678,12 +690,13 @@ class VerifyScheduler:
                 dur * (rows / total), klass=klass
             )
             self.metrics.fill_ratio.set(round(fill, 4), klass=klass)
-        self.metrics.padding_rows.inc(max(0, bucket - total))
+        self.metrics.padding_rows.inc(max(0, bucket - device_rows))
         self.ledger.record_round(
             t0,
             class_rows=class_rows,
-            requested=total,
+            requested=device_rows,
             dispatched=bucket,
+            host_rows=host_rows,
             devices=devices,
             submissions=n_subs,
             class_subs=class_subs,
@@ -701,6 +714,7 @@ class VerifyScheduler:
         tracer.add_span(
             "scheduler.device_round", t0, dur,
             n=total, bucket=bucket, fill=round(fill, 3),
+            host_rows=host_rows,
             classes=",".join(classes), coalesced=n_subs,
             sharded=devices > 1, devices=devices,
         )

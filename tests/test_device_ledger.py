@@ -130,6 +130,43 @@ def test_ledger_totals_and_per_class_attribution():
     assert s["requests_per_dispatch"] == pytest.approx(7 / 3, abs=1e-3)
 
 
+@pytest.mark.parametrize("since", [False, True], ids=["whole", "span"])
+def test_ledger_books_host_rows_apart_from_the_bucket(since):
+    """A mixed-key round's rows of other key types are host_rows: in
+    no bucket and no fill, but in each class's share of the round's
+    time. A round of host rows alone books no bucket and no fill."""
+    led = DispatchLedger()
+    mark = led.mark()
+    led.record_round(
+        1.0,
+        class_rows={"blocksync": 48, "consensus": 16},
+        requested=48,
+        dispatched=64,
+        host_rows=16,
+        submissions=2,
+        class_subs={"blocksync": 1, "consensus": 1},
+        device_s=0.200,
+    )
+    led.record_round(
+        2.0,
+        class_rows={"consensus": 8},
+        requested=0,
+        dispatched=0,
+        host_rows=8,
+        device_s=0.040,
+    )
+    s = led.summary(since=mark if since else None)
+    assert led.totals()["host_rows"] == s["host_rows"] == 24
+    assert (s["rows_requested"], s["rows_dispatched"]) == (48, 64)
+    assert s["fill_ratio"] == s["fill_ratio_p50"] == 0.75
+    assert list(s["by_bucket"]) == ["64"]
+    assert [e["host_rows"] for e in led.entries()] == [16, 8]
+    pc = s["per_class"]
+    assert pc["blocksync"]["device_seconds"] == pytest.approx(0.150)
+    assert pc["consensus"]["device_seconds"] == pytest.approx(0.090)
+    assert sum(v["device_share"] for v in pc.values()) == pytest.approx(1.0)
+
+
 def test_ledger_fill_percentiles_and_entry_ring():
     led = DispatchLedger(max_entries=8)
     for i in range(20):
