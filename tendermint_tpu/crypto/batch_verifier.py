@@ -23,7 +23,9 @@ Responsibilities:
   bulk batches (blocksync/light replay) use doubling-free fixed-window tables
   (128 KiB/key, ~64x the build cost — amortized over thousands of reuses,
   2.5x faster to verify);
-- mixed key types: non-ed25519 rows (secp256k1/sr25519) partition to host;
+- mixed key types: a round's secp256k1 rows verify on the host's
+  cores (crypto/secp_native.py) while the ed25519 batch runs on the
+  device; sr25519 rows verify on host;
 - optional mesh sharding: with a `jax.sharding.Mesh`, batches of at least
   `mesh_min_rows` rows are row-sharded across the mesh devices
   (`NamedSharding` over every mesh axis) so one coalesced scheduler round
@@ -46,6 +48,7 @@ import functools
 import hashlib
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from itertools import compress
 
@@ -57,6 +60,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs import default_tracer
 from ..ops import ed25519_batch
+from . import secp_native
 from .ed25519 import L
 from .shape_registry import (
     DEFAULT_BUCKET_LADDER,
@@ -104,6 +108,11 @@ TABLE_STORE_SHARE = 0.25
 # tier's expensive one-time table build
 BIGTABLE_MIN = 512
 
+# from this many secp256k1 rows a mixed round prepares them with its
+# ed25519 rows and verifies them beside its device program; fewer take
+# one host call inside the round
+SECP_PREPARED_MIN = 32
+
 # batches below this row count stay on ONE device even under a mesh:
 # a sharded dispatch pays shard + all-gather overhead that only
 # amortizes on bulk rounds, while consensus rounds (O(validators) rows)
@@ -132,13 +141,6 @@ def _traced(name: str, **fields):
         return
     with tracer.span(name, **fields), jax.profiler.TraceAnnotation(name):
         yield True
-
-
-def _bucket(n: int, multiple_of: int = 1) -> int:
-    """Smallest padded size >= n from the process bucket ladder, rounded
-    up so the batch axis divides evenly across `multiple_of` mesh
-    shards."""
-    return default_shape_registry().bucket_for(n, multiple_of)
 
 
 @dataclass(frozen=True)
@@ -729,6 +731,12 @@ class BatchVerifier:
             tier="big",
             sharding=rep,
         )
+        # secp256k1 keys' points, decompressed once per key, and the
+        # threads of their native step (none start before a chunk)
+        self._secp_keys = secp_native.KeyCache()
+        self._secp_threads = ThreadPoolExecutor(
+            secp_native.HOST_THREADS, thread_name_prefix="secp-native"
+        )
 
     # --- mesh topology -----------------------------------------------------
 
@@ -971,63 +979,74 @@ class BatchVerifier:
 
         Mixed-key commits (BASELINE config 4; reference allows ed25519 and
         secp256k1 validators side by side, crypto/secp256k1/secp256k1.go:192)
-        are partitioned per key type: ed25519 rows ride the device batch,
-        other types verify on host, and the bitmap is re-interleaved.
+        are partitioned per key type (`_prepare_mixed`) and the bitmap is
+        re-interleaved.
         """
         return self.prepare(items).run()
 
-    def _verify_mixed(self, items: list[SigItem], other_idx: list[int]):
-        """Mixed-key partition: ed25519 rows ride the device batch, other
-        types verify on host, and the bitmap is re-interleaved. The
-        secp256k1 share is traced as `crypto.secp_verify` (`rows`,
-        `rejected`, `engine` host or device), around the
-        `crypto.secp_prep` of the native path (secp_native.py)."""
+    def _prepare_mixed(self, items: list[SigItem], kinds: list[str]):
+        """A mixed-key batch, prepared like an ed25519 one: the ed25519
+        rows' own `prepare` and, from `SECP_PREPARED_MIN` secp256k1
+        rows, their prep (secp_native.prep_msgs over the verifier's
+        KeyCache, traced as `crypto.secp_prep`). `run` starts their
+        native step on the host's threads, runs the ed25519 batch on the
+        device meanwhile and waits for both; the secp256k1 share is
+        traced as `crypto.secp_verify` (`rows`, `rejected`, `engine`),
+        from its start to its verdicts. Fewer secp256k1 rows take one
+        host call after the device batch, their prep inside it. Other
+        key types verify on host; the bitmap is re-interleaved."""
         n = len(items)
-        out = np.zeros(n, dtype=bool)
-        ed_idx = [
-            i for i, it in enumerate(items) if it.key_type == "ed25519"
+        ed_idx = [i for i, k in enumerate(kinds) if k == "ed25519"]
+        secp_idx = [i for i, k in enumerate(kinds) if k == "secp256k1"]
+        other_idx = [
+            i for i, k in enumerate(kinds) if k not in ("ed25519", "secp256k1")
         ]
-        if ed_idx:
-            out[ed_idx] = self.verify([items[i] for i in ed_idx])
-        # secp256k1 rows: one native batched call (BASELINE config 4;
-        # the python loop is the no-compiler fallback inside)
-        secp_idx = [
-            i for i in other_idx if items[i].key_type == "secp256k1"
-        ]
-        if secp_idx:
-            import os as _os
+        ed = self.prepare([items[i] for i in ed_idx]) if ed_idx else None
+        secp = [items[i] for i in secp_idx]
+        m = len(secp)
+        cols = (
+            [it.pubkey for it in secp],
+            [it.msg for it in secp],
+            [it.sig for it in secp],
+        )
+        prepared = (
+            secp_native.prep_msgs(*cols, self._secp_keys)
+            if m >= SECP_PREPARED_MIN else None
+        )
 
-            # device kernel (SURVEY §2.2 secp row): gated like
-            # TM_TPU_MXU_GATHER — the native host batch won on the
-            # earlier executor; not measured on the current chip
-            device = (
-                _os.environ.get("TM_TPU_SECP_DEVICE") == "1"
-                and len(secp_idx) >= 32
-            )
-            with default_tracer().span(
-                "crypto.secp_verify", rows=len(secp_idx),
-                engine="device" if device else "host",
-            ) as span:
-                if device:
-                    verdicts = _verify_secp_device(
-                        [items[i] for i in secp_idx]
-                    )
-                else:
-                    from . import secp_native
-
-                    verdicts = secp_native.verify_msgs_batch(
-                        [items[i].pubkey for i in secp_idx],
-                        [items[i].msg for i in secp_idx],
-                        [items[i].sig for i in secp_idx],
-                    )
-                span.set(
-                    rejected=len(secp_idx) - int(np.count_nonzero(verdicts))
+        def _run() -> np.ndarray:
+            out = np.zeros(n, dtype=bool)
+            tracer = default_tracer()
+            if prepared is not None:
+                t0 = time.perf_counter()
+                wait = secp_native.start(prepared, self._secp_threads)
+            if ed is not None:
+                out[ed_idx] = ed.run()
+            if prepared is not None:
+                verdicts = wait()
+                tracer.add_span(
+                    "crypto.secp_verify", t0, time.perf_counter() - t0,
+                    rows=m, engine="host",
+                    rejected=m - int(np.count_nonzero(verdicts)),
                 )
-            out[secp_idx] = verdicts
-        for i in other_idx:
-            if items[i].key_type != "secp256k1":
+                out[secp_idx] = verdicts
+            elif m:
+                with tracer.span(
+                    "crypto.secp_verify", rows=m, engine="host"
+                ) as span:
+                    verdicts = secp_native.verify_msgs_batch(
+                        *cols, self._secp_keys
+                    )
+                    span.set(rejected=m - int(np.count_nonzero(verdicts)))
+                out[secp_idx] = verdicts
+            for i in other_idx:
                 out[i] = self._verify_host_other(items[i])
-        return out
+            return out
+
+        return _PreparedBatch(
+            n, _run, devices=ed.devices if ed else 1,
+            host_rows=n - len(ed_idx),
+        )
 
     def prepare(self, items: list[SigItem]) -> "_PreparedBatch":
         """Host-side assembly of one batch: partition decisions, bucket
@@ -1050,13 +1069,7 @@ class BatchVerifier:
             return _PreparedBatch(0, lambda: np.zeros(0, dtype=bool))
         kinds = [it.key_type for it in items]
         if kinds.count("ed25519") != n:
-            other_idx = [i for i, k in enumerate(kinds) if k != "ed25519"]
-            # mixed-key batches recurse through verify(); host-bound, so
-            # the work stays on the dispatch side
-            return _PreparedBatch(
-                n, lambda: self._verify_mixed(items, other_idx),
-                host_rows=len(other_idx),
-            )
+            return self._prepare_mixed(items, kinds)
         if n < self._min_device_batch:
 
             def _run_host() -> np.ndarray:
@@ -1176,13 +1189,8 @@ class BatchVerifier:
 
     @staticmethod
     def _verify_host_other(it: SigItem) -> bool:
-        """Host verify for non-ed25519 key types (secp256k1/sr25519);
-        batched secp rows route above instead — native C++, or the
-        TM_TPU_SECP_DEVICE kernel."""
-        if it.key_type == "secp256k1":
-            from . import secp256k1
-
-            return secp256k1.PubKey(it.pubkey).verify(it.msg, it.sig)
+        """Host verify of one row of a key type that has no batch
+        engine (sr25519); any other type is refused."""
         if it.key_type == "sr25519":
             from . import sr25519
 
@@ -1191,51 +1199,6 @@ class BatchVerifier:
 
     def verify_one(self, pubkey: bytes, msg: bytes, sig: bytes) -> bool:
         return bool(self.verify([SigItem(pubkey, msg, sig)])[0])
-
-
-def _verify_secp_device(items: list) -> np.ndarray:
-    """secp256k1 rows on the device kernel (ops/secp256k1_kernel):
-    host does parse/low-S/u1-u2/decompression (the same split the
-    native path uses, secp_native.py), the device runs the batched
-    joint ladder. Gated behind TM_TPU_SECP_DEVICE=1."""
-    import hashlib
-
-    import jax.numpy as jnp
-
-    from .secp_native import prep_digest_item
-    from ..ops import secp256k1_kernel as sk
-
-    n = len(items)
-    B = _bucket(n)
-    fe = sk.fe
-    qx = np.zeros((B, fe.NLIMBS), dtype=np.int32)
-    qy = np.zeros((B, fe.NLIMBS), dtype=np.int32)
-    u1 = np.zeros((B, 32), dtype=np.uint8)
-    u2 = np.zeros((B, 32), dtype=np.uint8)
-    rb = np.zeros((B, 32), dtype=np.uint8)
-    ok = np.zeros(B, dtype=bool)
-    for i, it in enumerate(items):
-        prep = prep_digest_item(
-            it.pubkey, hashlib.sha256(it.msg).digest(), it.sig
-        )
-        if prep is None:
-            continue
-        _r, pt, u1v, u2v = prep
-        qx[i] = fe.from_int(pt[0])
-        qy[i] = fe.from_int(pt[1])
-        u1[i] = np.frombuffer(u1v.to_bytes(32, "big"), np.uint8)
-        u2[i] = np.frombuffer(u2v.to_bytes(32, "big"), np.uint8)
-        rb[i] = np.frombuffer(it.sig[:32], np.uint8)
-        ok[i] = True
-    out = sk.verify_prehashed_jit(
-        jnp.asarray(qx),
-        jnp.asarray(qy),
-        jnp.asarray(u1),
-        jnp.asarray(u2),
-        jnp.asarray(rb),
-        jnp.asarray(ok),
-    )
-    return np.asarray(out)[:n]
 
 
 _default: BatchVerifier | None = None

@@ -15,10 +15,12 @@ p = 2^256 - 2^32 - 977); the curve is y^2 = x^3 + 7 (a = 0), Jacobian
 coordinates, dbl-2009-l / add-2007-bl formulas matching the host oracle
 (crypto/secp256k1.py) limb-for-limb after canonicalization.
 
-On this harness's executor the native host batch (~2k sigs/s) and this
-kernel trade places depending on batch size; the BatchVerifier routes
-secp rows here only when TM_TPU_SECP_DEVICE=1 (real-silicon design,
-same gating philosophy as TM_TPU_MXU_GATHER — see PERF_ANALYSIS.md).
+No served path runs it: on a TPU v5 lite an 8,192-row execution reads
+1,096.6 ms and the program takes 100 s to load from the compile cache,
+where the host's native step on eight threads verifies a round's 4,096
+rows in about 200 ms (PERF.md section 6). It is held to the host
+oracle by tests/test_ops_secp.py until a layout with the batch on the
+lanes (ROADMAP S18) makes it worth a round.
 """
 
 from __future__ import annotations

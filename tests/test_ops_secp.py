@@ -126,25 +126,40 @@ def test_group_ops_match_host_oracle():
 # --- full verify -----------------------------------------------------------
 
 
-def test_verify_kernel_differential_via_batch_verifier(monkeypatch):
-    """End to end through the BatchVerifier's TM_TPU_SECP_DEVICE route:
-    host prep (parse/low-S/u1-u2/decompress) + device joint ladder must
-    agree with the host verify on valid, corrupted, wrong-message,
-    cross-key, and malformed rows."""
-    from tendermint_tpu.crypto.batch_verifier import BatchVerifier, SigItem
+def _kernel_operands(batch, b: int):
+    """The ladder's operands from the host half's prepared rows, over a
+    bucket of b rows: qx, qy [b, 32] int32 little-endian byte limbs, u1,
+    u2, r [b, 32] uint8 big-endian, ok [b]; every other row zero."""
+    qx = np.zeros((b, 32), dtype=np.int32)
+    qy = np.zeros((b, 32), dtype=np.int32)
+    u1, u2, rb = (np.zeros((b, 32), dtype=np.uint8) for _ in range(3))
+    ok = np.zeros(b, dtype=bool)
+    at = batch.rows
+    qx[at] = batch.q[:, 31::-1]
+    qy[at] = batch.q[:, :31:-1]
+    u1[at], u2[at], rb[at] = batch.u1, batch.u2, batch.r
+    ok[at] = True
+    return qx, qy, u1, u2, rb, ok
 
-    monkeypatch.setenv("TM_TPU_SECP_DEVICE", "1")
+
+def test_verify_kernel_differential_via_batch_verifier():
+    """The device joint ladder over the operands the host half
+    (secp_native.prep_digest_batch: parse/low-S/u1-u2, the key's point)
+    builds must agree with the host verify on valid, corrupted,
+    wrong-message, cross-key, and malformed rows."""
+    from tendermint_tpu.crypto import secp_native
+
     privs = [host.PrivKey.from_secret(b"dev%d" % i) for i in range(7)]
-    items = []
+    rows = []
     expect = []
     for i, pv in enumerate(privs):
         msg = b"msg%d" % i
         sig = pv.sign(msg)
         pub = pv.public_key().data
-        items.append(SigItem(pub, msg, sig, "secp256k1"))
+        rows.append((pub, msg, sig))
         expect.append(True)
         bad = sig[:32] + bytes([sig[32] ^ 1]) + sig[33:]
-        items.append(SigItem(pub, msg, bad, "secp256k1"))
+        rows.append((pub, msg, bad))
         expect.append(
             host.verify_digest(
                 hashlib.sha256(msg).digest(),
@@ -152,25 +167,33 @@ def test_verify_kernel_differential_via_batch_verifier(monkeypatch):
                 host.decompress_point(pub),
             )
         )
-        items.append(SigItem(pub, b"other", sig, "secp256k1"))
+        rows.append((pub, b"other", sig))
         expect.append(False)
         other = privs[(i + 1) % 7].public_key().data
-        items.append(SigItem(other, msg, sig, "secp256k1"))
+        rows.append((other, msg, sig))
         expect.append(False)
     # malformed rows: short signature, garbage pubkey
-    items.append(SigItem(privs[0].public_key().data, b"m", b"\x01" * 10,
-                         "secp256k1"))
+    rows.append((privs[0].public_key().data, b"m", b"\x01" * 10))
     expect.append(False)
-    items.append(SigItem(b"\x02" + b"\x00" * 32, b"m",
-                         privs[0].sign(b"m"), "secp256k1"))
+    rows.append((b"\x02" + b"\x00" * 32, b"m", privs[0].sign(b"m")))
     expect.append(False)
-    assert len(items) >= 30  # the >=32 gate rounds to the 32 bucket
-    items += [items[0], items[1]]
+    rows += [rows[0], rows[1]]
     expect += [expect[0], expect[1]]
-    got = BatchVerifier().verify(items)
+    assert len(rows) == 32  # one bucket, no padding row
+    batch = secp_native.prep_digest_batch(
+        [r[0] for r in rows],
+        [hashlib.sha256(r[1]).digest() for r in rows],
+        [r[2] for r in rows],
+    )
+    got = np.asarray(
+        k.verify_prehashed_jit(
+            *(jnp.asarray(a) for a in _kernel_operands(batch, 32))
+        )
+    )
     assert got.tolist() == expect, (
         f"device/host divergence: {got.tolist()} vs {expect}"
     )
+    assert secp_native.start(batch)().tolist() == expect
 
 
 def test_verify_wrapped_mod_n_guard():
