@@ -151,6 +151,20 @@ class SigItem:
     key_type: str = "ed25519"
 
 
+class SigBatch(list):
+    """The SigItems of one gather, with the instant the gather began
+    (`t_gather_ns`, `time.perf_counter_ns()`): a remote scheduler's
+    `submit_sync` puts it on the submission's wire trailer, and the
+    service times the caller's gather from it. Every verifier takes it
+    as the list it is."""
+
+    __slots__ = ("t_gather_ns",)
+
+    def __init__(self, items=(), t_gather_ns: int = 0):
+        super().__init__(items)
+        self.t_gather_ns = t_gather_ns
+
+
 # L's 32 bytes, most significant first
 _L_BE = np.frombuffer(L.to_bytes(32, "big"), dtype=np.uint8)
 
@@ -986,22 +1000,26 @@ class BatchVerifier:
 
     def _prepare_mixed(self, items: list[SigItem], kinds: list[str]):
         """A mixed-key batch, prepared like an ed25519 one: the ed25519
-        rows' own `prepare` and, from `SECP_PREPARED_MIN` secp256k1
-        rows, their prep (secp_native.prep_msgs over the verifier's
-        KeyCache, traced as `crypto.secp_prep`). `run` starts their
-        native step on the host's threads, runs the ed25519 batch on the
-        device meanwhile and waits for both; the secp256k1 share is
-        traced as `crypto.secp_verify` (`rows`, `rejected`, `engine`),
-        from its start to its verdicts. Fewer secp256k1 rows take one
-        host call after the device batch, their prep inside it. Other
-        key types verify on host; the bitmap is re-interleaved."""
+        rows' own `prepare` (traced as `crypto.ed_prep`, `rows`) and,
+        from `SECP_PREPARED_MIN` secp256k1 rows, their prep
+        (secp_native.prep_msgs over the verifier's KeyCache, traced as
+        `crypto.secp_prep`). `run` starts their native step on the
+        host's threads, runs the ed25519 batch on the device meanwhile
+        and waits for both; the secp256k1 share is traced as
+        `crypto.secp_verify` (`rows`, `rejected`, `engine`), from its
+        start to its verdicts. Fewer secp256k1 rows take one host call
+        after the device batch, their prep inside it. Other key types
+        verify on host; the bitmap is re-interleaved."""
         n = len(items)
         ed_idx = [i for i, k in enumerate(kinds) if k == "ed25519"]
         secp_idx = [i for i, k in enumerate(kinds) if k == "secp256k1"]
         other_idx = [
             i for i, k in enumerate(kinds) if k not in ("ed25519", "secp256k1")
         ]
-        ed = self.prepare([items[i] for i in ed_idx]) if ed_idx else None
+        ed = None
+        if ed_idx:
+            with _traced("crypto.ed_prep", rows=len(ed_idx)):
+                ed = self.prepare([items[i] for i in ed_idx])
         secp = [items[i] for i in secp_idx]
         m = len(secp)
         cols = (

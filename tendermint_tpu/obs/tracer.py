@@ -160,7 +160,10 @@ class Tracer:
     ):
         self.enabled = enabled
         self._ring: deque[SpanRecord] = deque(maxlen=max(16, ring_size))
-        self._lock = threading.Lock()
+        # re-entrant: a garbage collection can start while this thread
+        # holds the lock (a record is being made), and a `gc.callbacks`
+        # hook (the verify service's `runtime.gc`) records from inside it
+        self._lock = threading.RLock()
         # perf_counter epoch all record times are relative to, anchored
         # to the wall clock for cross-process correlation
         self.epoch = time.perf_counter()

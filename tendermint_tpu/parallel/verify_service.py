@@ -80,10 +80,18 @@ Wire format (all integers big-endian):
                     submitter's span context; a decoder that stops at
                     the last item ignores it, so old servers accept new
                     clients and vice versa)
-    stamps          := u64 t_submit_ns | u64 t_encoded_ns
+    stamps          := u64 t_submit_ns | u64 t_encoded_ns | [links]
                     (optional within the trailer: the client's clock at
                     the entry of its submit and once the items were
                     encoded; see SHARED_CLOCK)
+    links           := u64 t_gather_ns | u64 prev_req | u64 t_prev_done_ns
+                    (optional after the stamps, on the same clock: when
+                    the caller's gather of these items began, 0 where
+                    the submission came from no gather; and the request
+                    this client finished last, with the instant its
+                    reader had decoded that reply, 0 and 0 before the
+                    first. An older client's 16-byte stamps end before
+                    them and decode as before)
     FN_RESULTS(4)   body := u32 n | n * (u8 tag | [u32 len | bytes])
                     tag: 0=False 1=True 2=None 3=bytes
     PING(5)/PONG(6) body := opaque (echoed verbatim)
@@ -106,6 +114,7 @@ submission locally, as on any ERROR frame.
 from __future__ import annotations
 
 import asyncio
+import gc
 import itertools
 import json
 import os
@@ -158,6 +167,7 @@ _U32 = struct.Struct(">I")
 _U64 = struct.Struct(">Q")
 _HDR = struct.Struct(">BQ")  # type, request_id
 _STAMPS = struct.Struct(">QQ")  # t_submit_ns, t_encoded_ns
+_LINKS = struct.Struct(">QQQ")  # t_gather_ns, prev_req, t_prev_done_ns
 _COL = struct.Struct(">BI")  # a column's form, blob_len
 
 # the two forms of a byte column: one width for every row, or n lengths
@@ -245,25 +255,33 @@ STAMP_MAX_AGE_S = 60.0
 PROFILE_DEFAULT_S = 5.0
 PROFILE_MAX_S = 30.0
 
-# records of the standalone service's ring: a request leaves about 17
-# (its way in and out, queue, prep, lookup, device), so this holds a
-# 30-second window of 56 requests a second more than twice
+# records of the standalone service's ring: a request leaves about 19
+# (its gather, its way in and out, queue, prep, lookup, device), and a
+# collection one, so this holds a 30-second window of 56 requests a
+# second more than twice
 SERVICE_RING_SIZE = 65536
+
+# a connection's newest replies whose write instant the service keeps
+# for `verify.wire_out`, until the client's next frame names one
+REPLIES_KEPT = 64
 
 
 def _put_trace_ctx(out: list, ctx) -> None:
     """Optional trace-context trailer: (height, round, origin) and,
-    where the client read one, its `t_submit_ns`. The second stamp,
-    `t_encoded_ns`, is read here: the trailer stands at the END of the
-    frame so that it is written once the items are encoded."""
+    where the client read one, its `t_submit_ns`, then, where given,
+    the links (`t_gather_ns`, `prev_req`, `t_prev_done_ns`). The second
+    stamp, `t_encoded_ns`, is read here: the trailer stands at the END
+    of the frame so that it is written once the items are encoded."""
     if ctx is None:
         return
     height, round_, origin, *stamp = ctx
     out.append(_U64.pack(max(0, int(height))))
     out.append(_U32.pack(max(0, int(round_))))
     _put_str8(out, str(origin))
-    if stamp and stamp[0] is not None:
+    if stamp:
         out.append(_STAMPS.pack(stamp[0], time.perf_counter_ns()))
+        if len(stamp) > 1:
+            out.append(_LINKS.pack(*stamp[1:]))
 
 
 def decode_trace_ctx(cur: _Cursor, req_id: int):
@@ -279,13 +297,29 @@ def decode_trace_ctx(cur: _Cursor, req_id: int):
 
 
 def decode_trace_stamps(cur: _Cursor):
-    """(t_submit_s, t_encoded_s) after the trailer's three fields, in
-    seconds of the shared clock, or None: a frame with no trailer or
-    with the three-field trailer of an older client carries none."""
-    if not SHARED_CLOCK or len(cur.buf) - cur.off < _STAMPS.size:
+    """(t_submit_s, t_encoded_s, t_gather_s, prev_req, t_prev_done_s)
+    after the trailer's three fields, times in seconds of the shared
+    clock: `t_gather_s` None where the submission came from no gather,
+    `prev_req` 0 and `t_prev_done_s` None where the client had finished
+    no request, and both so for an older client's 16-byte stamps. None:
+    a frame with no trailer or with the three-field trailer of an older
+    client carries no stamps."""
+    left = len(cur.buf) - cur.off
+    if not SHARED_CLOCK or left < _STAMPS.size:
         return None
     t_submit, t_encoded = _STAMPS.unpack(cur.take(_STAMPS.size))
-    return t_submit * 1e-9, t_encoded * 1e-9
+    t_gather = prev_req = t_prev_done = 0
+    if left >= _STAMPS.size + _LINKS.size:
+        t_gather, prev_req, t_prev_done = _LINKS.unpack(
+            cur.take(_LINKS.size)
+        )
+    return (
+        t_submit * 1e-9,
+        t_encoded * 1e-9,
+        t_gather * 1e-9 if t_gather else None,
+        prev_req,
+        t_prev_done * 1e-9 if prev_req else None,
+    )
 
 
 def _put_key_types(out: list, key_types: list) -> None:
@@ -546,18 +580,30 @@ class _WayIn:
     """What `_handle_conn` knows of a submission's way in, taken once
     the trailer is decoded: the frame's size, when the whole frame was
     held, when its decode ended, the client's stamps if it sent any,
-    and what `verify.frame_decode` says of the frame it decoded: for a
-    signature submission `frame` (`cols` or `v1`) and `uniform` (every
-    byte column in its single-width form)."""
+    what `verify.frame_decode` says of the frame it decoded (for a
+    signature submission `frame`, `cols` or `v1`, and `uniform`, every
+    byte column in its single-width form), and the connection's
+    `replies`: {req: (t_ready, ctx, fields)} of the replies it began
+    writing, from which `prev` is taken, the reply the client names as
+    the last it finished."""
 
-    __slots__ = ("nbytes", "t_frame", "t_decoded", "stamps", "decoded")
+    __slots__ = (
+        "nbytes", "t_frame", "t_decoded", "stamps", "decoded", "replies",
+        "prev",
+    )
 
-    def __init__(self, cur: _Cursor, t_frame: float, **decoded):
+    def __init__(self, cur: _Cursor, t_frame: float, replies: dict,
+                 **decoded):
         self.nbytes = len(cur.buf)
         self.t_frame = t_frame
         self.stamps = decode_trace_stamps(cur)
         self.t_decoded = time.perf_counter()
         self.decoded = decoded
+        self.replies = replies
+        self.prev = (
+            replies.pop(self.stamps[3], None)
+            if replies and self.stamps is not None else None
+        )
 
 
 class VerifyServiceServer:
@@ -636,9 +682,16 @@ class VerifyServiceServer:
         self._profile_timer: Optional[asyncio.Task] = None
         self._last_profile: Optional[dict] = None
         self._loop_thread = 0
+        # with the ring armed, one `runtime.gc` span a collection
+        # (installed by start, removed by stop; never with it off)
+        self._gc_hook = None
+        self._gc_t0: Optional[float] = None
 
     async def start(self) -> None:
         self._loop_thread = threading.get_ident()
+        if self._trace().enabled and self._gc_hook is None:
+            self._gc_hook = self._collector_span
+            gc.callbacks.append(self._gc_hook)
         if not self.scheduler.running:
             await self.scheduler.start()
         # a stale socket file from a crashed predecessor refuses bind
@@ -688,10 +741,31 @@ class VerifyServiceServer:
             await self._profile_stop()
         self._profile_pool.shutdown(wait=False)
         await self.scheduler.stop()
+        hook, self._gc_hook = self._gc_hook, None
+        if hook is not None:
+            gc.callbacks.remove(hook)
         try:
             os.unlink(self.path)
         except FileNotFoundError:
             pass
+
+    def _collector_span(self, phase: str, info: dict) -> None:
+        """`gc.callbacks` hook: one `runtime.gc` span a collection, in
+        whichever thread it ran (`generation`, `collected`,
+        `uncollectable`). Recorded as the collection ends; a collection
+        inside a span recorded afterwards (`verify.frame_decode`) is
+        placed by time, as the shorter span inside it."""
+        if phase == "start":
+            self._gc_t0 = time.perf_counter()
+            return
+        t0, self._gc_t0 = self._gc_t0, None
+        if t0 is not None:
+            self._trace().add_span(
+                "runtime.gc", t0, time.perf_counter() - t0,
+                generation=info["generation"],
+                collected=info["collected"],
+                uncollectable=info["uncollectable"],
+            )
 
     # --- stats/dump surface ------------------------------------------------
 
@@ -774,9 +848,6 @@ class VerifyServiceServer:
             )
         except ProfilerUnavailable as e:
             return 409, {"error": str(e)}
-        self._trace().event(
-            "profiler.start", session=started["id"], seconds=seconds
-        )
         self._profile_timer = loop.create_task(self._stop_after(seconds))
         return 200, {"started": True, "seconds": seconds, **started}
 
@@ -800,10 +871,6 @@ class VerifyServiceServer:
             return 409, {"error": str(e), "last": self._last_profile}
         session["stop_s"] = round(time.perf_counter() - t0, 3)
         self._last_profile = session
-        self._trace().event(
-            "profiler.stop", session=session["id"], dir=session["dir"],
-            duration_s=session["duration_s"], stop_s=session["stop_s"],
-        )
         self.logger.info(
             "profile session written", dir=session["dir"],
             duration_s=session["duration_s"], stop_s=session["stop_s"],
@@ -846,6 +913,7 @@ class VerifyServiceServer:
         task = asyncio.current_task()
         self._conn_tasks.add(task)
         pending: set[asyncio.Task] = set()
+        replies: dict = {}
 
         async def send(payload: bytes) -> None:
             async with wlock:
@@ -874,7 +942,7 @@ class VerifyServiceServer:
                         items, klass = decode_submit_legacy(cur)
                     ctx = decode_trace_ctx(cur, req_id)
                     way_in = _WayIn(
-                        cur, t_frame, frame=kind, uniform=uniform
+                        cur, t_frame, replies, frame=kind, uniform=uniform
                     )
                     self.submit_frames[kind] += 1
                     stats["submissions"] += 1
@@ -891,7 +959,7 @@ class VerifyServiceServer:
                 elif typ == MSG_SUBMIT_FN:
                     engine, items, klass = decode_submit_fn(cur)
                     ctx = decode_trace_ctx(cur, req_id)
-                    way_in = _WayIn(cur, t_frame)
+                    way_in = _WayIn(cur, t_frame, replies)
                     stats["fn_submissions"] += 1
                     stats["fn_items"] += len(items)
                     spawn(
@@ -950,20 +1018,38 @@ class VerifyServiceServer:
         """The submission's way from the client's `submit` to here:
         `verify.ingress` (the client's t_submit -> t_recv) around
         `verify.client_encode`, `verify.wire_in` (encoded -> the whole
-        frame held) and `verify.frame_decode`. Stamps that are not of
-        this host's clock (in its future, or STAMP_MAX_AGE_S old) are
-        dropped with the spans that need them; the decode is the
-        service's own and stays."""
+        frame held) and `verify.frame_decode`; before it, beside
+        `verify.ingress`, `verify.client_gather` (the caller's gather
+        began -> t_submit). And the way out of the request the client
+        finished last: `verify.wire_out`, from the instant this service
+        began writing that reply to the one the client had decoded it,
+        under that request's context. Stamps that are not of this
+        host's clock (in its future, STAMP_MAX_AGE_S old, or out of
+        order) are dropped with the spans that need them; the decode is
+        the service's own and stays."""
         if not self._trace().enabled:
             return
+        oldest = t_recv - STAMP_MAX_AGE_S
         stamps = way_in.stamps
+        if way_in.prev is not None:
+            t_ready, prev_ctx, prev_fields = way_in.prev
+            t_done = stamps[4]
+            if oldest <= t_done and t_ready <= t_done <= way_in.t_frame:
+                self._span(
+                    "verify.wire_out", prev_ctx, t_ready, t_done,
+                    **prev_fields,
+                )
         if stamps is not None and not (
-            t_recv - STAMP_MAX_AGE_S <= stamps[0] <= stamps[1]
-            <= way_in.t_frame
+            oldest <= stamps[0] <= stamps[1] <= way_in.t_frame
         ):
             stamps = None
         if stamps is not None:
-            t_submit, t_encoded = stamps
+            t_submit, t_encoded, t_gather = stamps[:3]
+            if t_gather is not None and oldest <= t_gather <= t_submit:
+                self._span(
+                    "verify.client_gather", ctx, t_gather, t_submit,
+                    **fields,
+                )
             self._span("verify.ingress", ctx, t_submit, t_recv, **fields)
             fields["parent"] = "verify.ingress"
             self._span(
@@ -978,17 +1064,25 @@ class VerifyServiceServer:
         )
 
     async def _answer(
-        self, send, encode, req_id, result, ctx, t_recv: float, **fields
+        self, send, encode, req_id, result, ctx, t_recv: float, replies,
+        **fields
     ) -> None:
         """`verify.service` (t_recv -> the answer is ready; what the
         benchmark's `ipc_overhead` subtracts, so its ends stay where
         they are), then `verify.reply`: the reply frame encoded,
-        written and drained."""
+        written and drained. With the ring armed, the instant the
+        answer was ready is kept in the connection's `replies` (the
+        newest REPLIES_KEPT) for the client's next frame to close its
+        `verify.wire_out`."""
         t_ready = time.perf_counter()
         self._span("verify.service", ctx, t_recv, t_ready, **fields)
         payload = encode(req_id, result)
-        await self._send_guarded(send, payload)
         fields["bytes"] = len(payload)
+        if ctx is not None and self._trace().enabled:
+            replies[req_id] = (t_ready, ctx, fields)
+            if len(replies) > REPLIES_KEPT:
+                del replies[next(iter(replies))]
+        await self._send_guarded(send, payload)
         self._span(
             "verify.reply", ctx, t_ready, time.perf_counter(), **fields
         )
@@ -1005,7 +1099,8 @@ class VerifyServiceServer:
             await self._send_error(send, req_id, f"verify failed: {e!r}")
             return
         await self._answer(
-            send, encode_verdicts, req_id, verdicts, ctx, t_recv, **fields
+            send, encode_verdicts, req_id, verdicts, ctx, t_recv,
+            way_in.replies, **fields
         )
 
     async def _do_submit_fn(
@@ -1030,7 +1125,8 @@ class VerifyServiceServer:
             )
             return
         await self._answer(
-            send, encode_fn_results, req_id, results, ctx, t_recv, **fields
+            send, encode_fn_results, req_id, results, ctx, t_recv,
+            way_in.replies, **fields
         )
 
     async def _send_error(self, send, req_id: int, message: str) -> None:
@@ -1180,6 +1276,10 @@ class RemoteVerifyScheduler:
         # metrics objects); guarded by the GIL — single-writer loop
         self._rtt_count = 0
         self._rtt_sum = 0.0
+        # (req, CLOCK_MONOTONIC ns) of the reply the read loop decoded
+        # last: the next submission's trailer carries it, so that the
+        # service can time the reply's way back (`verify.wire_out`)
+        self._last_done = (0, 0)
         self._remote_submissions = 0
         self._degrades = 0
         self._reconnects = 0
@@ -1311,10 +1411,12 @@ class RemoteVerifyScheduler:
                 self._book_rtt(req, now)
                 if not req.future.done():
                     req.future.set_result(decode_verdicts(cur))
+                self._last_done = (req_id, time.perf_counter_ns())
             elif typ == MSG_FN_RESULTS and req.kind == "fn":
                 self._book_rtt(req, now)
                 if not req.future.done():
                     req.future.set_result(decode_fn_results(cur))
+                self._last_done = (req_id, time.perf_counter_ns())
             elif typ == MSG_ERROR:
                 msg = cur.bytes32().decode(errors="replace")
                 self._degrade_one(req, f"service error: {msg}")
@@ -1377,8 +1479,12 @@ class RemoteVerifyScheduler:
     # --- submission --------------------------------------------------------
 
     async def submit(
-        self, items: list[SigItem], klass: str = "consensus"
+        self, items: list[SigItem], klass: str = "consensus",
+        t_gather_ns: int = 0,
     ) -> np.ndarray:
+        """`t_gather_ns`: when the caller's gather of `items` began
+        (`time.perf_counter_ns()`; 0: they came from no gather), for
+        the service's `verify.client_gather`."""
         items = list(items)
         if not items:
             return np.zeros(0, dtype=bool)
@@ -1389,7 +1495,9 @@ class RemoteVerifyScheduler:
             return await asyncio.get_running_loop().run_in_executor(
                 self._fallback_pool if self._running else None, fallback
             )
-        return await self._send_req("sig", items, klass, fallback)
+        return await self._send_req(
+            "sig", items, klass, fallback, t_gather_ns=t_gather_ns
+        )
 
     async def submit_fn(
         self, items: list, fn: Callable[[list], list],
@@ -1428,7 +1536,9 @@ class RemoteVerifyScheduler:
             "fn", items, klass, fb, engine=engine
         )
 
-    async def _send_req(self, kind, items, klass, fallback, engine=""):
+    async def _send_req(
+        self, kind, items, klass, fallback, engine="", t_gather_ns=0
+    ):
         from ..obs.tracer import height_hint
 
         t_submit_ns = time.perf_counter_ns()
@@ -1436,16 +1546,18 @@ class RemoteVerifyScheduler:
         req_id = self._next_id
         # trace context: the consensus height in progress (published by
         # the state machine on every step transition) + this client's
-        # identity, and two readings of the clock this process shares
-        # with the service (SHARED_CLOCK): this entry and, taken as the
-        # trailer is appended, the end of the encode. Always stamped —
-        # ~31 bytes on the wire — so the service side can attribute
-        # even when the client's own ring is off; recording on either
-        # side stays gated on its tracer.
+        # identity, and readings of the clock this process shares with
+        # the service (SHARED_CLOCK): this entry and, taken as the
+        # trailer is appended, the end of the encode; when the caller's
+        # gather began; the request finished last and when its reply
+        # was decoded. Always stamped — ~55 bytes on the wire — so the
+        # service side can attribute even when the client's own ring
+        # is off; recording on either side stays gated on its tracer.
         height, round_ = height_hint()
         wire_ctx = (
-            height, round_, self.origin,
-            t_submit_ns if SHARED_CLOCK else None,
+            (height, round_, self.origin, t_submit_ns, t_gather_ns,
+             *self._last_done)
+            if SHARED_CLOCK else (height, round_, self.origin)
         )
         req = _RemoteReq(
             kind, items, klass, self._loop.create_future(), fallback,
@@ -1486,6 +1598,9 @@ class RemoteVerifyScheduler:
     def submit_sync(
         self, items: list[SigItem], klass: str = "consensus"
     ) -> np.ndarray:
+        """From a worker thread. A `SigBatch` (a commit's gather) hands
+        its `t_gather_ns` on to the submission."""
+        t_gather_ns = getattr(items, "t_gather_ns", 0)
         items = list(items)
         loop = self._loop
         if not self._running or loop is None or _on_loop_thread():
@@ -1500,7 +1615,7 @@ class RemoteVerifyScheduler:
             return np.asarray(self.verifier.verify(items))
         try:
             fut = asyncio.run_coroutine_threadsafe(
-                self.submit(items, klass), loop
+                self.submit(items, klass, t_gather_ns), loop
             )
             return np.asarray(fut.result())
         except Exception as e:

@@ -22,12 +22,18 @@ Reference: types/validator_set.go. Two things matter here:
 
 from __future__ import annotations
 
+import time
 from typing import Optional
 
 import numpy as np
 
 from ..crypto import merkle
-from ..crypto.batch_verifier import BatchVerifier, SigItem, default_verifier
+from ..crypto.batch_verifier import (
+    BatchVerifier,
+    SigBatch,
+    SigItem,
+    default_verifier,
+)
 from ..libs import protoio as pio
 from ..obs.tracer import default_tracer
 from .block import BlockIDFlag, Commit
@@ -271,7 +277,9 @@ class ValidatorSet:
         `votes_from_parts` call over the commits' cached (prefix,
         suffix) parts (within a commit only the timestamp differs, and
         the block id of a nil vote), and the validators' keys are read
-        once a call. One `types.gather` span a call."""
+        once a call. One `types.gather` span a call; the instant it
+        began is the returned batch's `t_gather_ns`."""
+        t_gather_ns = time.perf_counter_ns()
         with default_tracer().span("types.gather") as span:
             parts: list = []
             part_of_row, for_block, idxs_of, ts, sigs, vals = (
@@ -297,7 +305,9 @@ class ValidatorSet:
                 sigs += [cs.signature for cs in rows]
                 vals += idxs
                 idxs_of.append(idxs)
-            items = self._items(parts, part_of_row, ts, sigs, vals, span)
+            items = self._items(
+                parts, part_of_row, ts, sigs, vals, span, t_gather_ns
+            )
         return (
             items,
             idxs_of,
@@ -306,11 +316,12 @@ class ValidatorSet:
         )
 
     def _items(
-        self, parts, part_of_row, ts, sigs, vals, span
-    ) -> list[SigItem]:
+        self, parts, part_of_row, ts, sigs, vals, span, t_gather_ns: int
+    ) -> SigBatch:
         """The SigItems of rows given by columns: their sign-bytes parts,
-        timestamps, signatures and validator indices. Sets `span`'s
-        `rows`, `columnar` and `fallback`."""
+        timestamps, signatures and validator indices, as one `SigBatch`
+        stamped `t_gather_ns`. Sets `span`'s `rows`, `columnar` and
+        `fallback`."""
         from .canonical import CanonicalVoteEncoder
 
         msgs, fallback = CanonicalVoteEncoder.votes_from_parts(
@@ -321,14 +332,15 @@ class ValidatorSet:
         )
         pubs = [v.pub_key.data for v in self.validators]
         kinds = [pubkey_type_name(v.pub_key) for v in self.validators]
-        return list(
+        return SigBatch(
             map(
                 SigItem,
                 map(pubs.__getitem__, vals),
                 msgs,
                 sigs,
                 map(kinds.__getitem__, vals),
-            )
+            ),
+            t_gather_ns,
         )
 
     def _powers(self) -> np.ndarray:
@@ -455,6 +467,7 @@ class ValidatorSet:
         verifier = verifier or default_verifier()
         vals, ts, sigs = [], [], []
         seen: set[bytes] = set()
+        t_gather_ns = time.perf_counter_ns()
         with default_tracer().span("types.gather") as span:
             # only ForBlock rows are gathered here, so one (prefix,
             # suffix) covers every row
@@ -471,7 +484,9 @@ class ValidatorSet:
                 vals.append(idx)
                 ts.append(cs.timestamp_ns)
                 sigs.append(cs.signature)
-            items = self._items(parts, None, ts, sigs, vals, span)
+            items = self._items(
+                parts, None, ts, sigs, vals, span, t_gather_ns
+            )
         ok = verifier.verify(items)
         (tallied,) = self._tallies(
             ok, np.asarray(vals, dtype=np.intp), [len(vals)]

@@ -8,8 +8,9 @@ Row bitmaps and per-commit verdicts are held to the benchmark's plain
 references (`benchmark/reference/ed25519_plain.py`,
 `secp256k1_plain.py`, which import nothing of the program), with every
 bad-row kind of both key types planted. Also pinned: the spans of the
-secp256k1 share (`crypto.secp_verify` around `crypto.secp_prep`) and
-the dispatch ledger's booking of a mixed round against an ed25519 one.
+secp256k1 share (`crypto.secp_verify` around `crypto.secp_prep`), the
+ed25519 rows' `crypto.ed_prep`, and the dispatch ledger's booking of a
+mixed round against an ed25519 one.
 No secp256k1 device program is compiled here.
 """
 
@@ -24,7 +25,11 @@ import random
 import pytest
 
 from tendermint_tpu.crypto import ed25519, secp256k1
-from tendermint_tpu.crypto.batch_verifier import BatchVerifier, SigItem
+from tendermint_tpu.crypto.batch_verifier import (
+    SECP_PREPARED_MIN,
+    BatchVerifier,
+    SigItem,
+)
 from tendermint_tpu.crypto.shape_registry import default_shape_registry
 from tendermint_tpu.obs import tracer as tracer_mod
 from tendermint_tpu.obs.ledger import DispatchLedger
@@ -275,6 +280,53 @@ def test_mixed_window_matches_the_plain_references(
     assert prep.fields["rows"] == len(secp_rows)
     assert verify.t0 <= prep.t0
     assert prep.t0 + prep.dur <= verify.t0 + verify.dur
+
+
+@pytest.mark.parametrize("secp_copies", [1, 2], ids=["host", "prepared"])
+def test_mixed_round_prepares_its_ed25519_rows_under_one_span(
+    tmp_path, committee, window, ring, secp_copies
+):
+    """One `crypto.ed_prep` (`rows`: its ed25519 rows) a mixed round,
+    inside the round's `scheduler.host_prep`, whether its secp256k1
+    rows take one host call (fewer than SECP_PREPARED_MIN) or are
+    prepared with the round; none in a round of ed25519 rows alone."""
+    rows = []
+    for _, msgs, sigs, _ in window:
+        ref = committee.reference(committee.pubs, msgs, sigs, committee.types)
+        rows += [
+            (SigItem(p, m, s, t), ok)
+            for p, m, s, t, ok in zip(
+                committee.pubs, msgs, sigs, committee.types, ref
+            )
+        ]
+    ed = [r for r in rows if r[0].key_type == ED]
+    secp = [r for r in rows if r[0].key_type == SECP]
+    mixed = ed + secp * secp_copies
+    assert (len(secp) * secp_copies >= SECP_PREPARED_MIN) == (
+        secp_copies == 2
+    )
+    ledger = DispatchLedger()
+    svc = served(tmp_path, ledger)
+    try:
+        got = submit(svc, [it for it, _ in mixed])
+        submit(svc, [it for it, _ in ed])
+    finally:
+        svc.stop()
+    assert got == [ok for _, ok in mixed]
+    assert ledger.totals()["rounds"] == 2
+    recs = ring.records()
+    (prep,) = [r for r in recs if r.name == "crypto.ed_prep"]
+    assert prep.fields["rows"] == len(ed)
+    round_prep = min(
+        (r for r in recs if r.name == "scheduler.host_prep"),
+        key=lambda r: r.t0,
+    )
+    assert round_prep.t0 <= prep.t0
+    assert prep.t0 + prep.dur <= round_prep.t0 + round_prep.dur
+    (secp_prep,) = [r for r in recs if r.name == "crypto.secp_prep"]
+    assert (secp_prep.fields.get("parent") == "crypto.secp_verify") == (
+        secp_copies == 1
+    )
 
 
 def test_mixed_round_books_its_ed25519_rows_against_their_bucket(

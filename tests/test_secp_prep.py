@@ -251,14 +251,18 @@ def _mixed(n_secp: int):
 
 def test_mixed_batch_prepares_32_secp_rows_with_its_ed25519_rows(ring):
     """>= 32 secp256k1 rows: crypto.secp_prep on the prepare side (a
-    second round finds every key cached), the native step on the run
-    side, crypto.secp_verify from its start to its verdicts and outside
-    no other span; the bitmap re-interleaved."""
+    second round finds every key cached) after the ed25519 rows'
+    crypto.ed_prep, the native step on the run side, crypto.secp_verify
+    from its start to its verdicts and outside no other span; the
+    bitmap re-interleaved."""
     v = BatchVerifier()
     items, want = _mixed(40)
     prepared = v.prepare(items)
-    assert [r.name for r in ring.records()] == ["crypto.secp_prep"]
-    prep = ring.records()[0]
+    assert [r.name for r in ring.records()] == [
+        "crypto.ed_prep", "crypto.secp_prep"
+    ]
+    ed_prep, prep = ring.records()
+    assert ed_prep.fields["rows"] == 3
     assert prep.fields["rows"] == 40
     assert (prep.fields["decompressed"], prep.fields["cached"]) == (8, 32)
     assert prepared.host_rows == 41
@@ -278,10 +282,13 @@ def test_mixed_batch_prepares_32_secp_rows_with_its_ed25519_rows(ring):
 
 def test_mixed_batch_keeps_31_secp_rows_in_one_host_call(ring):
     """Fewer than 32 secp256k1 rows: one host call inside the round,
-    its crypto.secp_prep inside the round's crypto.secp_verify."""
+    its crypto.secp_prep inside the round's crypto.secp_verify; the
+    ed25519 rows prepared under crypto.ed_prep as before."""
     items, want = _mixed(31)
     prepared = BatchVerifier().prepare(items)
-    assert ring.records() == []
+    assert [
+        (r.name, r.fields["rows"]) for r in ring.records()
+    ] == [("crypto.ed_prep", 3)]
     assert prepared.run().tolist() == want
     recs = {r.name: r for r in ring.records()}
     assert recs["crypto.secp_verify"].fields["engine"] == "host"

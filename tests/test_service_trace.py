@@ -1,13 +1,15 @@
 """A submission's way in and out on the verify service's own ring: the
 client's clock stamps in the wire trailer, the `verify.*` spans the
-service records from them, the verifier's `crypto.table_lookup` /
-`crypto.table_build`, the size of the service's ring, and the device
-profiler behind the stats port.
+service records from them (the caller's gather and the reply's way back
+among them), the collector's `runtime.gc`, the verifier's
+`crypto.table_lookup` / `crypto.table_build`, the size of the service's
+ring, and the device profiler behind the stats port.
 """
 
 from __future__ import annotations
 
 import asyncio
+import gc
 import glob
 import json
 import os
@@ -19,6 +21,7 @@ import numpy as np
 import pytest
 
 from tendermint_tpu import obs
+from tendermint_tpu.crypto import ed25519
 from tendermint_tpu.crypto.batch_verifier import BatchVerifier, SigItem
 from tendermint_tpu.obs import tracer as tracer_mod
 from tendermint_tpu.parallel import verify_service as vs
@@ -27,6 +30,7 @@ from tendermint_tpu.parallel.verify_service import (
     ServiceThread,
     _Cursor,
     _HDR,
+    _LINKS,
     _STAMPS,
     decode_submit,
     decode_submit_fn,
@@ -36,6 +40,11 @@ from tendermint_tpu.parallel.verify_service import (
     encode_submit,
     encode_submit_fn,
 )
+from tendermint_tpu.types.block import BlockIDFlag, Commit, CommitSig
+from tendermint_tpu.types.block_id import BlockID
+from tendermint_tpu.types.part_set import PartSetHeader
+from tendermint_tpu.types.validator import Validator
+from tendermint_tpu.types.validator_set import ValidatorSet
 
 from .wire_legacy import CODEC_CASES, encode_submit_legacy, submit_raw
 
@@ -45,6 +54,7 @@ WAY = (
     "verify.ingress", "verify.client_encode", "verify.wire_in",
     "verify.frame_decode", "verify.service", "verify.reply",
 )
+GATHER, WIRE_OUT = "verify.client_gather", "verify.wire_out"
 
 
 class SigTagVerifier:
@@ -102,7 +112,7 @@ async def submit_through_client(path: str, items, origin="nodeA"):
 def way_of(ring) -> dict:
     """{name: record} of the one submission's `verify.*` spans, in time."""
     time.sleep(0.05)  # verify.reply lands after the client has its answer
-    recs = [r for r in ring.records() if r.name in WAY]
+    recs = [r for r in ring.records() if r.name in WAY + (GATHER, WIRE_OUT)]
     return {r.name: r for r in sorted(recs, key=lambda r: r.t0)}
 
 
@@ -123,13 +133,23 @@ FRAMES = {
 
 
 @pytest.mark.parametrize("kind", FRAMES)
-@pytest.mark.parametrize("trailer", ["stamps", "old", "none"])
+@pytest.mark.parametrize(
+    "trailer", ["links", "no-links", "stamps", "old", "none"]
+)
 def test_trailer_round_trips_and_older_frames_decode(trailer, kind):
+    """The trailer as this tree's client writes it (`links`: a gather's
+    instant and the request finished last; `no-links`: a submission
+    from no gather, before any reply), and as older clients wrote it:
+    16 bytes of stamps, three fields, nothing."""
     assert vs.SHARED_CLOCK, time.get_clock_info("perf_counter")
     encode, decode = FRAMES[kind]
     items = sig_items(3)
     before = time.perf_counter()
+    gathered = time.perf_counter_ns()
+    done = time.perf_counter_ns()
     ctx = {
+        "links": (42, 1, "nodeA", time.perf_counter_ns(), gathered, 6, done),
+        "no-links": (42, 1, "nodeA", time.perf_counter_ns(), 0, 0, 0),
         "stamps": (42, 1, "nodeA", time.perf_counter_ns()),
         "old": (42, 1, "nodeA"),
         "none": None,
@@ -150,11 +170,21 @@ def test_trailer_round_trips_and_older_frames_decode(trailer, kind):
     if trailer == "old":
         assert stamps is None
         return
-    t_submit, t_encoded = stamps
+    t_submit, t_encoded, t_gather, prev_req, t_done = stamps
     assert before <= t_submit <= t_encoded <= time.perf_counter()
-    # 16 bytes a submission, and only those
     old = encode(7, items, "consensus", ctx=ctx[:3])
-    assert len(frame) - len(old) == _STAMPS.size == 16
+    if trailer == "stamps":
+        # an older client's 16 bytes: no gather, no request finished
+        assert (t_gather, prev_req, t_done) == (None, 0, None)
+        assert len(frame) - len(old) == _STAMPS.size == 16
+        return
+    # 40 bytes a submission, and only those
+    assert len(frame) - len(old) == _STAMPS.size + _LINKS.size == 40
+    if trailer == "no-links":
+        assert (t_gather, prev_req, t_done) == (None, 0, None)
+        return
+    assert t_gather == gathered * 1e-9 and prev_req == 6
+    assert t_done == done * 1e-9 and before <= t_gather <= t_done
 
 
 # --- (b) one submission, one chain of spans ---------------------------------
@@ -204,11 +234,20 @@ def test_one_submission_leaves_its_way_in_and_out_on_the_service_ring(svc):
 
 
 def stamped(
-    t_submit_s: float, t_encoded_s: float, encode=encode_submit
+    t_submit_s: float, t_encoded_s: float, encode=encode_submit,
+    t_gather_s=None, req_id=9, prev=(0, 0.0),
 ) -> bytes:
-    return encode(
-        9, sig_items(12), "consensus", ctx=(5, 0, "w1")
+    """A frame with hand-set stamps: the 16 bytes of an older client,
+    or, given `t_gather_s` or `prev` (the request finished last and
+    when), the 40 of this tree's."""
+    frame = encode(
+        req_id, sig_items(12), "consensus", ctx=(5, 0, "w1")
     ) + _STAMPS.pack(int(t_submit_s * 1e9), int(t_encoded_s * 1e9))
+    if t_gather_s is None and not prev[0]:
+        return frame
+    return frame + _LINKS.pack(
+        int((t_gather_s or 0) * 1e9), prev[0], int(prev[1] * 1e9)
+    )
 
 
 @pytest.mark.parametrize(
@@ -229,6 +268,29 @@ def stamped(
         (lambda now: stamped(now - 0.001, now - 0.002), WAY[3:]),
         # and one whose stamps are sound, sent the same way
         (lambda now: stamped(now - 0.002, now - 0.001), WAY),
+        # a caller's gather before the submit: its span beside the rest;
+        # a gather stamp out of order or of another clock drops that
+        # span alone, and stamps of another clock drop it with theirs
+        (
+            lambda now: stamped(now - 0.002, now - 0.001,
+                                t_gather_s=now - 0.003),
+            (GATHER,) + WAY,
+        ),
+        (
+            lambda now: stamped(now - 0.002, now - 0.001,
+                                t_gather_s=now - 0.0015),
+            WAY,
+        ),
+        (
+            lambda now: stamped(now - 0.002, now - 0.001,
+                                t_gather_s=now - 61.0),
+            WAY,
+        ),
+        (
+            lambda now: stamped(now + 5.0, now + 5.001,
+                                t_gather_s=now + 4.9),
+            WAY[3:],
+        ),
         # the per-item frame of an older client, with each trailer it
         # may carry
         (
@@ -249,7 +311,8 @@ def stamped(
         ),
     ],
     ids=["no-trailer", "old-trailer", "future", "60s-old", "backwards",
-         "sound", "v1-no-trailer", "v1-old-trailer", "v1-sound"],
+         "sound", "gather", "gather-after-submit", "gather-60s-old",
+         "gather-future", "v1-no-trailer", "v1-old-trailer", "v1-sound"],
 )
 def test_other_frames_are_served_alike_and_bad_stamps_drop_client_spans(
     svc, frame, names
@@ -261,8 +324,13 @@ def test_other_frames_are_served_alike_and_bad_stamps_drop_client_spans(
     assert (verdicts == WANT).all()
     way = way_of(ring)
     assert tuple(way) == tuple(names)
-    if "verify.frame_decode" in way and names != WAY:
+    if "verify.frame_decode" in way and "verify.ingress" not in names:
         assert "parent" not in way["verify.frame_decode"].fields
+    if GATHER in way:
+        gather = way[GATHER]
+        assert "parent" not in gather.fields  # beside ingress, not in it
+        assert abs(gather.t0 + gather.dur - way["verify.ingress"].t0) < 1e-6
+        assert gather.fields["req"] == way["verify.ingress"].fields["req"]
     assert thread.server.error_frames == 0
 
 
@@ -298,23 +366,252 @@ def test_frame_decode_span_says_which_frame_it_read(svc, encode, case, want):
     assert thread.server.dump()["service"]["submit_frames"] == counts
 
 
-# --- (d) tracer off ---------------------------------------------------------
+# --- (c2) the caller's gather and the reply's way back ----------------------
+
+CHAIN = "trace-chain"
+
+
+def commit_windows(windows: int = 2, heights: int = 3):
+    """(validator set, `windows` lists of verify_commits_light entries):
+    four ed25519 validators whose every signature starts with b"1", so
+    that the stub verifier passes them all."""
+    keys = [
+        ed25519.PrivKey.from_secret(b"gather%d" % i).public_key()
+        for i in range(4)
+    ]
+    vset = ValidatorSet([Validator(k, 10) for k in keys])
+    out = []
+    for w in range(windows):
+        entries = []
+        for h in range(w * heights + 1, (w + 1) * heights + 1):
+            bh = bytes([h]) * 32
+            bid = BlockID(bh, PartSetHeader(1, bh))
+            sigs = [
+                CommitSig(
+                    BlockIDFlag.COMMIT, v.address, 10**18 + h * 10**9 + i,
+                    b"1" + bytes([h, i]) * 31 + b"s",
+                )
+                for i, v in enumerate(vset.validators)
+            ]
+            entries.append((bid, h, Commit(h, 0, bid, sigs)))
+        out.append(entries)
+    return vset, out
+
+
+async def gather_through_client(path: str, vset, windows) -> list:
+    """Each window through `verify_commits_light` on a worker thread,
+    over the remote scheduler's classed verifier, one after another."""
+    client = RemoteVerifyScheduler(
+        path, verifier=SigTagVerifier(), retry_base=0.02, origin="nodeA",
+        tracer=obs.Tracer(enabled=False),
+    )
+    await client.start()
+    deadline = time.monotonic() + 15
+    while not client.connected and time.monotonic() < deadline:
+        await asyncio.sleep(0.01)
+    assert client.connected, "client never attached"
+    loop = asyncio.get_running_loop()
+    classed = client.classed("blocksync")
+    try:
+        return [
+            await loop.run_in_executor(
+                None,
+                lambda e=entries: vset.verify_commits_light(
+                    CHAIN, e, verifier=classed
+                ),
+            )
+            for entries in windows
+        ]
+    finally:
+        await client.stop()
+
+
+def test_gather_and_the_reply_way_back_land_under_their_requests(svc):
+    """Two catch-up windows from one client: each submission's
+    `verify.client_gather` (the gather began -> its submit, beside its
+    `verify.ingress`), and the first one's `verify.wire_out` (the
+    service began writing its reply -> the client had decoded it),
+    recorded when the second names it and under the first's `req`."""
+    thread, ring = svc
+    vset, windows = commit_windows()
+    t0 = time.perf_counter()
+    verdicts = asyncio.run(
+        gather_through_client(thread.server.path, vset, windows)
+    )
+    assert verdicts == [[True] * 3, [True] * 3]
+    time.sleep(0.05)
+    recs = [r for r in ring.records() if r.name.startswith("verify.")]
+    by = {}
+    for r in recs:
+        by.setdefault((r.name, r.fields["req"]), []).append(r)
+    reqs = sorted({req for _, req in by})
+    assert len(reqs) == 2
+    first, second = reqs
+    end = lambda r: r.t0 + r.dur  # noqa: E731
+    for req in reqs:
+        (gather,) = by[GATHER, req]
+        (ingress,) = by["verify.ingress", req]
+        assert "parent" not in gather.fields
+        assert gather.fields["origin"] == "nodeA"
+        assert gather.fields["n"] == 12
+        assert ring.epoch + gather.t0 >= t0
+        assert abs(end(gather) - ingress.t0) < 1e-6
+    (wire_out,) = by[WIRE_OUT, first]
+    assert (WIRE_OUT, second) not in by  # nobody has named it yet
+    (service,) = by["verify.service", first]
+    (reply,) = by["verify.reply", first]
+    assert abs(wire_out.t0 - end(service)) < 1e-6
+    assert wire_out.t0 == reply.t0
+    assert end(wire_out) <= end(by[GATHER, second][0])  # <= its submit
+    assert wire_out.fields["bytes"] == reply.fields["bytes"]
+    assert wire_out.fields["origin"] == "nodeA"
+
+
+async def two_frames(path: str, done_stamp) -> np.ndarray:
+    """Frame 9, its reply, then frame 10 naming request
+    `done_stamp(t_sent, t_done)[0]` as finished at `[1]`, on one
+    connection; the second reply's verdicts."""
+    from tendermint_tpu.parallel.verify_service import (
+        decode_verdicts, read_frame, write_frame,
+    )
+
+    reader, writer = await asyncio.open_unix_connection(path)
+    try:
+        t_sent = time.perf_counter()
+        write_frame(writer, stamped(t_sent, t_sent, t_gather_s=t_sent))
+        await writer.drain()
+        await asyncio.wait_for(read_frame(reader), 10)
+        t_done = time.perf_counter()
+        now = time.perf_counter()
+        write_frame(
+            writer,
+            stamped(now, now, req_id=10, prev=done_stamp(t_sent, t_done)),
+        )
+        await writer.drain()
+        cur = _Cursor(await asyncio.wait_for(read_frame(reader), 10))
+        cur.take(_HDR.size)
+        return decode_verdicts(cur)
+    finally:
+        writer.close()
+
+
+@pytest.mark.parametrize(
+    "done_stamp, recorded",
+    [
+        (lambda sent, done: (9, done), True),
+        # a request this connection was never answered
+        (lambda sent, done: (8, done), False),
+        # before the service began writing the reply, or in its future
+        (lambda sent, done: (9, sent - 0.001), False),
+        (lambda sent, done: (9, done + 5.0), False),
+    ],
+    ids=["sound", "unknown-req", "before-the-reply", "future"],
+)
+def test_reply_way_back_needs_a_sound_done_stamp(svc, done_stamp, recorded):
+    thread, ring = svc
+    verdicts = asyncio.run(two_frames(thread.server.path, done_stamp))
+    assert (verdicts == WANT).all()
+    time.sleep(0.05)
+    out = [r for r in ring.records() if r.name == WIRE_OUT]
+    assert len(out) == recorded
+    if recorded:
+        assert out[0].fields["req"] == 9
+    # the rest of both ways stands either way, and nothing failed
+    assert [r.name for r in ring.records()].count("verify.ingress") == 2
+    assert thread.server.error_frames == 0
+
+
+# --- (d) tracer off, and the collector --------------------------------------
 
 
 def test_tracer_off_same_verdicts_empty_ring(tmp_path):
+    hooks = list(gc.callbacks)
     ring = obs.Tracer(enabled=False)
     thread = ServiceThread(
         str(tmp_path / "vs.sock"), verifier=SigTagVerifier(), tracer=ring
     )
     thread.start()
     try:
+        assert gc.callbacks == hooks  # no collector hook with it off
         verdicts = asyncio.run(
             submit_through_client(thread.server.path, sig_items(12))
         )
+        vset, windows = commit_windows()
+        asyncio.run(gather_through_client(thread.server.path, vset, windows))
+        gc.collect()
     finally:
         thread.stop()
     assert (verdicts == WANT).all()
     assert len(ring) == 0
+    assert gc.callbacks == hooks
+
+
+def test_collector_is_a_span_while_the_ring_is_armed(svc):
+    """One `runtime.gc` a collection, whichever thread ran it, with
+    `generation`, `collected` and `uncollectable`; the hook goes with
+    the service."""
+    thread, ring = svc
+    assert thread.server._gc_hook in gc.callbacks
+    t0 = time.perf_counter()
+    garbage = [[] for _ in range(10)]
+    for a, b in zip(garbage, garbage[1:] + garbage[:1]):
+        a.append(b)  # a cycle only a collection frees
+    del garbage, a, b
+    gc.collect()
+    t1 = time.perf_counter()
+    full = [
+        r for r in ring.records()
+        if r.name == "runtime.gc" and t0 <= ring.epoch + r.t0 <= t1
+        and r.fields["generation"] == 2
+    ]
+    span = full[-1]  # gc.collect()'s, the last thing in the interval
+    assert span.fields["collected"] >= 10
+    assert span.fields["uncollectable"] == 0
+    assert "parent" not in span.fields
+    assert 0.0 <= span.dur <= t1 - t0
+    hook = thread.server._gc_hook
+    thread.stop()
+    assert hook not in gc.callbacks
+
+
+REENTRY = """
+import gc, sys, threading, time
+from tendermint_tpu import obs
+
+ring = obs.Tracer(enabled=True, ring_size=100000)
+t0 = {}
+
+def hook(phase, info):
+    if phase == "start":
+        t0["t"] = time.perf_counter()
+    else:
+        ring.add_span("runtime.gc", t0["t"], 0.0)
+
+gc.callbacks.append(hook)
+gc.set_threshold(1, 1, 1)  # a collection at every tracked allocation
+for i in range(3000):
+    ring.add_span("x", 0.0, 0.0, i=i)
+    if i % 100 == 0:
+        ring.event("e")
+        ring.records()
+print(sum(r.name == "runtime.gc" for r in ring.records()))
+"""
+
+
+def test_a_collection_inside_a_record_is_recorded_not_a_deadlock():
+    """A collection can start while a thread holds the ring's lock (the
+    record it is making is an allocation); the hook's span then comes
+    from inside that hold."""
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    done = subprocess.run(
+        [sys.executable, "-c", REENTRY], cwd=root, capture_output=True,
+        text=True, timeout=60,
+    )
+    assert done.returncode == 0, done.stderr[-2000:]
+    assert int(done.stdout) > 1000
 
 
 # --- (e) the verifier's way to its tables -----------------------------------
@@ -441,9 +738,10 @@ def test_profile_routes_trace_the_service_while_it_serves(svc):
                 session["dir"], "plugins", "profile", "*", "*.xplane.pb"
             )
         )
-    events = {r.name: r for r in ring.records() if r.kind == "event"}
-    assert events["profiler.start"].fields["session"] == started["id"]
-    assert events["profiler.stop"].fields["dir"] == session["dir"]
+    # the routes' replies say which session and where; the ring holds
+    # no record of it
+    assert session["dir"] == started["dir"]
+    assert not any(r.name.startswith("profiler.") for r in ring.records())
     # and after it
     verdicts = asyncio.run(
         submit_through_client(thread.server.path, sig_items(12))
@@ -457,11 +755,15 @@ def test_profile_session_stops_by_itself(svc):
     port = thread.server.stats_port
     status, started = get(port, "profile_start?seconds=0.2")
     assert status == 200 and started["seconds"] == 0.2
+    assert started["device_trace"]["enabled"]
+    # the session's own trace, written as it stops by itself
+    xplanes = os.path.join(
+        started["dir"], "plugins", "profile", "*", "*.xplane.pb"
+    )
     deadline = time.monotonic() + 30
-    while time.monotonic() < deadline:
-        if any(r.name == "profiler.stop" for r in ring.records()):
-            break
+    while time.monotonic() < deadline and not glob.glob(xplanes):
         time.sleep(0.05)
+    assert glob.glob(xplanes)
     status, doc = get(port, "profile_stop")
     assert status == 409 and doc["last"]["id"] == started["id"]
     assert get(port, "profile_start?seconds=99")[1]["seconds"] == 30.0
