@@ -606,6 +606,114 @@ class _WayIn:
         )
 
 
+# --- the collector of the process that owns the chip ---------------------
+
+# tracked objects one served row allocates: its `SigItem` (the byte
+# columns, `prepare`'s arrays and the verdicts are not tracked; the
+# lists a submission and a round add are per submission).
+# tests/test_service_gc.py measures it around a decode and a prepare
+ROW_OBJECTS = 1
+# a second submission decoded while the first is served, or a round's
+# items still held as the next window decodes
+SUBMISSIONS_IN_FLIGHT = 2
+# the most young objects between two collections: at the 80-144 bytes
+# a list, a small instance, a `SigItem` or a dict takes, 2**18 of them
+# bound a young generation of cyclic garbage at 21-38 MB (PERF.md
+# section 6 gives the arithmetic)
+YOUNG_CAP = 1 << 18
+
+
+class CollectorPolicy:
+    """The collector policy of the process that owns the chip:
+    `run_service` builds one and hands it to its server. An embedded
+    server (`ServiceThread`, a test's) gets none and leaves its host's
+    collector as it found it.
+
+    Generation 0's threshold follows the largest submission begun:
+    ROW_OBJECTS x SUBMISSIONS_IN_FLIGHT x its rows, never under the
+    threshold the process started with and never over YOUNG_CAP. A
+    submission's rows die by reference count once its reply goes, so
+    the young count rises and falls with each one and does not reach
+    the threshold. Generations 1 and 2 keep theirs.
+
+    The warm heap is frozen once set-up has grown it: when no
+    submission is in flight and the server's heap mark (programs
+    compiled or loaded, keys resident in the table stores) has passed
+    its highest reading so far, `collect()` then `freeze()`, so no
+    garbage cycle is frozen and a later full collection walks only what
+    came after. A timed window compiles and builds nothing, so no freeze
+    lands in one. Each freeze is a `runtime.gc_freeze` span (`frozen`,
+    `threshold0`) on `tracer`. `gc_module` is the tests' stand-in."""
+
+    def __init__(self, tracer=None, gc_module=gc):
+        self._gc = gc_module
+        self._tracer = tracer
+        self._young0 = gc_module.get_threshold()[0]
+        self._heap_mark: Optional[Callable[[], tuple]] = None
+        self._mark = None
+        self.largest = 0  # rows of the largest submission begun
+        self.in_flight = 0
+        self.freezes = 0
+
+    def watch(self, heap_mark: Callable[[], tuple]) -> None:
+        """The server's heap mark, read now as the baseline."""
+        self._heap_mark = heap_mark
+        self._mark = heap_mark()
+
+    def begin(self, rows: int) -> None:
+        """A submission of `rows` is in flight."""
+        self.in_flight += 1
+        if rows > self.largest:
+            self.largest = rows
+            young = min(
+                YOUNG_CAP,
+                max(self._young0, ROW_OBJECTS * SUBMISSIONS_IN_FLIGHT * rows),
+            )
+            _, gen1, gen2 = self._gc.get_threshold()
+            self._gc.set_threshold(young, gen1, gen2)
+
+    def end(self) -> None:
+        """A submission's work is over; its reply has not gone."""
+        self.in_flight -= 1
+        self.settle()
+
+    def settle(self) -> bool:
+        """Freeze the warm heap if nothing is in flight and the heap
+        mark rose past its highest reading; True if it froze."""
+        if self.in_flight or self._heap_mark is None:
+            return False
+        mark = self._heap_mark()
+        if all(now <= high for now, high in zip(mark, self._mark)):
+            return False
+        self._mark = tuple(map(max, mark, self._mark))
+        t0 = time.perf_counter()
+        self._gc.collect()
+        self._gc.freeze()
+        self.freezes += 1
+        if self._tracer is not None:
+            self._tracer.add_span(
+                "runtime.gc_freeze", t0, time.perf_counter() - t0,
+                frozen=self._gc.get_freeze_count(),
+                threshold0=self._gc.get_threshold()[0],
+            )
+        return True
+
+    def snapshot(self) -> dict:
+        """The dump's `gc` block: the freezes made, the objects frozen,
+        the thresholds and counts now, and per generation the
+        collections and the objects they freed since the process
+        started."""
+        stats = self._gc.get_stats()
+        return {
+            "freezes": self.freezes,
+            "frozen": self._gc.get_freeze_count(),
+            "threshold": list(self._gc.get_threshold()),
+            "count": list(self._gc.get_count()),
+            "collections": [s["collections"] for s in stats],
+            "collected": [s["collected"] for s in stats],
+        }
+
+
 class VerifyServiceServer:
     """Owns the scheduler/device plane and serves the UDS protocol.
 
@@ -615,7 +723,10 @@ class VerifyServiceServer:
     (the same JSON shape as the node RPC route, plus per-client tenant
     rows) and GET /dump_traces (the service flight ring in the node
     dump_traces shape, mergeable by obs/cluster.py) over TCP —
-    `tools/device_report.py` reads those dumps directly."""
+    `tools/device_report.py` reads those dumps directly. `collector`
+    (run_service's `CollectorPolicy`) tunes and freezes the process's
+    collector around the submissions served; without one the server
+    leaves it alone."""
 
     def __init__(
         self,
@@ -629,6 +740,7 @@ class VerifyServiceServer:
         registry: Optional[Registry] = None,
         engines: Optional[dict] = None,
         tracer=None,
+        collector: Optional[CollectorPolicy] = None,
     ):
         self.path = path
         self.logger = logger or nop_logger()
@@ -686,6 +798,20 @@ class VerifyServiceServer:
         # (installed by start, removed by stop; never with it off)
         self._gc_hook = None
         self._gc_t0: Optional[float] = None
+        # the process's collector policy: run_service's; an embedded
+        # server has none and leaves its host's collector alone
+        self.collector = collector
+        if collector is not None:
+            collector.watch(self._heap_mark)
+
+    def _heap_mark(self) -> tuple:
+        """What set-up grows the long-lived heap by: the programs this
+        process compiled or loaded, and the keys its table stores hold
+        (a store that only evicts does not grow)."""
+        return (
+            compile_log().totals()["compilations"],
+            sum(t["keys_resident"] for t in self._table_store().values()),
+        )
 
     async def start(self) -> None:
         self._loop_thread = threading.get_ident()
@@ -786,6 +912,10 @@ class VerifyServiceServer:
                 "error_frames": self.error_frames,
                 "submit_frames": dict(self.submit_frames),
                 "table_store": self._table_store(),
+                "gc": (
+                    self.collector.snapshot()
+                    if self.collector is not None else None
+                ),
             },
             "summary": ledger.summary(),
             "entries": ledger.entries(limit=entries) if entries > 0 else [],
@@ -1087,6 +1217,20 @@ class VerifyServiceServer:
             "verify.reply", ctx, t_ready, time.perf_counter(), **fields
         )
 
+    async def _served(self, work, rows: int):
+        """Await a submission's work. With a collector policy it is in
+        flight from here to its answer, where the policy may freeze:
+        before the reply goes, so that a freeze is part of the request
+        that grew the heap and never of the client's next one."""
+        collector = self.collector
+        if collector is None:
+            return await work
+        collector.begin(rows)
+        try:
+            return await work
+        finally:
+            collector.end()
+
     async def _do_submit(
         self, send, req_id, items, klass, ctx, way_in
     ):
@@ -1094,7 +1238,9 @@ class VerifyServiceServer:
         fields = {"n": len(items), "klass": klass, "bytes": way_in.nbytes}
         self._way_in_spans(ctx, way_in, t_recv, **fields)
         try:
-            verdicts = await self.scheduler.submit(items, klass, ctx=ctx)
+            verdicts = await self._served(
+                self.scheduler.submit(items, klass, ctx=ctx), len(items)
+            )
         except Exception as e:
             await self._send_error(send, req_id, f"verify failed: {e!r}")
             return
@@ -1116,8 +1262,11 @@ class VerifyServiceServer:
         fields = {"n": len(items), "klass": klass, "bytes": way_in.nbytes}
         self._way_in_spans(ctx, way_in, t_recv, **fields)
         try:
-            results = await self.scheduler.submit_fn(
-                items, fn, klass, engine=engine, ctx=ctx
+            results = await self._served(
+                self.scheduler.submit_fn(
+                    items, fn, klass, engine=engine, ctx=ctx
+                ),
+                len(items),
             )
         except Exception as e:
             await self._send_error(
@@ -1696,7 +1845,7 @@ def run_service(
     socket is accepting — spawners wait on it instead of polling.
     `trace` (or TM_TPU_TRACE=1) arms the service flight ring
     (SERVICE_RING_SIZE records) served at GET /dump_traces on the stats
-    port."""
+    port. The process's collector follows `CollectorPolicy`."""
     import signal
 
     from ..obs import Tracer, set_default_tracer
@@ -1713,7 +1862,7 @@ def run_service(
     )
     server = VerifyServiceServer(
         path, max_batch=max_batch, logger=logger, stats_port=stats_port,
-        tracer=tracer,
+        tracer=tracer, collector=CollectorPolicy(tracer=tracer),
     )
 
     async def run() -> None:
@@ -1721,6 +1870,7 @@ def run_service(
         try:
             if prewarm:
                 entries = server.scheduler.verifier.prewarm_buckets()
+                server.collector.settle()
                 logger.info(
                     "verify-service prewarm complete",
                     programs=len(entries),

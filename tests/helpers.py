@@ -5,6 +5,8 @@ from __future__ import annotations
 
 import time
 
+import numpy as np
+
 from tendermint_tpu.types.block import Block, Commit
 from tendermint_tpu.types.block_id import BlockID
 from tendermint_tpu.types.genesis import GenesisDoc, GenesisValidator
@@ -129,3 +131,53 @@ def sign_commit(
             )
         votes.add_vote(v, verified=True)
     return votes.make_commit()
+
+
+class KeyedVerifier:
+    """Stub rounds (verdict = sig starts with b'1') whose table store
+    takes in every key it has not seen, as the verifier's stores do."""
+
+    def __init__(self):
+        self.keys: set = set()
+
+    def verify(self, items):
+        self.keys.update(it.pubkey for it in items)
+        return np.array([it.sig[:1] == b"1" for it in items], dtype=bool)
+
+    def table_store_stats(self):
+        return {"small": {"keys_resident": len(self.keys)}}
+
+
+class FakeGC:
+    """A stand-in for the `gc` module, for a collector policy under test:
+    its calls land here in order, and the process's own collector keeps
+    its thresholds and freeze count."""
+
+    def __init__(self, threshold=(700, 10, 10)):
+        self.threshold = tuple(threshold)
+        self.calls: list[str] = []
+        self.frozen = 0
+
+    def get_threshold(self):
+        return self.threshold
+
+    def set_threshold(self, *threshold):
+        self.calls.append("set_threshold")
+        self.threshold = threshold
+
+    def collect(self):
+        self.calls.append("collect")
+        return 0
+
+    def freeze(self):
+        self.calls.append("freeze")
+        self.frozen += 1000
+
+    def get_freeze_count(self):
+        return self.frozen
+
+    def get_count(self):
+        return (0, 0, 0)
+
+    def get_stats(self):
+        return [{"collections": 0, "collected": 0, "uncollectable": 0}] * 3

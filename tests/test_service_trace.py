@@ -1,9 +1,9 @@
 """A submission's way in and out on the verify service's own ring: the
 client's clock stamps in the wire trailer, the `verify.*` spans the
 service records from them (the caller's gather and the reply's way back
-among them), the collector's `runtime.gc`, the verifier's
-`crypto.table_lookup` / `crypto.table_build`, the size of the service's
-ring, and the device profiler behind the stats port.
+among them), the collector's `runtime.gc` and `runtime.gc_freeze`, the
+verifier's `crypto.table_lookup` / `crypto.table_build`, the size of
+the service's ring, and the device profiler behind the stats port.
 """
 
 from __future__ import annotations
@@ -46,6 +46,7 @@ from tendermint_tpu.types.part_set import PartSetHeader
 from tendermint_tpu.types.validator import Validator
 from tendermint_tpu.types.validator_set import ValidatorSet
 
+from .helpers import FakeGC, KeyedVerifier
 from .wire_legacy import CODEC_CASES, encode_submit_legacy, submit_raw
 
 pytestmark = pytest.mark.verify_service
@@ -572,6 +573,43 @@ def test_collector_is_a_span_while_the_ring_is_armed(svc):
     hook = thread.server._gc_hook
     thread.stop()
     assert hook not in gc.callbacks
+
+
+def test_a_freeze_is_a_span_while_the_ring_is_armed(tmp_path):
+    """One `runtime.gc_freeze` a freeze (`frozen`, `threshold0`),
+    inside the `verify.service` of the submission whose keys grew the
+    store and before its reply; none for one that grew nothing."""
+    ring = obs.Tracer(enabled=True)
+    fake = FakeGC()
+    thread = ServiceThread(
+        str(tmp_path / "vs.sock"), verifier=KeyedVerifier(),
+        tracer=ring,
+        collector=vs.CollectorPolicy(tracer=ring, gc_module=fake),
+    )
+    thread.start()
+    try:
+        for _ in range(2):
+            verdicts = asyncio.run(
+                submit_through_client(thread.server.path, sig_items(12))
+            )
+            assert (verdicts == WANT).all()
+        time.sleep(0.05)  # verify.reply lands after the client's answer
+    finally:
+        thread.stop()
+    recs = ring.records()
+    [freeze] = [r for r in recs if r.name == "runtime.gc_freeze"]
+    assert freeze.fields == {"frozen": 1000, "threshold0": 700}
+    assert fake.calls[-2:] == ["collect", "freeze"]
+    first = min(
+        (r for r in recs if r.name == "verify.service"), key=lambda r: r.t0
+    )
+    reply = next(
+        r for r in recs if r.name == "verify.reply"
+        and r.fields["req"] == first.fields["req"]
+    )
+    assert first.t0 <= freeze.t0
+    assert freeze.t0 + freeze.dur <= first.t0 + first.dur + 1e-9
+    assert freeze.t0 + freeze.dur <= reply.t0 + 1e-9
 
 
 REENTRY = """
